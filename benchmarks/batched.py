@@ -73,6 +73,9 @@ def bench_one(a, method, backend, batch, reps, *, block_cols=None,
 
 
 def main():
+    from repro import runtime
+
+    runtime.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=512,
                     help="host-backend pattern size")

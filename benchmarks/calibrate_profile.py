@@ -77,6 +77,9 @@ def _validate(prof) -> dict:
 
 
 def main(argv=None) -> int:
+    from repro import runtime
+
+    runtime.enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
                     help="small ladder (scale 0.25, 2 reps) for CI")

@@ -21,54 +21,45 @@ PASS criterion (ISSUE 8): the largest mesh's multiply exceeds the
 single-device guard yet completes distributed and bit-matches the oracle,
 with placement imbalance < 2.0 at every mesh size.
 
-Runs on a simulated host mesh: the script re-execs itself under
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` when fewer devices
-are visible.  Timings on such a mesh share one set of CPU cores, so the
-weak-scaling table is about *feasibility and balance*, not parallel
-speedup — the JSON records both anyway.
+Runs in one process on the devices it finds.  Under ``JAX_PLATFORMS=cpu``
+it simulates an 8-device host mesh
+(``--xla_force_host_platform_device_count``); timings on such a mesh
+share one set of CPU cores, so the weak-scaling table is about
+*feasibility and balance*, not parallel speedup — the JSON records both
+anyway.
 
-    PYTHONPATH=src python benchmarks/distributed_spgemm.py [--smoke] [--out PATH]
+    JAX_PLATFORMS=cpu PYTHONPATH=src \
+        python benchmarks/distributed_spgemm.py [--smoke] [--out PATH]
 """
 
 from __future__ import annotations
 
 import argparse
 import os
-import subprocess
 import sys
 import time
 
 sys.path.insert(0, "src")
 
-_REEXEC_MARK = "_DIST_SPGEMM_REEXEC"
 
+def _host_mesh(want: int) -> None:
+    """Under ``JAX_PLATFORMS=cpu``, simulate a ``want``-device host mesh.
 
-def _ensure_devices(want: int) -> None:
-    """Re-exec under a forced host mesh when too few devices are visible.
-
-    jax fixes the device topology at backend init, so the flag cannot be
-    applied after import — a fresh interpreter is the only way up.
+    XLA reads the flag when the backend starts, so this runs before the
+    first device query.  On any other platform the script uses the devices
+    it finds.
     """
-    import jax
-
-    if len(jax.devices()) >= want:
-        return
-    if os.environ.get(_REEXEC_MARK) == "1":
-        raise RuntimeError(
-            f"re-exec still sees {len(jax.devices())} device(s); "
-            f"xla_force_host_platform_device_count={want} was not honoured")
-    env = dict(os.environ)
-    env[_REEXEC_MARK] = "1"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count={want}").strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    print(f"re-exec under a simulated {want}-device host mesh ...")
-    rc = subprocess.run([sys.executable, os.path.abspath(__file__)]
-                        + sys.argv[1:], env=env).returncode
-    sys.exit(rc)
+    flags = os.environ.get("XLA_FLAGS", "")
+    if (os.environ.get("JAX_PLATFORMS") == "cpu"
+            and "xla_force_host_platform_device_count" not in flags):
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={want}").strip()
 
 
 def main():
+    from repro import runtime
+
+    runtime.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=8)
     ap.add_argument("--guard", type=int, default=1_500_000,
@@ -86,7 +77,7 @@ def main():
         args.guard, args.fill = 40_000, 8
         args.inner, args.rows, args.reps = 1024, 768, 3
 
-    _ensure_devices(args.devices)
+    _host_mesh(args.devices)
 
     import jax
     import numpy as np
@@ -101,7 +92,8 @@ def main():
 
     guard = args.guard
     per_device_target = 3 * guard // 4   # weak-scaling per-device work
-    mesh_sizes = [d for d in (1, 2, 4, 8) if d <= len(jax.devices())]
+    mesh_sizes = [d for d in (1, 2, 4, 8)
+                  if d <= min(args.devices, len(jax.devices()))]
 
     def int_csc(n, z, seed, n_rows):
         # integer-valued f32: every partial sum is exact, so the merged

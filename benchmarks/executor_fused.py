@@ -56,6 +56,9 @@ REQUIRED_SPEEDUP = 2.0
 
 
 def main():
+    from repro import runtime
+
+    runtime.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--m", type=int, default=256)
     ap.add_argument("--n-sparse", type=int, default=992)

@@ -76,6 +76,9 @@ def bench_end_to_end(a, method, backend, reps, header=False):
 
 
 def main():
+    from repro import runtime
+
+    runtime.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=4000,
                     help="pattern size for the overhead measurement")

@@ -21,7 +21,8 @@ phase of the PR 3 mixed-density workload and reports, per engine:
   ``(bytes_model / t) / peak_bw``, with ``peak_bw`` *measured* on the spot
   by a large-array triad sweep (not a spec-sheet constant).  This is the
   headline number: an engine at ``bw_frac ~ 1`` cannot be made faster
-  without moving fewer bytes.
+  without moving fewer bytes.  The bound is the *host's*, so the script
+  runs on the CPU only and refuses any other platform.
 
 ``bw_frac`` is equivalently ``t_bound / t`` — the per-engine bound uses the
 engine's own dtype widths, so the host engines are not penalized for their
@@ -105,6 +106,15 @@ def _engines(a, b):
 def run(m: int = 256, n_sparse: int = 992, dense_a: int = 32,
         dense_b: int = 32, per_dense: int = 24, reps: int = 5,
         out: str = "BENCH_roofline.json", smoke: bool = False) -> dict:
+    from repro import runtime
+
+    if runtime.platform() != "cpu":
+        # the bound below is a host triad; device engines need the chip's
+        # own peak, keyed by device_kind, which this script does not have
+        raise SystemExit(
+            f"roofline: no bw_frac on {runtime.platform()!r} — the "
+            "bandwidth bound is measured on the host; a device peak table "
+            "is needed")
     if smoke:
         m, n_sparse = 96, 240
         dense_a = dense_b = per_dense = 16
@@ -148,9 +158,8 @@ def run(m: int = 256, n_sparse: int = 992, dense_a: int = 32,
         print(f"| {r['engine']:6s} | {r['t_ms']:8.3f} | {r['gflops']:7.3f} "
               f"| {r['bw_achieved_gbs']:8.3f} | {r['bw_frac']:10.4f} |"
               f"{'' if r['correct'] else '  !! MISMATCH'}")
-    print("\n(interpret-mode Pallas emulates the kernel scalar-by-scalar on "
-          "CPU — the fused row's fraction is meaningful on real devices, "
-          "where the same launch count meets hardware gathers)")
+    print("\n(the Pallas interpreter emulates the kernel on the CPU, and the "
+          "bound is the host's: no row here is a device number)")
 
     report = {
         "bench": "roofline",
@@ -166,6 +175,9 @@ def run(m: int = 256, n_sparse: int = 992, dense_a: int = 32,
 
 
 def main():
+    from repro import runtime
+
+    runtime.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--m", type=int, default=256)
     ap.add_argument("--n-sparse", type=int, default=992)

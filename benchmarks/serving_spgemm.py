@@ -311,6 +311,9 @@ def bench_resilience(n, density, reqs, reps=3):
 
 
 def main():
+    from repro import runtime
+
+    runtime.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=64)
     ap.add_argument("--density", type=float, default=0.05)

@@ -125,6 +125,9 @@ def rank_check(a: CSC, b: CSC, auto_plan, constants, reps: int) -> dict:
 
 
 def main():
+    from repro import runtime
+
+    runtime.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--m", type=int, default=256)
     ap.add_argument("--n-sparse", type=int, default=4032)

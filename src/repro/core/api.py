@@ -4,8 +4,9 @@ Methods mirror the paper's evaluated algorithms, plus ``method="auto"`` —
 the self-tuning entry point (DESIGN.md §8): the operands are sliced into a
 2D tile grid and every tile runs the method an analytical cost model picks
 for that tile's work profile.  ``backend="host"`` runs the faithful numpy
-executors; ``backend="pallas"`` runs the TPU kernels (interpret mode on
-CPU).  Default parameters are the paper's best settings.
+executors; ``backend="pallas"`` runs the TPU kernels (compiled on a TPU,
+interpreted on the CPU — ``repro.runtime``).  Default parameters are the
+paper's best settings.
 
 ``spgemm`` is a thin wrapper over the plan/execute split (DESIGN.md §6): it
 builds — or fetches from a bounded LRU keyed on pattern fingerprints — a
@@ -52,7 +53,7 @@ DEFAULT_METHOD = "h-hash-256/256"
 PLAN_CACHE_SIZE = 64
 _PLAN_CACHE: "OrderedDict[tuple, object]" = OrderedDict()
 _CACHE_STATS = {"hits": 0, "misses": 0, "evictions": 0, "wasted_builds": 0,
-                "listener_errors": 0, "wait_timeouts": 0}
+                "listener_errors": 0, "wait_timeouts": 0, "host_fallbacks": 0}
 
 # Default bound (seconds) on how long a synchronous caller may wait on
 # ANOTHER thread's in-flight build of the same key before _build_once
@@ -117,6 +118,12 @@ def plan_cache_clear() -> None:
             _CACHE_STATS[k] = 0
 
 
+def _count_host_fallback() -> None:
+    """Count one device-plan execution that ran on the host instead."""
+    with _CACHE_LOCK:
+        _CACHE_STATS["host_fallbacks"] += 1
+
+
 def register_eviction_listener(fn) -> None:
     """Register ``fn(keys, reason)`` for post-shrink eviction batches.
 
@@ -168,7 +175,9 @@ def plan_cache_info() -> dict:
     :func:`plan_cache_resize`, and ``builders`` lists each live
     ``PlanBuilder``'s :meth:`~repro.core.plan_builder.PlanBuilder.info`
     (queue depth, retries, timeouts, recycled workers, backpressure
-    policy).
+    policy).  ``host_fallbacks`` counts device-plan executions (jax,
+    pallas fused) that ran on the host stream because the plan's stream
+    was above its guard.
     """
     with _CACHE_LOCK:
         lookups = _CACHE_STATS["hits"] + _CACHE_STATS["misses"]
@@ -356,7 +365,7 @@ def _single_plan_key(a: CSC, b: CSC, method: str, backend: str,
     elif stream_limit is not None:
         limit = int(stream_limit)
     else:
-        limit = _fast.STREAM_MAX_PRODUCTS
+        limit = _fast.default_stream_limit(contract.device_resident)
     return (pattern_fingerprint(a), pattern_fingerprint(b), method, backend,
             tuple(sorted(params.items())), limit)
 
@@ -452,10 +461,11 @@ def _cached_tiled_plan(a: CSC, b: CSC, backend: str, tile,
     # calibration must not alias picks ranked under defaults
     from repro.core import profile
 
+    contract = backends.get_backend(backend)
     key = (pattern_fingerprint(a), pattern_fingerprint(b), "auto", backend,
            spec, cands,
-           _fast.STREAM_MAX_PRODUCTS
-           if backends.get_backend(backend).carries_stream else None,
+           _fast.default_stream_limit(contract.device_resident)
+           if contract.carries_stream else None,
            profile.current_profile().tag)
     return _build_once(
         key,
@@ -473,7 +483,7 @@ def _mesh_plan_key(a: CSC, b: CSC, shards, tile,
     from repro.core import profile
 
     n_shards = len(jax.devices()) if shards is None else int(shards)
-    limit = (_fast.STREAM_MAX_PRODUCTS if stream_limit is None
+    limit = (_fast.default_stream_limit(device=True) if stream_limit is None
              else int(stream_limit))
     # the profile tag rides along for the same reason as in the tiled key:
     # the LPT shard placement is ranked on the profile's constants

@@ -99,7 +99,7 @@ class CostConstants:
     # fused Pallas stream kernel (core/pallas_stream.py): one launch for
     # the whole numeric phase.  Constants are the honest CI-container
     # numbers (``benchmarks/tiled.py --calibrate``), where the kernel runs
-    # under pallas_call(interpret=True) and the [block, block] one-hot
+    # in the Pallas interpreter and the [block, block] one-hot
     # contraction is emulated on CPU — per-product cost sits ~40x above
     # the numpy stream's, so auto never picks "fused" here.  Re-calibrate
     # on a real device, where the MXU absorbs the one-hot matmul and this
@@ -208,14 +208,14 @@ def _host_cost(stats: TileStats, method: str, c: CostConstants) -> float:
         # guard-tripped: every call rebuilds the stream transiently
         return _guarded_rebuild_cost(flops, c)
     if fam == "jax":
-        if flops <= _fast.STREAM_MAX_PRODUCTS:
+        if flops <= _fast.default_stream_limit(device=True):
             # jitted device stream: one dispatch, flat per-product cost
             return c.jax_base + c.jax_prod * flops
         # guard-tripped jax plans fall back to the host transient rebuild
         # (core/jax_stream.py), so they cost what guarded expand costs
         return _guarded_rebuild_cost(flops, c)
     if fam == "fused":
-        if flops <= _fast.STREAM_MAX_PRODUCTS:
+        if flops <= _fast.default_stream_limit(device=True):
             # single fused kernel launch: one dispatch, flat per-product
             return c.fused_base + c.fused_prod * flops
         # guard-tripped fused executions fall back to the host transient
@@ -311,7 +311,7 @@ def estimate_mesh_cost(stats: TileStats, n_shards: int,
     d = max(int(n_shards), 1)
     flops = stats.flops
     per_shard = -(-flops // d)
-    if per_shard <= _fast.STREAM_MAX_PRODUCTS:
+    if per_shard <= _fast.default_stream_limit(device=True):
         compute = c.jax_base + c.jax_prod * per_shard
     else:
         compute = _guarded_rebuild_cost(per_shard, c)
@@ -339,7 +339,7 @@ def should_distribute(stats: TileStats, n_shards: int,
     if constants is None:
         _note_if_default("mesh", AUTO_CANDIDATES["mesh"])
     c = _resolve_constants(constants)
-    limit = (_fast.STREAM_MAX_PRODUCTS if shard_limit is None
+    limit = (_fast.default_stream_limit(device=True) if shard_limit is None
              else int(shard_limit))
     if stats.flops > limit:
         return True

@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import runtime
 from repro.core import backends, fast, jax_stream, naive, pallas_stream
 from repro.core.backends import check_engine, default_engine, get_backend
 from repro.core.expand import spgemm_expand
@@ -69,7 +70,7 @@ ENGINES = backends.engine_spellings()
 
 # (backend, resolved engine) -> (execute_fn, execute_batched_fn); the
 # executor half of the backend registry.  Uniform signature:
-# fn(plan, a_values, b_values, *, interpret, stats, validate)
+# fn(plan, a_values, b_values, *, stats, validate)
 _DISPATCH: dict = {}
 
 
@@ -101,7 +102,7 @@ def _check_engine(plan, engine: str | None) -> None:
 
 
 def execute(plan: SpgemmPlan, a_values, b_values, *,
-            interpret: bool = True, stats: dict | None = None,
+            stats: dict | None = None,
             validate: str | None = None,
             engine: str | None = None) -> CSC:
     """C = A @ B for new numeric values on the plan's sparsity patterns.
@@ -118,12 +119,11 @@ def execute(plan: SpgemmPlan, a_values, b_values, *,
     """
     eng = resolve_engine(plan, engine)
     fn, _ = _DISPATCH[(plan.backend, eng)]
-    return fn(plan, a_values, b_values, interpret=interpret, stats=stats,
-              validate=validate)
+    return fn(plan, a_values, b_values, stats=stats, validate=validate)
 
 
 def execute_batched(plan: SpgemmPlan, a_values, b_values, *,
-                    interpret: bool = True, stats: dict | None = None,
+                    stats: dict | None = None,
                     validate: str | None = None,
                     engine: str | None = None) -> list:
     """B same-pattern multiplies through one execution of the plan.
@@ -145,8 +145,7 @@ def execute_batched(plan: SpgemmPlan, a_values, b_values, *,
     """
     eng = resolve_engine(plan, engine)
     _, fn = _DISPATCH[(plan.backend, eng)]
-    return fn(plan, a_values, b_values, interpret=interpret, stats=stats,
-              validate=validate)
+    return fn(plan, a_values, b_values, stats=stats, validate=validate)
 
 
 def _check_batch(av, bv) -> int:
@@ -165,9 +164,8 @@ def _check_batch(av, bv) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _host_naive(plan, a_values, b_values, *, interpret=True, stats=None,
+def _host_naive(plan, a_values, b_values, *, stats=None,
                 validate=None) -> CSC:
-    del interpret
     plan.a.check_compatible(a_values, validate)
     plan.b.check_compatible(b_values, validate)
     if stats is not None:
@@ -175,18 +173,16 @@ def _host_naive(plan, a_values, b_values, *, interpret=True, stats=None,
     return _execute_host(plan, a_values, b_values)
 
 
-def _host_stream(plan, a_values, b_values, *, interpret=True, stats=None,
+def _host_stream(plan, a_values, b_values, *, stats=None,
                  validate=None) -> CSC:
-    del interpret
     plan.a.check_compatible(a_values, validate)
     plan.b.check_compatible(b_values, validate)
     return fast.execute_stream(plan, _values(a_values), _values(b_values),
                                stats=stats)
 
 
-def _host_naive_batched(plan, a_values, b_values, *, interpret=True,
-                        stats=None, validate=None) -> list:
-    del interpret
+def _host_naive_batched(plan, a_values, b_values, *, stats=None,
+                        validate=None) -> list:
     av = plan.a.batched_values(a_values, validate)
     bv = plan.b.batched_values(b_values, validate)
     batch = _check_batch(av, bv)
@@ -202,9 +198,8 @@ def _host_naive_batched(plan, a_values, b_values, *, interpret=True,
     return out
 
 
-def _host_stream_batched(plan, a_values, b_values, *, interpret=True,
-                         stats=None, validate=None) -> list:
-    del interpret
+def _host_stream_batched(plan, a_values, b_values, *, stats=None,
+                         validate=None) -> list:
     av = plan.a.batched_values(a_values, validate)
     bv = plan.b.batched_values(b_values, validate)
     batch = _check_batch(av, bv)
@@ -312,7 +307,7 @@ def _record_tile_stats(plan, stats, child_stats):
             (s.get("peak_tile_elems", 0) for s in child_stats), default=0)
 
 
-def execute_tiled(plan, a_values, b_values, *, interpret: bool = True,
+def execute_tiled(plan, a_values, b_values, *,
                   stats: dict | None = None,
                   validate: str | None = None,
                   engine: str | None = None) -> CSC:
@@ -345,7 +340,7 @@ def execute_tiled(plan, a_values, b_values, *, interpret: bool = True,
         cs = {} if (stats is not None
                     and plan.backend == "pallas") else None
         per_block[tile.n].append(_host_child(
-            tile.plan.execute(ta, tb, interpret=interpret, stats=cs,
+            tile.plan.execute(ta, tb, stats=cs,
                               engine=engine if engine is not None
                               else tile.engine)))
         if cs is not None:
@@ -355,7 +350,6 @@ def execute_tiled(plan, a_values, b_values, *, interpret: bool = True,
 
 
 def execute_tiled_batched(plan, a_values, b_values, *,
-                          interpret: bool = True,
                           stats: dict | None = None,
                           validate: str | None = None,
                           engine: str | None = None) -> list:
@@ -381,7 +375,7 @@ def execute_tiled_batched(plan, a_values, b_values, *,
         cs = {} if (stats is not None
                     and plan.backend == "pallas") else None
         outs = tile.plan.execute_batched(
-            ta, tb, interpret=interpret, stats=cs,
+            ta, tb, stats=cs,
             engine=engine if engine is not None else tile.engine)
         for bi, c in enumerate(outs):
             per_block[bi][tile.n].append(_host_child(c))
@@ -484,14 +478,22 @@ def _assemble_batched(batch, cols_rows, cols_vals, shape, dtype) -> list:
 # ---------------------------------------------------------------------------
 
 
+def _check_kernels(lay) -> None:
+    """Refuse the plan's kernels the platform cannot compile, before any
+    group launches (``repro.runtime.check_kernel``)."""
+    for kind in sorted({g.kind for g in lay.groups}):
+        runtime.check_kernel(kind)
+
+
 def _execute_pallas(plan: SpgemmPlan, a_values, b_values, *,
-                    interpret: bool = True, stats: dict | None = None,
+                    stats: dict | None = None,
                     validate: str | None = None) -> CSC:
     from repro.kernels import ops as kops
 
     plan.a.check_compatible(a_values, validate)
     plan.b.check_compatible(b_values, validate)
     lay = plan.pallas
+    _check_kernels(lay)
     m, n = plan.shape
     av = padded_values(_values(a_values), lay.a_gather,
                        lay.a_mask).astype(np.float32, copy=False)
@@ -506,23 +508,22 @@ def _execute_pallas(plan: SpgemmPlan, a_values, b_values, *,
                                g.b_vmask).astype(np.float32, copy=False)
         if g.kind == "spa":
             tile = kops.run_spa(g, a_arrs, g_vals, m=m,
-                                block_cols=lay.block_cols,
-                                interpret=interpret)
+                                block_cols=lay.block_cols)
             builder.add_dense_tile(g.cols, tile)
         elif g.kind == "spars":
             tile = kops.run_spars(g, a_arrs, g_vals, m=m,
-                                  block_cols=lay.block_cols,
-                                  interpret=interpret)
+                                  block_cols=lay.block_cols)
             builder.add_dense_tile(g.cols, tile)
         elif g.kind == "hash":
             keys, vals = kops.run_hash(g, a_arrs, g_vals, m=m,
-                                       block_cols=lay.block_cols,
-                                       interpret=interpret)
+                                       block_cols=lay.block_cols)
             builder.add_hash_tables(g.cols, keys, vals)
         else:
             raise AssertionError(g.kind)
     c = builder.build()
     if stats is not None:
+        stats.update(engine="naive", backend="pallas", device=True,
+                     fallback=None)
         stats["tile_shapes"] = list(builder.tile_shapes)
         stats["peak_tile_elems"] = builder.peak_tile_elems
         stats["n_launches"] = len(lay.groups)
@@ -531,7 +532,6 @@ def _execute_pallas(plan: SpgemmPlan, a_values, b_values, *,
 
 
 def _execute_pallas_batched(plan: SpgemmPlan, a_values, b_values, *,
-                            interpret: bool = True,
                             stats: dict | None = None,
                             validate: str | None = None) -> list:
     from repro.kernels import ops as kops
@@ -540,6 +540,7 @@ def _execute_pallas_batched(plan: SpgemmPlan, a_values, b_values, *,
     bv = plan.b.batched_values(b_values, validate)
     batch = _check_batch(av, bv)
     lay = plan.pallas
+    _check_kernels(lay)
     m, n = plan.shape
     avp = padded_values_batched(av, lay.a_gather,
                                 lay.a_mask).astype(np.float32, copy=False)
@@ -552,23 +553,22 @@ def _execute_pallas_batched(plan: SpgemmPlan, a_values, b_values, *,
                                                          copy=False)
         if g.kind == "spa":
             tiles = kops.run_spa_batched(g, a_arrs, g_vals, m=m,
-                                         block_cols=lay.block_cols,
-                                         interpret=interpret)
+                                         block_cols=lay.block_cols)
             builder.add_dense_tile(g.cols, tiles)
         elif g.kind == "spars":
             tiles = kops.run_spars_batched(g, a_arrs, g_vals, m=m,
-                                           block_cols=lay.block_cols,
-                                           interpret=interpret)
+                                           block_cols=lay.block_cols)
             builder.add_dense_tile(g.cols, tiles)
         elif g.kind == "hash":
             keys, vals = kops.run_hash_batched(g, a_arrs, g_vals, m=m,
-                                               block_cols=lay.block_cols,
-                                               interpret=interpret)
+                                               block_cols=lay.block_cols)
             builder.add_hash_tables(g.cols, keys, vals)
         else:
             raise AssertionError(g.kind)
     out = builder.build()
     if stats is not None:
+        stats.update(engine="naive", backend="pallas", device=True,
+                     fallback=None)
         stats["tile_shapes"] = list(builder.tile_shapes)
         stats["peak_tile_elems"] = builder.peak_tile_elems
         stats["n_launches"] = len(lay.groups)   # independent of the batch

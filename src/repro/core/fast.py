@@ -48,6 +48,24 @@ from repro.sparse.format import CSC, _np, segment_reduce
 DEFAULT_STREAM_MAX_PRODUCTS = 8_000_000
 STREAM_MAX_PRODUCTS = DEFAULT_STREAM_MAX_PRODUCTS
 
+
+def default_stream_limit(device: bool = False) -> int:
+    """The plan-memory guard a plan gets when none is passed (products).
+
+    Host plans use :data:`STREAM_MAX_PRODUCTS`.  Device plans (``device``:
+    the backend's ``device_resident`` flag) use the guard sized from the
+    chip's own memory on a TPU (:func:`repro.runtime.device_stream_limit`)
+    and the host knob elsewhere.
+    """
+    if device:
+        from repro import runtime
+
+        limit = runtime.device_stream_limit()
+        if limit is not None:
+            return limit
+    return STREAM_MAX_PRODUCTS
+
+
 # batched execution: streams up to this many products run the whole value
 # axis through one 2-D gather/reduce pass (amortizing per-call numpy
 # overhead, the regime of small per-tile streams); longer streams loop the

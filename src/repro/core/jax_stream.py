@@ -35,12 +35,15 @@ host streams (``stream_limit`` resolved at plan time).  A guarded jax plan
 executes by falling back to the *host* stream engine (transient rebuild,
 numerically the host stream's result) when the operands are concrete;
 under a trace (``jax.jit``/``jax.grad`` — the operands are tracers) the
-fallback is impossible and a capability error explains the fix.
+fallback is impossible and a capability error explains the fix.  A
+fallback warns and is counted (``plan_cache_info()["host_fallbacks"]``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
+import warnings
 from typing import Optional, Tuple
 
 import jax
@@ -81,6 +84,11 @@ class DeviceStream:
         """Device bytes held by the stream's index arrays."""
         return int(self.a_pos.nbytes + self.b_pos.nbytes
                    + self.seg_ids.nbytes)
+
+    @property
+    def indices(self) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """``(a_pos, b_pos, seg_ids)``: the index arguments of a replay."""
+        return (self.a_pos, self.b_pos, self.seg_ids)
 
 
 def check_int32_stream(plan, s) -> None:
@@ -180,59 +188,74 @@ def _take(values, idx):
 def bilinear_custom_vjp(forward, grad_a, grad_b):
     """``jax.custom_vjp`` wrapper for a bilinear stream contraction.
 
-    ``forward(a_values, b_values)`` is the primal replay; the contraction is
-    bilinear, so its VJP is two more replays through the same frozen plan
-    indices (module docstring): ``grad_a(g, a_values, b_values)`` and
-    ``grad_b(g, a_values, b_values)`` each take the broadcast output
-    cotangent plus both residual operands and return the corresponding
-    operand cotangent (shaped like the primal operand — oversized raw value
-    arrays get oversized cotangents).  Shared by the XLA device stream
-    (:func:`_bilinear_contract`) and the fused Pallas stream
-    (``core.pallas_stream``), which differ only in how a replay is lowered.
-    ``jax.vmap`` composes with the returned function, which is how both
-    batched paths ride one trace for a whole ``[B, nnz]`` value stack.
+    ``forward(idx, a_values, b_values)`` is the primal replay through the
+    plan's frozen index pytree ``idx``; the contraction is bilinear, so its
+    VJP is two more replays through the same indices (module docstring):
+    ``grad_a(idx, g, a_values, b_values)`` and ``grad_b(idx, g, a_values,
+    b_values)`` each take the broadcast output cotangent plus both residual
+    operands and return the corresponding operand cotangent (shaped like
+    the primal operand — oversized raw value arrays get oversized
+    cotangents).  ``idx`` gets no cotangent.  Shared by the XLA device
+    stream (:func:`_bilinear_contract`), the fused Pallas stream
+    (``core.pallas_stream``) and the mesh stream, which differ only in how
+    a replay is lowered.  ``jax.vmap`` composes with the returned function,
+    which is how the batched paths ride one trace for a whole ``[B, nnz]``
+    value stack; :func:`bind_indices` jits it for one plan.
     """
 
     @jax.custom_vjp
-    def contract(a_values, b_values):
-        return forward(a_values, b_values)
+    def contract(idx, a_values, b_values):
+        return forward(idx, a_values, b_values)
 
-    def fwd(a_values, b_values):
-        return contract(a_values, b_values), (a_values, b_values)
+    def fwd(idx, a_values, b_values):
+        return contract(idx, a_values, b_values), (idx, a_values, b_values)
 
     def bwd(residuals, g):
-        a_values, b_values = residuals
-        return (grad_a(g, a_values, b_values),
-                grad_b(g, a_values, b_values))
+        idx, a_values, b_values = residuals
+        return (None, grad_a(idx, g, a_values, b_values),
+                grad_b(idx, g, a_values, b_values))
 
     contract.defvjp(fwd, bwd)
     return contract
 
 
-def _bilinear_contract(dev: DeviceStream):
-    """The custom-vjp gather→multiply→segment-sum contraction for ``dev``."""
+def bind_indices(contract, idx, *, batched: bool = False):
+    """``f(a_values, b_values)``: ``contract`` jitted for one plan's ``idx``.
 
-    def forward(a_values, b_values):
-        prod = _take(a_values, dev.a_pos) * _take(b_values, dev.b_pos)
-        return jax.ops.segment_sum(prod, dev.seg_ids,
-                                   num_segments=dev.num_segments,
-                                   indices_are_sorted=True,
-                                   mode=_IN_BOUNDS)
+    The index arrays are *arguments* of the jitted function, not closure
+    constants: a closure would compile the whole stream into the executable
+    (slow compiles, a second copy in device memory, and an entry too large
+    for the persistent compilation cache).  ``batched`` vmaps the value
+    operands over a leading ``[B]`` axis.
+    """
+    fn = jax.vmap(contract, in_axes=(None, 0, 0)) if batched else contract
+    return functools.partial(jax.jit(fn), idx)
+
+
+def _bilinear_contract(num_segments: int):
+    """The custom-vjp gather→multiply→segment-sum contraction over
+    ``idx = (a_pos, b_pos, seg_ids)`` with ``num_segments`` C slots."""
+
+    def forward(idx, a_values, b_values):
+        a_pos, b_pos, seg_ids = idx
+        prod = _take(a_values, a_pos) * _take(b_values, b_pos)
+        return jax.ops.segment_sum(prod, seg_ids, num_segments=num_segments,
+                                   indices_are_sorted=True, mode=_IN_BOUNDS)
 
     # cotangent per product (a take through seg_ids), then scatter-add
     # through the same frozen indices the forward gathered through; the
     # shared g_prod gather is deduped by XLA CSE across the two replays
-    def grad_a(g, a_values, b_values):
-        g_prod = _take(g, dev.seg_ids)
-        return jax.ops.segment_sum(g_prod * _take(b_values, dev.b_pos),
-                                   dev.a_pos,
+    def grad_a(idx, g, a_values, b_values):
+        a_pos, b_pos, seg_ids = idx
+        g_prod = _take(g, seg_ids)
+        return jax.ops.segment_sum(g_prod * _take(b_values, b_pos), a_pos,
                                    num_segments=a_values.shape[0],
                                    mode=_IN_BOUNDS)
 
-    def grad_b(g, a_values, b_values):
-        g_prod = _take(g, dev.seg_ids)
-        return jax.ops.segment_sum(g_prod * _take(a_values, dev.a_pos),
-                                   dev.b_pos,
+    def grad_b(idx, g, a_values, b_values):
+        a_pos, b_pos, seg_ids = idx
+        g_prod = _take(g, seg_ids)
+        return jax.ops.segment_sum(g_prod * _take(a_values, a_pos), b_pos,
                                    num_segments=b_values.shape[0],
                                    mode=_IN_BOUNDS)
 
@@ -251,8 +274,8 @@ def stream_fn(plan):
         dev = device_stream(plan)
         if dev is None:
             raise _guard_error(plan)
-        memo["jax_contract"] = _bilinear_contract(dev)
-        memo["jax_fn"] = jax.jit(memo["jax_contract"])
+        memo["jax_contract"] = _bilinear_contract(dev.num_segments)
+        memo["jax_fn"] = bind_indices(memo["jax_contract"], dev.indices)
     return memo["jax_fn"]
 
 
@@ -266,7 +289,8 @@ def stream_fn_batched(plan):
     memo = plan._stream_memo
     if "jax_fn_batched" not in memo:
         stream_fn(plan)   # ensures jax_contract (or raises the guard error)
-        memo["jax_fn_batched"] = jax.jit(jax.vmap(memo["jax_contract"]))
+        memo["jax_fn_batched"] = bind_indices(
+            memo["jax_contract"], memo["device"].indices, batched=True)
     return memo["jax_fn_batched"]
 
 
@@ -280,31 +304,54 @@ def _operand_values(operand):
         else operand
 
 
-def execute_jax(plan, a_values, b_values, *, interpret: bool = True,
-                stats: dict | None = None,
+def host_fallback(plan, av, bv, stats: dict | None = None, *,
+                  batch: int | None = None):
+    """Run a guarded device plan on the host stream engine, loudly.
+
+    A plan whose stream tripped the plan-memory guard has no device index
+    arrays; on concrete operands it executes through the host stream
+    (transient rebuild), which warns, counts ``host_fallbacks`` in
+    ``plan_cache_info()`` and reports ``stats["fallback"] = "host"``.
+    Under a trace there is nothing to fall back to: the capability error.
+    ``batch`` marks ``[B, nnz]`` stacks.
+    """
+    if _is_traced(av, bv):
+        raise _guard_error(plan)
+    from repro.core import api
+
+    api._count_host_fallback()
+    warnings.warn(
+        f"{plan.backend!r} plan's product stream is above its plan-memory "
+        f"guard (stream_limit={plan.stream_limit}); executing on the host "
+        "stream engine instead of the device", RuntimeWarning, stacklevel=3)
+    if batch is None:
+        out = fast.execute_stream(plan, np.asarray(av), np.asarray(bv),
+                                  stats=stats)
+    else:
+        out = fast.execute_stream_batched(
+            plan, np.asarray(av)[:, : int(plan.a.col_ptr[-1])],
+            np.asarray(bv)[:, : int(plan.b.col_ptr[-1])], stats=stats)
+    if stats is not None:
+        stats.update(backend=plan.backend, device=False, fallback="host")
+        if batch is not None:
+            stats["batch"] = batch
+    return out
+
+
+def execute_jax(plan, a_values, b_values, *, stats: dict | None = None,
                 validate: str | None = None) -> CSC:
     """Numeric phase of a jax-backend plan (executor dispatch target).
 
     Returns a CSC whose values are a device array on the plan's canonical
-    stream structure.  Guarded plans (``plan.stream is None``) fall back to
-    the host stream engine on concrete operands and raise the capability
-    error under a trace.  ``interpret`` is accepted for signature
-    uniformity and ignored (nothing to interpret — the function is XLA).
+    stream structure.  Guarded plans (``plan.stream is None``) run
+    :func:`host_fallback`.
     """
-    del interpret
     plan.a.check_compatible(a_values, validate)
     plan.b.check_compatible(b_values, validate)
     av = _operand_values(a_values)
     bv = _operand_values(b_values)
     if plan.stream is None:
-        if _is_traced(av, bv):
-            raise _guard_error(plan)
-        out = fast.execute_stream(plan, np.asarray(av), np.asarray(bv),
-                                  stats=stats)
-        if stats is not None:
-            stats["backend"] = "jax"
-            stats["fallback"] = "host"
-        return out
+        return host_fallback(plan, av, bv, stats)
     vals = stream_fn(plan)(av, bv)
     s = plan.stream
     if stats is not None:
@@ -322,27 +369,17 @@ def _batched_operand(pattern, operand, validate):
     return operand.values if isinstance(operand, BatchedCSC) else operand
 
 
-def execute_jax_batched(plan, a_values, b_values, *, interpret: bool = True,
+def execute_jax_batched(plan, a_values, b_values, *,
                         stats: dict | None = None,
                         validate: str | None = None) -> list:
     """Batched numeric phase: B value sets through one vmapped dispatch."""
-    del interpret
     from repro.core.executor import _check_batch   # lazy: executor imports us
 
     av = _batched_operand(plan.a, a_values, validate)
     bv = _batched_operand(plan.b, b_values, validate)
     batch = _check_batch(av, bv)
     if plan.stream is None:
-        if _is_traced(av, bv):
-            raise _guard_error(plan)
-        out = fast.execute_stream_batched(
-            plan, np.asarray(av)[:, : int(plan.a.col_ptr[-1])],
-            np.asarray(bv)[:, : int(plan.b.col_ptr[-1])], stats=stats)
-        if stats is not None:
-            stats["backend"] = "jax"
-            stats["fallback"] = "host"
-            stats["batch"] = batch
-        return out
+        return host_fallback(plan, av, bv, stats, batch=batch)
     vals = stream_fn_batched(plan)(av, bv)
     s = plan.stream
     if stats is not None:

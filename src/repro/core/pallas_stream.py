@@ -1,104 +1,109 @@
-"""Fused Pallas stream kernel: the whole numeric phase in one launch.
+"""Fused Pallas stream kernel: the numeric phase's reduction in one launch.
 
 ``engine="fused"`` lowers a plan's product stream (``core.fast``, DESIGN.md
-§9) to a *single* ``pl.pallas_call``: the product axis ``[P]`` is tiled into
-grid blocks of ``FUSED_BLOCK`` products, and each grid step gathers its
-block's operand values, multiplies, reduces the block's segment partials,
-and accumulates them into the VMEM-resident output::
+§9) to a *single* ``pl.pallas_call``.  The product axis ``[P]`` is cut into
+blocks of ``FUSED_BLOCK`` products; the kernel reduces every block's
+products to per-segment partials, and one plan-static ``segment_sum``
+joins the segments that straddle a block edge::
 
-    per grid step i over products [iT, (i+1)T):
-      prod    = x_vals[idx_x] * y_vals[idx_y] * mask          # gather+FMA
-      partial = onehot(local) @ prod                          # [T] segmented
-      out[seg_first_i : seg_first_i + T] += partial           # accumulate
+    prod      = x_vals[idx_x] * y_vals[idx_y]                 # XLA gather
+    partial_i = onehot(local_i) @ prod_i      (kernel, per block i)  # [T]
+    out       = segment_sum(partial, seg_first_i + t)         # combine
 
-This is the accumulator-resident numeric phase of Nagasaka et al. /
-Gu et al. transplanted to Pallas: where ``backend="jax"`` lowers the same
-contraction to three separate XLA HLOs (gather → multiply → ``segment_sum``)
-with ``[P]``-sized intermediates in HBM, and the original Pallas path
-launches one kernel per plan group from Python, the fused kernel is one
-launch whose intermediates never leave VMEM (DESIGN.md §11).
+where the XLA stream (``backend="jax"``) scatters every product through
+``segment_sum`` directly.
 
-**Why the window accumulate is safe.**  The stream's segment ids are
+**Why the block reduction is safe.**  The stream's segment ids are
 non-decreasing and consecutive (every stored C slot has >= 1 product), so
 within any block of ``T`` products the local ids ``seg - seg_first`` lie in
-``[0, T)`` — each id increment consumes at least one product.  A segment
-straddling a block boundary is handled by the ``+=`` into the resident
-output: its left part lands from block ``i``, its right part from block
-``i+1``, at the same output slot (Pallas grid steps are sequential, and the
-output block is carried across steps — the revisiting guarantee).  This
-"accumulate into the VMEM-resident output" strategy replaces both a
-carried-scratch partial and a host-side per-block combine; DESIGN.md §11
-records why it benched fastest.
+``[0, T)`` — each id increment consumes at least one product.  Block ``i``'s
+partial at slot ``t`` belongs to segment ``seg_first_i + t``; slots past the
+block's last local id hold exact zeros and are pointed at that last
+segment, so the combine's indices stay sorted.
+
+**TPU layout.**  Each grid step reduces ``ROWS`` blocks held as one
+``[ROWS, T]`` tile (``T`` = 128 lanes): for every row the one-hot
+``[T, T]`` is contracted against the products on the MXU at full f32
+precision, so integer-valued inputs stay bit-exact.  Every operand is a
+2-D, lane-aligned block; there is no in-kernel gather and no unaligned
+store (DESIGN.md §11).
 
 **Differentiability.**  The contraction is bilinear, so the backward pass is
-two more fused stream replays of the broadcast cotangent through permuted
-index views (:func:`jax_stream.bilinear_custom_vjp` — the vjp machinery is
-shared with the XLA device stream, only the replay lowering differs).  The
-grad views sort the stream by the differentiated operand's value position;
-positions with zero products would break the ``[0, T)`` window invariant as
-empty segments, so the views reduce into *compact* (rank) ids and a
-plan-static ``out_map`` scatter places them (DESIGN.md §11).
+two more fused replays of the broadcast cotangent through permuted index
+views (:func:`jax_stream.bilinear_custom_vjp`).  The grad views sort the
+stream by the differentiated operand's value position; positions with zero
+products would break the ``[0, T)`` invariant as empty segments, so the
+views reduce into *compact* (rank) ids and a plan-static ``out_map``
+scatter places them.
 
-**Hardware note.**  The in-kernel gather is isolated in :func:`_gather`
-(``jnp.take`` with an in-bounds promise) and the segmented reduction uses
-the one-hot-matmul idiom of ``kernels/spa.py`` — the two points a real-TPU
-port would revisit (Mosaic's arbitrary-gather support / MXU tiling).  Tier-1
-runs the kernel body under ``interpret=True`` (no accelerator in CI), which
-is also the default of every executor below.
+Whether the kernel is interpreted or compiled follows the platform
+(:func:`repro.runtime.interpret_mode`).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core import fast, jax_stream
+from repro import runtime
+from repro.core import jax_stream
 from repro.core.jax_stream import (
     _IN_BOUNDS,
     _guard_error,
-    _is_traced,
     _operand_values,
+    _take,
     bilinear_custom_vjp,
+    bind_indices,
     check_int32_stream,
+    host_fallback,
     stream_seg_ids,
 )
 from repro.sparse.format import CSC
 
-# products per grid block (T): the kernel's VMEM working set per step is
-# O(T) index/value lanes plus the [T, T] one-hot; the output window it
-# accumulates into is T wide.  Overridable for tests (segment-boundary
-# edge cases build plans under tiny blocks); views/functions memoized on a
-# plan record the block they were built with and rebuild on mismatch.
-# DEFAULT_FUSED_BLOCK is the shipped fallback; a calibrated machine
-# profile can retune the live knob to this host's measured argmin via
+# products per block (T): the one-hot a block is reduced with is [T, T],
+# and T is the lane width of every kernel operand.  Overridable for tests
+# (segment-boundary edge cases build plans under tiny blocks); views and
+# functions memoized on a plan record the block they were built with and
+# rebuild on mismatch.  DEFAULT_FUSED_BLOCK is the shipped fallback; a
+# calibrated machine profile can retune the live knob via
 # ``core.profile.apply_tuning`` (DESIGN.md §15).
 DEFAULT_FUSED_BLOCK = 128
 FUSED_BLOCK = DEFAULT_FUSED_BLOCK
 
+# blocks reduced per grid step: one f32 sublane tile of rows
+ROWS = 8
 
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["idx_x", "idx_y", "local", "seg_first", "seg_last",
+                 "out_map"],
+    meta_fields=["n_out", "n_products", "block"])
 @dataclasses.dataclass(frozen=True)
 class FusedView:
-    """Device-resident index arrays of one fused replay (P padded to Pp).
+    """Device-resident index arrays of one fused replay.
 
     The forward view replays the stream in C-slot order (``out_map`` is
-    ``None`` — block partials accumulate straight into the output window).
-    Grad views replay it sorted by the differentiated operand's value
-    position, reduce into compact rank ids, and scatter through ``out_map``
-    (the sorted unique value positions) into the operand-shaped cotangent.
+    ``None``).  Grad views replay it sorted by the differentiated operand's
+    value position, reduce into compact rank ids, and scatter through
+    ``out_map`` (the sorted unique value positions) into the operand-shaped
+    cotangent.  The block axis is padded to a multiple of :data:`ROWS`;
+    padded products are zeros and padded blocks point at the last segment.
+    A pytree: the arrays are the leaves (arguments of a jitted replay), the
+    sizes its static structure.
     """
 
-    idx_x: Optional[jax.Array]      # [Pp] int32 into the x operand
-    idx_y: Optional[jax.Array]      # [Pp] int32 into the y operand
-    local: Optional[jax.Array]      # [Pp] int32 in [0, block): seg - first
-    mask: Optional[jax.Array]       # [Pp] f32 1/0 (0 on the padded tail)
-    seg_first: Optional[jax.Array]  # [nblocks] int32: block's first seg id
-    block_id: Optional[jax.Array]   # [nblocks] int32: 0..nblocks-1
+    idx_x: Optional[jax.Array]      # [P] int32 into the x operand
+    idx_y: Optional[jax.Array]      # [P] int32 into the y operand
+    local: Optional[jax.Array]      # [nb, T] int32 in [0, T): seg - first
+    seg_first: Optional[jax.Array]  # [nb] int32: block's first segment
+    seg_last: Optional[jax.Array]   # [nb] int32: block's last segment
     out_map: Optional[jax.Array]    # [n_out] int32 scatter (grad views)
     n_out: int                      # segments reduced by the kernel
     n_products: int                 # real (unpadded) product count
@@ -106,17 +111,22 @@ class FusedView:
 
     @property
     def n_blocks(self) -> int:
-        return -(-max(self.n_products, 1) // self.block)
+        """Padded block count (a multiple of :data:`ROWS`)."""
+        nb = -(-max(self.n_products, 1) // self.block)
+        return -(-nb // ROWS) * ROWS
 
     @property
     def nbytes(self) -> int:
         """Device bytes held by this view's index arrays."""
         return sum(a.nbytes for a in (self.idx_x, self.idx_y, self.local,
-                                      self.mask, self.seg_first,
-                                      self.block_id, self.out_map)
+                                      self.seg_first, self.seg_last,
+                                      self.out_map)
                    if a is not None)
 
 
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["forward", "grad_a", "grad_b"], meta_fields=["block"])
 @dataclasses.dataclass(frozen=True)
 class FusedStream:
     """The plan's three fused replay views (forward + the two grad views).
@@ -140,7 +150,7 @@ class FusedStream:
 
 def _build_view(idx_x, idx_y, seg, block: int, n_out: int,
                 out_map=None) -> FusedView:
-    """One replay view: pad [P] streams to whole blocks, move to device.
+    """One replay view: block metadata on the host, indices to the device.
 
     ``seg`` must be non-decreasing with unit steps covering ``0..n_out-1``
     (forward: the stream's C-slot ids; grad: compact ranks) — that is what
@@ -148,35 +158,32 @@ def _build_view(idx_x, idx_y, seg, block: int, n_out: int,
     """
     p = len(idx_x)
     if p == 0:
-        return FusedView(None, None, None, None, None, None,
+        return FusedView(None, None, None, None, None,
                          None if out_map is None else jnp.asarray(
                              out_map, jnp.int32),
                          n_out, 0, block)
-    nblocks = -(-p // block)
-    pp = nblocks * block
-
-    def _pad(arr, fill=0):
-        out = np.full(pp, fill, arr.dtype)
-        out[:p] = arr
-        return out
-
-    starts = np.arange(nblocks, dtype=np.int64) * block   # all < p
+    view = FusedView(None, None, None, None, None, None, n_out, p, block)
+    nb = view.n_blocks
     seg = np.asarray(seg, np.int64)
-    seg_first = seg[starts]
-    local = seg - np.repeat(seg_first, block)[:p]
-    mask = np.zeros(pp, np.float32)
-    mask[:p] = 1.0
+    used = -(-p // block)
+    starts = np.arange(used, dtype=np.int64) * block       # all < p
+    ends = np.minimum(starts + block, p) - 1
+    seg_first = np.full(nb, n_out - 1, np.int64)
+    seg_last = np.full(nb, n_out - 1, np.int64)
+    seg_first[:used] = seg[starts]
+    seg_last[:used] = seg[ends]
+    local = np.zeros(nb * block, np.int64)
+    local[:p] = seg - np.repeat(seg_first[:used], block)[:p]
     with jax.ensure_compile_time_eval():
         # the lazy build may run inside a caller's jit trace (the first
         # traced fused execution of a fresh plan); the index arrays must
         # come out concrete — they are plan state shared by every later
         # trace, not constants of this one (same rule as device_stream)
-        dev = (jnp.asarray(_pad(np.asarray(idx_x, np.int32))),
-               jnp.asarray(_pad(np.asarray(idx_y, np.int32))),
-               jnp.asarray(_pad(local.astype(np.int32))),
-               jnp.asarray(mask),
+        dev = (jnp.asarray(np.asarray(idx_x, np.int32)),
+               jnp.asarray(np.asarray(idx_y, np.int32)),
+               jnp.asarray(local.astype(np.int32).reshape(nb, block)),
                jnp.asarray(seg_first.astype(np.int32)),
-               jnp.asarray(np.arange(nblocks, dtype=np.int32)),
+               jnp.asarray(seg_last.astype(np.int32)),
                None if out_map is None
                else jnp.asarray(np.asarray(out_map, np.int32)))
     return FusedView(*dev, n_out=n_out, n_products=p, block=block)
@@ -225,166 +232,148 @@ def fused_stream(plan, block: int | None = None) -> Optional[FusedStream]:
             block=block,
         )
         memo["fused"] = fs
-        # the jitted contraction closes over the views: drop stale entries
-        for k in ("fused_contract", "fused_fn", "fused_fn_batched"):
+        # the jitted replays are bound to the views: drop stale entries
+        for k in ("fused_fn", "fused_fn_batched"):
             memo.pop(k, None)
     return fs
 
 
-def _gather(values, idx):
-    """In-kernel indexed vector load (the hardware-swappable point).
+def _fused_kernel(prod_ref, local_ref, out_ref):
+    """One grid step: ``ROWS`` blocks of products -> ``ROWS`` partial rows.
 
-    A flat gather with the stream's in-bounds promise: exact under
-    ``interpret=True`` (what CI runs); a Mosaic TPU port would swap this
-    for the one-hot MXU gather of ``kernels/spa.py`` or a DMA-based load.
+    ``out[r, t] = sum_c prod[r, c] * [local[r, c] == t]``.  Each row's
+    one-hot is contracted against the whole ``[ROWS, T]`` tile (an
+    ``[8, T] x [T, T]`` MXU pass) and only row ``r`` of the result is kept:
+    every operand stays a lane-aligned 2-D tile.
     """
-    return values.at[idx].get(mode=_IN_BOUNDS)
+    rows, block = prod_ref.shape
+    prod = prod_ref[...]
+    local = local_ref[...]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 0)
+    out = jnp.zeros((rows, block), out_ref.dtype)
+    for r in range(rows):
+        onehot = (slot == local[r:r + 1, :]).astype(prod.dtype)   # [t, c]
+        part = jax.lax.dot_general(
+            prod, onehot, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=out_ref.dtype)
+        out = jnp.where(row == r, part, out)
+    out_ref[...] = out
 
 
-def _fused_kernel(bid_ref, sf_ref, ix_ref, iy_ref, loc_ref, mask_ref,
-                  x_ref, y_ref, out_ref, *, block: int):
-    """One grid step: gather, multiply, reduce, window-accumulate.
-
-    The output block is the whole (padded) result vector, resident across
-    all grid steps; step 0 zero-initializes it.  Grid position comes from
-    the ``block_id`` input (not ``pl.program_id``) so ``jax.vmap`` over the
-    ``pallas_call`` stays well-defined when the batch axis becomes the
-    leading grid dimension (same rule as ``kernels/spa.py``).
-    """
-    @pl.when(bid_ref[0] == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    prod = (_gather(x_ref[...], ix_ref[...])
-            * _gather(y_ref[...], iy_ref[...]) * mask_ref[...])      # [T]
-    # within-block segmented sum as a one-hot contraction (MXU idiom):
-    # partial[r] = sum_c prod[c] * [local[c] == r]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
-    onehot = (iota == loc_ref[...][None, :]).astype(prod.dtype)
-    partial = onehot @ prod                                           # [T]
-    start = sf_ref[0]
-    window = pl.ds(start, block)
-    out_ref[window] = out_ref[window] + partial
+def _block_partials(prod, local):
+    """``[nb, T]`` per-block segment partials of ``[nb, T]`` products."""
+    nb, block = local.shape
+    spec = pl.BlockSpec((ROWS, block), lambda i: (i, 0))
+    return pl.pallas_call(
+        _fused_kernel,
+        grid=(nb // ROWS,),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((nb, block), prod.dtype),
+        interpret=runtime.interpret_mode(),
+    )(prod, local)
 
 
-def _fused_call(view: FusedView, x, y, *, interpret: bool = True):
-    """Run one fused replay: ``[n_out]`` segment sums in one launch."""
+def _fused_call(view: FusedView, x, y):
+    """Run one fused replay: ``[n_out]`` segment sums, one kernel launch."""
     dt = jnp.result_type(x, y)
     if view.n_products == 0:
         return jnp.zeros((view.n_out,), dt)
-    block = view.block
-    # the accumulate window [seg_first, seg_first + T) may run past the
-    # last segment: pad the output by one block and slice it off
-    out_pad = view.n_out + block
-    nblocks = view.n_blocks
-    x = jnp.asarray(x, dt)
-    y = jnp.asarray(y, dt)
-    out = pl.pallas_call(
-        functools.partial(_fused_kernel, block=block),
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (i,)),          # block_id
-            pl.BlockSpec((1,), lambda i: (i,)),          # seg_first
-            pl.BlockSpec((block,), lambda i: (i,)),      # idx_x
-            pl.BlockSpec((block,), lambda i: (i,)),      # idx_y
-            pl.BlockSpec((block,), lambda i: (i,)),      # local
-            pl.BlockSpec((block,), lambda i: (i,)),      # mask
-            pl.BlockSpec(x.shape, lambda i: (0,)),       # x values (whole)
-            pl.BlockSpec(y.shape, lambda i: (0,)),       # y values (whole)
-        ],
-        out_specs=pl.BlockSpec((out_pad,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((out_pad,), dt),
-        interpret=interpret,
-    )(view.block_id, view.seg_first, view.idx_x, view.idx_y, view.local,
-      view.mask.astype(dt), x, y)
-    return out[: view.n_out]
+    block, nb = view.block, view.n_blocks
+    prod = (_take(jnp.asarray(x, dt), view.idx_x)
+            * _take(jnp.asarray(y, dt), view.idx_y))
+    prod = jnp.pad(prod, (0, nb * block - view.n_products))
+    partial = _block_partials(prod.reshape(nb, block), view.local)
+    # slot t of block i is segment seg_first + t; slots past the block's
+    # last segment hold zeros and clamp onto it (the ids stay sorted)
+    target = jnp.minimum(
+        view.seg_first[:, None] + jnp.arange(block, dtype=jnp.int32),
+        view.seg_last[:, None])
+    return jax.ops.segment_sum(partial.reshape(-1), target.reshape(-1),
+                               num_segments=view.n_out,
+                               indices_are_sorted=True, mode=_IN_BOUNDS)
 
 
-def _fused_contract(fs: FusedStream, interpret: bool = True):
-    """The custom-vjp fused contraction: forward + two fused grad replays."""
-
-    def forward(a_values, b_values):
-        return _fused_call(fs.forward, a_values, b_values,
-                           interpret=interpret)
-
-    def _scatter(view, compact, n_primal, dt):
-        if view.out_map is None:      # P == 0: no contributing products
-            return jnp.zeros((n_primal,), dt)
-        return jnp.zeros((n_primal,), dt).at[view.out_map].set(
-            compact, unique_indices=True, mode=_IN_BOUNDS)
-
-    def grad_a(g, a_values, b_values):
-        compact = _fused_call(fs.grad_a, g, b_values, interpret=interpret)
-        return _scatter(fs.grad_a, compact, a_values.shape[0],
-                        compact.dtype)
-
-    def grad_b(g, a_values, b_values):
-        compact = _fused_call(fs.grad_b, g, a_values, interpret=interpret)
-        return _scatter(fs.grad_b, compact, b_values.shape[0],
-                        compact.dtype)
-
-    return bilinear_custom_vjp(forward, grad_a, grad_b)
+def _fused_forward(fs: FusedStream, a_values, b_values):
+    return _fused_call(fs.forward, a_values, b_values)
 
 
-def fused_fn(plan, *, interpret: bool = True, block: int | None = None):
+def _scatter(view: FusedView, compact, n_primal: int):
+    """Place a grad view's compact sums into the operand-shaped cotangent."""
+    out = jnp.zeros((n_primal,), compact.dtype)
+    if view.out_map is None:      # P == 0: no contributing products
+        return out
+    return out.at[view.out_map].set(compact, unique_indices=True,
+                                    mode=_IN_BOUNDS)
+
+
+def _fused_grad_a(fs: FusedStream, g, a_values, b_values):
+    return _scatter(fs.grad_a, _fused_call(fs.grad_a, g, b_values),
+                    a_values.shape[0])
+
+
+def _fused_grad_b(fs: FusedStream, g, a_values, b_values):
+    return _scatter(fs.grad_b, _fused_call(fs.grad_b, g, a_values),
+                    b_values.shape[0])
+
+
+#: the custom-vjp fused contraction ``f(fs, a_values, b_values)``: forward
+#: plus two fused grad replays, every view's arrays passed as arguments
+_FUSED_CONTRACT = bilinear_custom_vjp(_fused_forward, _fused_grad_a,
+                                      _fused_grad_b)
+
+
+def fused_fn(plan, *, block: int | None = None):
     """The plan's jitted fused function ``f(a_values, b_values) -> c_values``.
 
     Pure, jit-compatible, differentiable (shared bilinear custom vjp) —
     the fused twin of :func:`jax_stream.stream_fn`.  Memoized on the plan
-    (keyed on the block/interpret it was built under); guarded plans raise
-    the capability error.
+    (keyed on the block it was built under); guarded plans raise the
+    capability error.
     """
     fs = fused_stream(plan, block)
     if fs is None:
         raise _guard_error(plan)
     memo = plan._stream_memo
-    if memo.get("fused_fn_key") != (fs.block, interpret):
-        memo["fused_contract"] = _fused_contract(fs, interpret=interpret)
-        memo["fused_fn"] = jax.jit(memo["fused_contract"])
-        memo.pop("fused_fn_batched", None)
-        memo["fused_fn_key"] = (fs.block, interpret)
+    if "fused_fn" not in memo:
+        memo["fused_fn"] = bind_indices(_FUSED_CONTRACT, fs)
     return memo["fused_fn"]
 
 
-def fused_fn_batched(plan, *, interpret: bool = True,
-                     block: int | None = None):
+def fused_fn_batched(plan, *, block: int | None = None):
     """Vmapped twin of :func:`fused_fn`: ``[B, nnz]`` stacks, one trace.
 
     ``jit(vmap(contract))`` — the batch axis becomes the leading grid
     dimension of the one fused launch (exactly how ``spa_spgemm_batched``
     batches, DESIGN.md §7), so the launch count stays 1 regardless of B.
     """
-    fused_fn(plan, interpret=interpret, block=block)   # ensures contract
+    fused_fn(plan, block=block)   # builds (or rebuilds) the views
     memo = plan._stream_memo
     if "fused_fn_batched" not in memo:
-        memo["fused_fn_batched"] = jax.jit(jax.vmap(memo["fused_contract"]))
+        memo["fused_fn_batched"] = bind_indices(
+            _FUSED_CONTRACT, memo["fused"], batched=True)
     return memo["fused_fn_batched"]
 
 
-def execute_fused(plan, a_values, b_values, *, interpret: bool = True,
-                  stats: dict | None = None,
+def execute_fused(plan, a_values, b_values, *, stats: dict | None = None,
                   validate: str | None = None) -> CSC:
     """Numeric phase via the fused kernel (executor dispatch target).
 
     One ``pallas_call`` launch; result values are a device array on the
     plan's canonical stream structure.  Guarded plans fall back to the host
-    stream engine on concrete operands and raise the capability error
-    under a trace (same semantics as the jax backend).
+    stream engine on concrete operands (with a warning, counted in
+    ``plan_cache_info()``) and raise the capability error under a trace
+    (same semantics as the jax backend).
     """
     plan.a.check_compatible(a_values, validate)
     plan.b.check_compatible(b_values, validate)
     av = _operand_values(a_values)
     bv = _operand_values(b_values)
     if plan.stream is None:
-        if _is_traced(av, bv):
-            raise _guard_error(plan)
-        out = fast.execute_stream(plan, np.asarray(av), np.asarray(bv),
-                                  stats=stats)
-        if stats is not None:
-            stats["backend"] = plan.backend
-            stats["fallback"] = "host"
-        return out
-    vals = fused_fn(plan, interpret=interpret)(av, bv)
+        return host_fallback(plan, av, bv, stats)
+    vals = fused_fn(plan)(av, bv)
     s = plan.stream
     if stats is not None:
         stats.update(engine="fused", backend=plan.backend, device=True,
@@ -396,7 +385,6 @@ def execute_fused(plan, a_values, b_values, *, interpret: bool = True,
 
 
 def execute_fused_batched(plan, a_values, b_values, *,
-                          interpret: bool = True,
                           stats: dict | None = None,
                           validate: str | None = None) -> list:
     """Batched fused numeric phase: B value sets, still one launch."""
@@ -406,17 +394,8 @@ def execute_fused_batched(plan, a_values, b_values, *,
     bv = jax_stream._batched_operand(plan.b, b_values, validate)
     batch = _check_batch(av, bv)
     if plan.stream is None:
-        if _is_traced(av, bv):
-            raise _guard_error(plan)
-        out = fast.execute_stream_batched(
-            plan, np.asarray(av)[:, : int(plan.a.col_ptr[-1])],
-            np.asarray(bv)[:, : int(plan.b.col_ptr[-1])], stats=stats)
-        if stats is not None:
-            stats["backend"] = plan.backend
-            stats["fallback"] = "host"
-            stats["batch"] = batch
-        return out
-    vals = fused_fn_batched(plan, interpret=interpret)(av, bv)
+        return host_fallback(plan, av, bv, stats, batch=batch)
+    vals = fused_fn_batched(plan)(av, bv)
     s = plan.stream
     if stats is not None:
         stats.update(engine="fused", backend=plan.backend, device=True,

@@ -400,7 +400,7 @@ class SpgemmPlan:
         return (self.a.fingerprint, self.b.fingerprint, self.method,
                 self.backend, self.params, self.stream_limit)
 
-    def execute(self, a_values, b_values, *, interpret: bool = True,
+    def execute(self, a_values, b_values, *,
                 stats: dict | None = None, validate: str | None = None,
                 engine: str | None = None) -> CSC:
         """Numeric phase only: C for new values on the planned patterns.
@@ -412,10 +412,10 @@ class SpgemmPlan:
         """
         from repro.core.executor import execute
 
-        return execute(self, a_values, b_values, interpret=interpret,
-                       stats=stats, validate=validate, engine=engine)
+        return execute(self, a_values, b_values, stats=stats,
+                       validate=validate, engine=engine)
 
-    def execute_batched(self, a_values, b_values, *, interpret: bool = True,
+    def execute_batched(self, a_values, b_values, *,
                         stats: dict | None = None,
                         validate: str | None = None,
                         engine: str | None = None) -> list:
@@ -429,8 +429,8 @@ class SpgemmPlan:
         """
         from repro.core.executor import execute_batched
 
-        return execute_batched(self, a_values, b_values, interpret=interpret,
-                               stats=stats, validate=validate, engine=engine)
+        return execute_batched(self, a_values, b_values, stats=stats,
+                               validate=validate, engine=engine)
 
 
 def _freeze(params: dict) -> tuple:
@@ -461,8 +461,9 @@ def plan_spgemm(
 
     Host plans also carry the product stream (``engine="stream"``, DESIGN.md
     §9), built lazily on first stream access and kept plan-resident while
-    the flop count is within ``stream_limit`` (default: the value of
-    ``fast.STREAM_MAX_PRODUCTS`` at plan time); above it ``plan.stream`` is
+    the flop count is within ``stream_limit`` (default:
+    ``fast.default_stream_limit`` at plan time — on a TPU, device plans
+    are sized from the chip's memory); above it ``plan.stream`` is
     ``None`` and stream executions rebuild it transiently — same results,
     no plan-resident O(flops) memory.
 
@@ -506,8 +507,8 @@ def plan_spgemm(
     # resolve the guard now (it is a mutable module knob) so every plan's
     # lazy stream build is deterministic no matter when it happens; pallas
     # plans carry it too since the fused engine rides the product stream
-    limit = (_fast.STREAM_MAX_PRODUCTS if stream_limit is None
-             else int(stream_limit))
+    limit = (_fast.default_stream_limit(contract.device_resident)
+             if stream_limit is None else int(stream_limit))
     if backend == "pallas":
         pre, layout = _plan_pallas(a, b, method, params, block_cols,
                                    tile_cols)
@@ -525,10 +526,6 @@ def plan_spgemm(
         elif method.startswith("h-"):
             pre = preprocess(a, b, t=params["t"], b_min=params["b_min"],
                              b_max=params["b_max"])
-    # resolve the guard now (it is a mutable module knob) so the plan's
-    # lazy stream build is deterministic no matter when it happens
-    limit = (_fast.STREAM_MAX_PRODUCTS if stream_limit is None
-             else int(stream_limit))
     return SpgemmPlan(method, backend, _freeze(params), a_pat, b_pat,
                       pre, None, limit)
 
@@ -644,7 +641,7 @@ class TiledSpgemmPlan:
                 self.backend, own["tile"], own["candidates"],
                 own["stream_guard"], own.get("profile", "default"))
 
-    def execute(self, a_values, b_values, *, interpret: bool = True,
+    def execute(self, a_values, b_values, *,
                 stats: dict | None = None, validate: str | None = None,
                 engine: str | None = None) -> CSC:
         """Numeric phase: run every tile plan, merge row blocks, stitch.
@@ -654,10 +651,10 @@ class TiledSpgemmPlan:
         """
         from repro.core.executor import execute_tiled
 
-        return execute_tiled(self, a_values, b_values, interpret=interpret,
-                             stats=stats, validate=validate, engine=engine)
+        return execute_tiled(self, a_values, b_values, stats=stats,
+                             validate=validate, engine=engine)
 
-    def execute_batched(self, a_values, b_values, *, interpret: bool = True,
+    def execute_batched(self, a_values, b_values, *,
                         stats: dict | None = None,
                         validate: str | None = None,
                         engine: str | None = None) -> list:
@@ -665,8 +662,8 @@ class TiledSpgemmPlan:
         from repro.core.executor import execute_tiled_batched
 
         return execute_tiled_batched(self, a_values, b_values,
-                                     interpret=interpret, stats=stats,
-                                     validate=validate, engine=engine)
+                                     stats=stats, validate=validate,
+                                     engine=engine)
 
 
 def normalize_tile_spec(tile) -> tuple:
@@ -795,8 +792,8 @@ def plan_spgemm_tiled(
               # steers host/jax per-tile method choices and bounds every
               # child plan's lazy stream build, fused replays included
               ("stream_guard",
-               _fast.STREAM_MAX_PRODUCTS if contract.carries_stream
-               else None),
+               _fast.default_stream_limit(contract.device_resident)
+               if contract.carries_stream else None),
               ("tile", (k_width, n_width)))
     return TiledSpgemmPlan(backend, Pattern.of(a), Pattern.of(b),
                            np.asarray(k_bounds, np.int64),

@@ -623,9 +623,9 @@ def _measure_jax(ladder, reps: int):
 def _measure_fused(scale: float, reps: int, rng):
     """Fused Pallas stream kernel: fused_base + fused_prod*P.
 
-    Small sizes only — on CPU the kernel runs under
-    ``pallas_call(interpret=True)`` and costs minutes per Mproduct; the
-    honest interpret-mode constants keep auto from ever picking "fused"
+    Small sizes only — on CPU the kernel runs in the Pallas interpreter
+    and costs minutes per Mproduct; the honest interpreter-measured
+    constants keep auto from ever picking "fused"
     here, which is exactly what they should do.
     """
     from repro.core.planner import plan_spgemm
@@ -662,7 +662,6 @@ def _measure_comm(scale: float, reps: int):
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec
 
     devices = jax.devices()
@@ -673,7 +672,7 @@ def _measure_comm(scale: float, reps: int):
     for s in (int(8e3 * scale) + d, int(1e5 * scale) + d,
               int(5e5 * scale) + d, int(2e6 * scale) + d):
         s = -(-s // d) * d
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda v: jax.lax.psum_scatter(
                 v[0], "shards", scatter_dimension=0, tiled=True)[None],
             mesh=mesh,

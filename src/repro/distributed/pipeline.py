@@ -79,11 +79,9 @@ def pipelined_apply(mesh: Mesh, stage_fn, stage_params, x_micro,
     n_stages = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
     run = pipeline_forward(stage_fn, n_stages, axis)
     spec_params = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
-    from jax.experimental.shard_map import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         run, mesh=mesh,
         in_specs=(spec_params, P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return fn(stage_params, x_micro)

@@ -51,8 +51,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 import repro.core.fast as _fast
 from repro.core.cost import CostConstants, DEFAULT_CONSTANTS
@@ -62,6 +61,7 @@ from repro.core.jax_stream import (
     _I32_MAX,
     _take,
     bilinear_custom_vjp,
+    bind_indices,
     stream_seg_ids,
 )
 from repro.core.planner import (
@@ -223,24 +223,23 @@ class ShardedSpgemmPlan:
         self.b.check_compatible(b_values)
         return mesh_fn(self)(a_values, b_values)
 
-    def execute(self, a_values, b_values, *, interpret: bool = True,
+    def execute(self, a_values, b_values, *,
                 stats: dict | None = None, validate: str | None = None,
                 engine: str | None = None) -> CSC:
         """Numeric phase through the executor dispatch (one shard_map)."""
         from repro.core.executor import execute
 
-        return execute(self, a_values, b_values, interpret=interpret,
-                       stats=stats, validate=validate, engine=engine)
+        return execute(self, a_values, b_values, stats=stats,
+                       validate=validate, engine=engine)
 
-    def execute_batched(self, a_values, b_values, *, interpret: bool = True,
+    def execute_batched(self, a_values, b_values, *,
                         stats: dict | None = None,
                         validate: str | None = None,
                         engine: str | None = None) -> list:
         """Batched numeric phase (B same-pattern value sets)."""
         from repro.core.executor import execute_batched
 
-        return execute_batched(self, a_values, b_values,
-                               interpret=interpret, stats=stats,
+        return execute_batched(self, a_values, b_values, stats=stats,
                                validate=validate, engine=engine)
 
 
@@ -316,7 +315,7 @@ def plan_spgemm_mesh(
     ``shards`` — mesh size (defaults to every visible device; planning for
     more shards than currently visible is allowed, execution then raises
     with the ``XLA_FLAGS`` fix).  ``shard_limit`` — the *per-shard*
-    plan-memory guard (defaults to ``fast.STREAM_MAX_PRODUCTS``): the grid
+    plan-memory guard (defaults to ``fast.default_stream_limit``): the grid
     is auto-sized so every tile's stream fits it, which is how a multiply
     whose total stream exceeds the single-device guard stays plannable.
     ``tile`` — explicit ``(k_width, n_width)`` grid override (see
@@ -330,7 +329,7 @@ def plan_spgemm_mesh(
     n_shards = len(jax.devices()) if shards is None else int(shards)
     if n_shards < 1:
         raise ValueError(f"shards must be >= 1, got {n_shards}")
-    limit = (_fast.STREAM_MAX_PRODUCTS if shard_limit is None
+    limit = (_fast.default_stream_limit(device=True) if shard_limit is None
              else int(shard_limit))
     if limit < 1:
         raise ValueError(f"shard_limit must be >= 1, got {limit}")
@@ -444,7 +443,8 @@ def shard_stream(plan: ShardedSpgemmPlan) -> ShardStream:
     3. **Stacking** — per device, its tiles' streams concatenate in the
        plan's fixed n-major/k-ascending order, rewritten to global A/B
        value positions, padded to the longest device's length (pads mask
-       off and point at the trash slot past ``nnz_c``).
+       off and point at the trash slot past ``nnz_c``), and row ``d``
+       is placed on mesh device ``d`` alone.
     """
     memo = plan._memo
     if "mesh" in memo:
@@ -520,9 +520,10 @@ def shard_stream(plan: ShardedSpgemmPlan) -> ShardStream:
         bp[d, :L] = b_idx
         sg[d, :L] = seg
         mk[d, :L] = True
+    # row d lives on device d only: each chip holds its own shard's stream
+    rows = NamedSharding(_device_mesh(D), PartitionSpec(MESH_AXIS, None))
     with jax.ensure_compile_time_eval():
-        dev_arrays = (jnp.asarray(ap), jnp.asarray(bp),
-                      jnp.asarray(sg), jnp.asarray(mk))
+        dev_arrays = tuple(jax.device_put(x, rows) for x in (ap, bp, sg, mk))
     memo["mesh"] = ShardStream(
         a_pos=dev_arrays[0], b_pos=dev_arrays[1], seg=dev_arrays[2],
         mask=dev_arrays[3], c_rows=c_rows, c_col_ptr=c_col_ptr,
@@ -577,15 +578,17 @@ def mesh_fn(plan: ShardedSpgemmPlan):
 
     if ss.n_products == 0:
         # nothing to contract: C values are structurally zero (or empty)
-        def forward(av, bv):
+        idx = ()
+
+        def forward(idx, av, bv):
             dt = jnp.result_type(jnp.asarray(av).dtype,
                                  jnp.asarray(bv).dtype)
             return jnp.zeros((nnz_c,), dt)
 
-        def grad_a(g, av, bv):
+        def grad_a(idx, g, av, bv):
             return jnp.zeros_like(jnp.asarray(av))
 
-        def grad_b(g, av, bv):
+        def grad_b(idx, g, av, bv):
             return jnp.zeros_like(jnp.asarray(bv))
     else:
         mesh = _device_mesh(D)
@@ -593,7 +596,7 @@ def mesh_fn(plan: ShardedSpgemmPlan):
         a_pad = D * (-(-max(nnz_a, 1) // D))
         b_pad = D * (-(-max(nnz_b, 1) // D))
         sharded = functools.partial(
-            shard_map, mesh=mesh, check_rep=False,
+            jax.shard_map, mesh=mesh, check_vma=False,
             in_specs=(P(), P(), P(MESH_AXIS), P(MESH_AXIS), P(MESH_AXIS),
                       P(MESH_AXIS)),
             out_specs=P(MESH_AXIS))
@@ -627,7 +630,7 @@ def mesh_fn(plan: ShardedSpgemmPlan):
 
         idx = (ss.a_pos, ss.b_pos, ss.seg, ss.mask)
 
-        def forward(av, bv):
+        def forward(idx, av, bv):
             return _fwd(av, bv, *idx)[:nnz_c]
 
         def _fit(cot, primal, nnz):
@@ -640,16 +643,16 @@ def mesh_fn(plan: ShardedSpgemmPlan):
                 return cot
             return jnp.zeros((want,), cot.dtype).at[:nnz].set(cot)
 
-        def grad_a(g, av, bv):
+        def grad_a(idx, g, av, bv):
             gp = _pad_to(g, s_pad)
             return _fit(_grad_a(gp, bv, *idx), av, nnz_a)
 
-        def grad_b(g, av, bv):
+        def grad_b(idx, g, av, bv):
             gp = _pad_to(g, s_pad)
             return _fit(_grad_b(gp, av, *idx), bv, nnz_b)
 
-    memo["mesh_contract"] = bilinear_custom_vjp(forward, grad_a, grad_b)
-    memo["mesh_fn"] = jax.jit(memo["mesh_contract"])
+    memo["mesh_fn"] = bind_indices(
+        bilinear_custom_vjp(forward, grad_a, grad_b), idx)
     return memo["mesh_fn"]
 
 
@@ -662,22 +665,20 @@ def _record_stats(plan, ss, stats):
     if stats is None:
         return
     stats.update(engine="stream", backend="mesh", device=True,
-                 shards=plan.n_shards, grid=plan.grid,
+                 fallback=None, shards=plan.n_shards, grid=plan.grid,
                  stream_products=ss.n_products,
                  per_device_products=ss.per_device.tolist(),
                  imbalance=plan.imbalance, result_shape=ss.shape)
 
 
-def execute_mesh(plan, a_values, b_values, *, interpret: bool = True,
+def execute_mesh(plan, a_values, b_values, *,
                  stats: dict | None = None,
                  validate: str | None = None) -> CSC:
     """Numeric phase of a mesh plan (executor dispatch target).
 
     One jitted ``shard_map`` dispatch; the result's values are a device
-    array on the plan's canonical global output structure.  ``interpret``
-    is accepted for signature uniformity and ignored.
+    array on the plan's canonical global output structure.
     """
-    del interpret
     plan.a.check_compatible(a_values, validate)
     plan.b.check_compatible(b_values, validate)
     av = _operand_values(a_values)
@@ -689,7 +690,6 @@ def execute_mesh(plan, a_values, b_values, *, interpret: bool = True,
 
 
 def execute_mesh_batched(plan, a_values, b_values, *,
-                         interpret: bool = True,
                          stats: dict | None = None,
                          validate: str | None = None) -> list:
     """Batched numeric phase: B value sets through the sharded replay.
@@ -698,7 +698,6 @@ def execute_mesh_batched(plan, a_values, b_values, *,
     collective-bearing ``shard_map`` does not ride ``vmap``); results are
     bit-identical to looping :func:`execute_mesh` by construction.
     """
-    del interpret
     from repro.core.executor import _check_batch
 
     plan.a.check_batched_compatible(a_values, validate)

@@ -8,10 +8,11 @@
 - ops.py          jit'd wrappers + spgemm_pallas host API
 
 All kernels are written for TPU (pl.pallas_call + BlockSpec VMEM tiling,
-PrefetchScalarGridSpec for CSC pointer structure) and validated on CPU in
-interpret mode.  Each SpGEMM kernel also has a ``*_batched`` variant that
-carries a leading batch axis on the value operands only — B same-pattern
-multiplies in one launch (DESIGN.md §7).
+PrefetchScalarGridSpec for CSC pointer structure).  ``repro.runtime`` picks
+the mode: interpreted on the CPU, compiled on a TPU, where the HASH kernel
+is refused until it is ported.  Each SpGEMM kernel also has a
+``*_batched`` variant that carries a leading batch axis on the value
+operands only — B same-pattern multiplies in one launch (DESIGN.md §7).
 """
 
 from repro.kernels.spa import spa_spgemm, spa_spgemm_batched

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import runtime
+
 
 def _bsr_kernel(idx_ref, nnz_ref,         # scalar prefetch (SMEM)
                 blocks_ref, x_ref, o_ref, *, bk: int):
@@ -41,9 +43,8 @@ def _bsr_kernel(idx_ref, nnz_ref,         # scalar prefetch (SMEM)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("bn", "interpret"))
-def bsr_spmm(block_idx, block_nnz, blocks, x, *, bn: int = 128,
-             interpret: bool = True):
+    jax.jit, static_argnames=("bn",))
+def bsr_spmm(block_idx, block_nnz, blocks, x, *, bn: int = 128):
     """[n_rb*bm, N] = BSR(A) @ x.
 
     block_idx [n_rb, max_nb] int32, block_nnz [n_rb] int32,
@@ -65,7 +66,7 @@ def bsr_spmm(block_idx, block_nnz, blocks, x, *, bn: int = 128,
         functools.partial(_bsr_kernel, bk=bk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_rb * bm, n), x.dtype),
-        interpret=interpret,
+        interpret=runtime.interpret_mode(),
     )(block_idx, block_nnz, blocks, x)
 
 

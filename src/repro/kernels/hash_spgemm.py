@@ -11,6 +11,10 @@ Collision handling: all lanes probe in lock-step; a bounded fori over
 MAX_PROBES resolves each lane's slot (first matching-or-empty), mirroring the
 paper's observation that one collision stalls all VL lanes for one probe
 round. MAX_PROBES = H makes the bound exact.
+
+Not ported to the TPU yet: the v5e compiler aborts the process on this
+kernel, so :func:`repro.runtime.check_kernel` refuses it there before
+tracing (it runs interpreted on the CPU).
 """
 
 from __future__ import annotations
@@ -22,6 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import runtime
 from repro.core.analysis import HASH_C
 
 _EMPTY = -1
@@ -99,15 +104,15 @@ def _hash_kernel(steps_ref,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("m", "h", "block_cols", "interpret"))
+    jax.jit, static_argnames=("m", "h", "block_cols"))
 def hash_spgemm(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, steps,
-                *, m: int, h: int, block_cols: int = 128,
-                interpret: bool = True):
+                *, m: int, h: int, block_cols: int = 128):
     """Per-lane hash tables (keys [h, n_b], vals [h, n_b]), HASH dataflow.
 
     ``h`` must be a power of two >= max Op_j of any processed column (the
     host blocking pass guarantees it; tables never overflow).
     """
+    runtime.check_kernel("hash")
     n_a, za = a_rows.shape
     n_b, zb = b_rows.shape
     assert n_b % block_cols == 0, (n_b, block_cols)
@@ -138,15 +143,14 @@ def hash_spgemm(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, steps,
             jax.ShapeDtypeStruct((h, n_b), jnp.int32),
             jax.ShapeDtypeStruct((h, n_b), a_vals.dtype),
         ],
-        interpret=interpret,
+        interpret=runtime.interpret_mode(),
     )(steps, b_rows, b_vals, b_nnz, a_rows, a_vals, a_nnz)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("m", "h", "block_cols", "interpret"))
+    jax.jit, static_argnames=("m", "h", "block_cols"))
 def hash_spgemm_batched(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, steps,
-                        *, m: int, h: int, block_cols: int = 128,
-                        interpret: bool = True):
+                        *, m: int, h: int, block_cols: int = 128):
     """Batched HASH: tables (keys, vals) [B, h, n_b] for B value sets.
 
     Probing positions depend only on row indices, so every batch element
@@ -155,7 +159,6 @@ def hash_spgemm_batched(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, steps,
     are shared, and all B multiplies run in one vmapped launch
     (DESIGN.md §7).
     """
-    f = functools.partial(hash_spgemm, m=m, h=h, block_cols=block_cols,
-                          interpret=interpret)
+    f = functools.partial(hash_spgemm, m=m, h=h, block_cols=block_cols)
     return jax.vmap(f, in_axes=(None, 0, None, None, 0, None, None))(
         a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, steps)

@@ -35,76 +35,72 @@ def device_operand(rows: np.ndarray, vals: np.ndarray, nnz: np.ndarray):
     return (jnp.asarray(rows), jnp.asarray(vals), jnp.asarray(nnz))
 
 
-def run_spa(group, a_arrs, b_vals, *, m: int, block_cols: int,
-            interpret: bool = True) -> np.ndarray:
+def run_spa(group, a_arrs, b_vals, *, m: int, block_cols: int) -> np.ndarray:
     """Dense [m, n_real] tile for one SPA plan group."""
     a_rows, a_vals, a_nnz = a_arrs
     out = spa_spgemm(
         a_rows, a_vals, a_nnz,
         jnp.asarray(group.b_rows), jnp.asarray(b_vals),
         jnp.asarray(group.b_nnz),
-        m=m, block_cols=block_cols, interpret=interpret)
+        m=m, block_cols=block_cols)
     return np.asarray(out)[:, : group.n_real]
 
 
-def run_spars(group, a_arrs, b_vals, *, m: int, block_cols: int,
-              interpret: bool = True) -> np.ndarray:
+def run_spars(group, a_arrs, b_vals, *, m: int, block_cols: int) -> np.ndarray:
     """Dense [m, n_real] tile for one SPARS plan group (plan-provided steps)."""
     a_rows, a_vals, a_nnz = a_arrs
     out, _flags = spars_spgemm(
         a_rows, a_vals, a_nnz,
         jnp.asarray(group.b_rows), jnp.asarray(b_vals),
         jnp.asarray(group.b_nnz), jnp.asarray(group.steps),
-        m=m, block_cols=block_cols, interpret=interpret)
+        m=m, block_cols=block_cols)
     return np.asarray(out)[:, : group.n_real]
 
 
-def run_hash(group, a_arrs, b_vals, *, m: int, block_cols: int,
-             interpret: bool = True):
+def run_hash(group, a_arrs, b_vals, *, m: int, block_cols: int):
     """Hash tables (keys, vals) [H, n_real] for one HASH plan group."""
     a_rows, a_vals, a_nnz = a_arrs
     keys, vals = hash_spgemm(
         a_rows, a_vals, a_nnz,
         jnp.asarray(group.b_rows), jnp.asarray(b_vals),
         jnp.asarray(group.b_nnz), jnp.asarray(group.steps),
-        m=m, h=int(group.h), block_cols=block_cols, interpret=interpret)
+        m=m, h=int(group.h), block_cols=block_cols)
     return (np.asarray(keys)[:, : group.n_real],
             np.asarray(vals)[:, : group.n_real])
 
 
-def run_spa_batched(group, a_arrs, b_vals, *, m: int, block_cols: int,
-                    interpret: bool = True) -> np.ndarray:
+def run_spa_batched(group, a_arrs, b_vals, *, m: int,
+                    block_cols: int) -> np.ndarray:
     """Dense [B, m, n_real] tiles for one SPA plan group, one launch."""
     a_rows, a_vals, a_nnz = a_arrs          # a_vals carries the batch axis
     out = spa_spgemm_batched(
         a_rows, a_vals, a_nnz,
         jnp.asarray(group.b_rows), jnp.asarray(b_vals),
         jnp.asarray(group.b_nnz),
-        m=m, block_cols=block_cols, interpret=interpret)
+        m=m, block_cols=block_cols)
     return np.asarray(out)[:, :, : group.n_real]
 
 
-def run_spars_batched(group, a_arrs, b_vals, *, m: int, block_cols: int,
-                      interpret: bool = True) -> np.ndarray:
+def run_spars_batched(group, a_arrs, b_vals, *, m: int,
+                      block_cols: int) -> np.ndarray:
     """Dense [B, m, n_real] tiles for one SPARS plan group, one launch."""
     a_rows, a_vals, a_nnz = a_arrs
     out, _flags = spars_spgemm_batched(
         a_rows, a_vals, a_nnz,
         jnp.asarray(group.b_rows), jnp.asarray(b_vals),
         jnp.asarray(group.b_nnz), jnp.asarray(group.steps),
-        m=m, block_cols=block_cols, interpret=interpret)
+        m=m, block_cols=block_cols)
     return np.asarray(out)[:, :, : group.n_real]
 
 
-def run_hash_batched(group, a_arrs, b_vals, *, m: int, block_cols: int,
-                     interpret: bool = True):
+def run_hash_batched(group, a_arrs, b_vals, *, m: int, block_cols: int):
     """Hash tables (keys, vals) [B, H, n_real] for one HASH plan group."""
     a_rows, a_vals, a_nnz = a_arrs
     keys, vals = hash_spgemm_batched(
         a_rows, a_vals, a_nnz,
         jnp.asarray(group.b_rows), jnp.asarray(b_vals),
         jnp.asarray(group.b_nnz), jnp.asarray(group.steps),
-        m=m, h=int(group.h), block_cols=block_cols, interpret=interpret)
+        m=m, h=int(group.h), block_cols=block_cols)
     return (np.asarray(keys)[:, :, : group.n_real],
             np.asarray(vals)[:, :, : group.n_real])
 
@@ -113,8 +109,7 @@ def spgemm_pallas(
     a: CSC, b: CSC, method: str = "spa", *, t: float = 40.0,
     b_min: int | None = None, b_max: int | None = None,
     accumulator: str | None = None, block_cols: int = 128,
-    tile_cols: int | None = None, interpret: bool = True,
-    tile=None, plan=None,
+    tile_cols: int | None = None, tile=None, plan=None,
 ) -> CSC:
     """C = A @ B on the Pallas backend (plan once, execute once).
 
@@ -154,4 +149,4 @@ def spgemm_pallas(
             plan = plan_spgemm(a, b, method, backend="pallas", t=t,
                                b_min=b_min, b_max=b_max,
                                block_cols=block_cols, tile_cols=tile_cols)
-    return plan.execute(a, b, interpret=interpret)
+    return plan.execute(a, b)

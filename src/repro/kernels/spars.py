@@ -12,6 +12,11 @@ independence argument by layout).
 The per-block trip count (max Op_j in the block) is data-dependent; it rides
 in as a scalar-prefetch operand per grid step, exactly how a production TPU
 kernel consumes CSC pointer structure (PrefetchScalarGridSpec).
+
+Layout as in ``kernels/spa.py``: the lanes are the block's C columns, B and
+A arrive transposed (:func:`~repro.kernels.spa.lane_major`), and the cursor
+vectors are ``[1, L]`` rows.  A's column lengths ride as one extra row of
+the A row table, so one MXU pass gathers row ids and lengths together.
 """
 
 from __future__ import annotations
@@ -23,44 +28,54 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import runtime
+from repro.kernels.spa import _HIGHEST, _round_up, lane_major
+
+
+def _pick(sel, table):
+    """``[1, L]`` row of ``table`` chosen per lane by a one-hot ``sel``."""
+    return jnp.sum(jnp.where(sel, table, 0), axis=0, keepdims=True)
+
 
 def _spars_kernel(steps_ref,            # scalar prefetch: [n_blocks] int32
                   b_rows_ref, b_vals_ref, b_nnz_ref,
-                  a_rows_ref, a_vals_ref, a_nnz_ref,
-                  out_ref, flags_ref, *, m: int, za: int, n_a: int):
-    L, zb = b_rows_ref.shape
+                  a_tab_ref, a_vals_ref,
+                  out_ref, flags_ref, *, za: int):
+    zb, L = b_rows_ref.shape
+    zt, n_a = a_tab_ref.shape
+    m = out_ref.shape[0]
     steps = steps_ref[pl.program_id(0)]
-    a_rows_f = a_rows_ref[...].astype(jnp.float32)
+    a_tab = a_tab_ref[...]            # [zt, n_a] f32: row ids, then lengths
     a_vals = a_vals_ref[...]
-    a_nnz_f = a_nnz_ref[...].astype(jnp.float32)
-    b_nnz = b_nnz_ref[...]
-    iota_na = jax.lax.broadcasted_iota(jnp.int32, (L, n_a), 1)
-    iota_zb = jax.lax.broadcasted_iota(jnp.int32, (L, zb), 1)
-    iota_za = jax.lax.broadcasted_iota(jnp.int32, (L, za), 1)
+    b_rows = b_rows_ref[...]
+    b_vals = b_vals_ref[...]
+    b_nnz = b_nnz_ref[...]            # [1, L]
+    iota_na = jax.lax.broadcasted_iota(jnp.int32, (n_a, L), 0)
+    iota_zb = jax.lax.broadcasted_iota(jnp.int32, (zb, L), 0)
+    iota_zt = jax.lax.broadcasted_iota(jnp.int32, (zt, L), 0)
     iota_m = jax.lax.broadcasted_iota(jnp.int32, (m, L), 0)
 
     def step(_, carry):
         vidx_b, vcnt_a, acc, flags = carry
-        active = vidx_b < b_nnz                           # [L] vMask
-        # -- indexed vector load of vB (gather via one-hot over this lane's
-        #    B column entries)
-        sel_b = (vidx_b[:, None] == iota_zb).astype(acc.dtype)
-        bk = jnp.round((sel_b * b_rows_ref[...]).sum(1)).astype(jnp.int32)
-        bv = (sel_b * b_vals_ref[...]).sum(1)             # [L]
-        # -- indexed vector load of vA (row gather over the A table, MXU)
-        oh = (bk[:, None] == iota_na).astype(acc.dtype)   # [L, n_a]
-        ar_all = oh @ a_rows_f                            # [L, za]
-        av_all = oh @ a_vals
-        an = jnp.round(oh @ a_nnz_f).astype(jnp.int32)    # [L] col lengths
-        sel_a = (vcnt_a[:, None] == iota_za).astype(acc.dtype)
-        r = jnp.round((sel_a * ar_all).sum(1)).astype(jnp.int32)  # [L]
-        av = (sel_a * av_all).sum(1)
+        active = vidx_b < b_nnz                           # [1, L] vMask
+        # -- indexed vector load of vB (this lane's B column entry)
+        sel_b = iota_zb == vidx_b
+        bk = jnp.round(_pick(sel_b, b_rows)).astype(jnp.int32)
+        bv = _pick(sel_b, b_vals)
+        # -- indexed vector load of vA (A column gather, MXU)
+        oh = (iota_na == bk).astype(jnp.float32)          # [n_a, L]
+        tab = jnp.dot(a_tab, oh, precision=_HIGHEST,
+                      preferred_element_type=jnp.float32)  # [zt, L]
+        vals = jnp.dot(a_vals, oh.astype(a_vals.dtype), precision=_HIGHEST,
+                       preferred_element_type=a_vals.dtype)
+        an = jnp.round(tab[za:za + 1, :]).astype(jnp.int32)   # col lengths
+        sel_a = iota_zt == vcnt_a
+        r = jnp.round(_pick(sel_a, tab)).astype(jnp.int32)    # [1, L]
+        av = _pick(sel_a, vals)
         # -- FMA + indexed store into the [m, L] accumulator
-        contrib = jnp.where(active, av * bv, 0.0)
-        hit = (iota_m == r[None, :]).astype(acc.dtype)
-        hit = hit * active[None, :].astype(acc.dtype)
-        acc = acc + hit * contrib[None, :]
-        flags = jnp.maximum(flags, hit)
+        hit = (iota_m == r) & active
+        acc = acc + jnp.where(hit, av * bv, 0)
+        flags = jnp.maximum(flags, hit.astype(flags.dtype))
         # -- cursor update (Algorithm 3 lines 15-19)
         last = vcnt_a + 1 >= an
         vcnt_a = jnp.where(active & ~last, vcnt_a + 1, 0)
@@ -68,8 +83,8 @@ def _spars_kernel(steps_ref,            # scalar prefetch: [n_blocks] int32
         return vidx_b, vcnt_a, acc, flags
 
     init = (
-        jnp.zeros((L,), jnp.int32),
-        jnp.zeros((L,), jnp.int32),
+        jnp.zeros((1, L), jnp.int32),
+        jnp.zeros((1, L), jnp.int32),
         jnp.zeros((m, L), out_ref.dtype),
         jnp.zeros((m, L), out_ref.dtype),
     )
@@ -78,59 +93,58 @@ def _spars_kernel(steps_ref,            # scalar prefetch: [n_blocks] int32
     flags_ref[...] = flags
 
 
-@functools.partial(
-    jax.jit, static_argnames=("m", "block_cols", "interpret"))
+@functools.partial(jax.jit, static_argnames=("m", "block_cols"))
 def spars_spgemm(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, steps,
-                 *, m: int, block_cols: int = 128, interpret: bool = True):
+                 *, m: int, block_cols: int = 128):
     """Dense C [m, n_b] + flags, SPARS dataflow.
 
     ``steps[i]`` = trip count of block i (max Op_j over its columns, from the
     host-side blocking pre-process). n_b % block_cols == 0.
     """
-    n_a, za = a_rows.shape
-    n_b, zb = b_rows.shape
+    n_b = b_rows.shape[0]
     assert n_b % block_cols == 0, (n_b, block_cols)
     n_blocks = n_b // block_cols
-    kernel = functools.partial(_spars_kernel, m=m, za=za, n_a=n_a)
+    ar, av = lane_major(a_rows, a_vals, a_nnz, lanes=128)
+    za = a_rows.shape[1]
+    if za == ar.shape[0]:             # no padding row left for the lengths
+        ar = jnp.pad(ar, ((0, 8), (0, 0)), constant_values=-1)
+        av = jnp.pad(av, ((0, 8), (0, 0)))
+    # A's column lengths as row ``za`` of the row table
+    a_tab = ar.at[za].set(jnp.pad(a_nnz.astype(jnp.float32),
+                                  (0, ar.shape[1] - a_nnz.shape[0])))
+    br, bv = lane_major(b_rows, b_vals, b_nnz)
+    zb = br.shape[0]
+    m8 = _round_up(m, 8)
+    kernel = functools.partial(_spars_kernel, za=za)
+    lanes = lambda rows: pl.BlockSpec((rows, block_cols), lambda i, s: (0, i))
+    whole = pl.BlockSpec(a_tab.shape, lambda i, s: (0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((block_cols, zb), lambda i, s: (i, 0)),
-            pl.BlockSpec((block_cols, zb), lambda i, s: (i, 0)),
-            pl.BlockSpec((block_cols,), lambda i, s: (i,)),
-            pl.BlockSpec((n_a, za), lambda i, s: (0, 0)),
-            pl.BlockSpec((n_a, za), lambda i, s: (0, 0)),
-            pl.BlockSpec((n_a,), lambda i, s: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((m, block_cols), lambda i, s: (0, i)),
-            pl.BlockSpec((m, block_cols), lambda i, s: (0, i)),
-        ],
+        in_specs=[lanes(zb), lanes(zb), lanes(1), whole, whole],
+        out_specs=[lanes(m8), lanes(m8)],
     )
-    return pl.pallas_call(
+    out, flags = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((m, n_b), a_vals.dtype),
-            jax.ShapeDtypeStruct((m, n_b), a_vals.dtype),
+            jax.ShapeDtypeStruct((m8, n_b), a_vals.dtype),
+            jax.ShapeDtypeStruct((m8, n_b), a_vals.dtype),
         ],
-        interpret=interpret,
-    )(steps, b_rows, b_vals, b_nnz, a_rows, a_vals, a_nnz)
+        interpret=runtime.interpret_mode(),
+    )(steps, br, bv, b_nnz.astype(jnp.int32).reshape(1, n_b), a_tab, av)
+    return out[:m], flags[:m]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("m", "block_cols", "interpret"))
+@functools.partial(jax.jit, static_argnames=("m", "block_cols"))
 def spars_spgemm_batched(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, steps,
-                         *, m: int, block_cols: int = 128,
-                         interpret: bool = True):
+                         *, m: int, block_cols: int = 128):
     """Batched SPARS: C + flags [B, m, n_b] for B same-pattern value sets.
 
     Value operands carry the batch axis (``a_vals [B, n_a, za]``,
     ``b_vals [B, n_b, zb]``); pattern operands and the per-block trip counts
     are shared.  One vmapped launch for all B (DESIGN.md §7).
     """
-    f = functools.partial(spars_spgemm, m=m, block_cols=block_cols,
-                          interpret=interpret)
+    f = functools.partial(spars_spgemm, m=m, block_cols=block_cols)
     return jax.vmap(f, in_axes=(None, 0, None, None, 0, None, None))(
         a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, steps)

@@ -229,7 +229,6 @@ def run_cell(arch: str, shape: ShapeConfig, *, multi_pod: bool,
 def run_pipeline_check(multi_pod: bool = True) -> dict:
     """PP-over-pod compile check on qwen2-0.5b (DESIGN.md §5)."""
     from repro.distributed.pipeline import pipeline_forward
-    from jax.experimental.shard_map import shard_map
     from repro.models.blocks import stage_forward, superblock_table
 
     cfg = get_config("qwen2-0.5b")
@@ -252,8 +251,8 @@ def run_pipeline_check(multi_pod: bool = True) -> dict:
                                    jnp.bfloat16)
     run = pipeline_forward(stage_fn, n_stages, axis="pod")
     spec_p = jax.tree_util.tree_map(lambda _: P("pod"), staged)
-    fn = shard_map(run, mesh=mesh, in_specs=(spec_p, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh, in_specs=(spec_p, P()),
+                       out_specs=P(), check_vma=False)
     t0 = time.time()
     with mesh:
         lowered = jax.jit(fn).lower(staged, x_micro)

@@ -233,7 +233,7 @@ class SparseMatmul:
         out[rows, cols] = np.asarray(c.values, np.float32)
         return out
 
-    def __call__(self, x, *, bn=None, interpret=True):
+    def __call__(self, x, *, bn=None):
         """y = W @ x for x [K, N]."""
         if self.path == "dense":
             return self.dense_w @ x
@@ -242,9 +242,9 @@ class SparseMatmul:
         n = x.shape[1]
         bn = bn or min(128, n)
         return bsr_spmm(self.block_idx, self.block_nnz, self.blocks, x,
-                        bn=bn, interpret=interpret)
+                        bn=bn)
 
-    def batched(self, xs, *, bn=None, interpret=True):
+    def batched(self, xs, *, bn=None):
         """y [B, M, N] = W @ xs[b] for xs [B, K, N] — one launch for all B.
 
         The weight pattern is static (pruned at conversion time), so a batch
@@ -254,7 +254,10 @@ class SparseMatmul:
         leading-grid-dimension launch instead of B Python round-trips.
         """
         if self.path == "dense":
-            return self.dense_w @ xs              # broadcasts over the batch
+            # per-sample [M, K] @ [K, N] products, the loop's exact shapes: a
+            # broadcast [M, K] @ [B, K, N] is one wider GEMM that sums in a
+            # different order
+            return jax.lax.map(lambda x: self.dense_w @ x, xs)
         if self.path == "spgemm":
             # same-pattern batched regime: the plan's vmapped device stream
             return jax.vmap(
@@ -262,7 +265,7 @@ class SparseMatmul:
         n = xs.shape[2]
         bn = bn or min(128, n)
         f = lambda x: bsr_spmm(self.block_idx, self.block_nnz, self.blocks,
-                               x, bn=bn, interpret=interpret)
+                               x, bn=bn)
         return jax.vmap(f)(xs)
 
     @property

@@ -1,0 +1,113 @@
+"""The platform the process runs on, and what follows from it.
+
+Every Pallas call in this package asks :func:`interpret_mode` how to run:
+interpreted on the CPU (the test suite), compiled on a TPU, refused on any
+other platform.  Nothing takes an ``interpret=`` argument, so a TPU process
+can never run a kernel interpreted by accident.
+
+The module also sizes the default device plan-memory guard from the chip's
+own memory (:func:`device_stream_limit`) and points JAX's persistent
+compilation cache at a fixed directory (:func:`enable_compile_cache`).
+Importing it has no side effects; entry points call what they need.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+from pathlib import Path
+
+import jax
+
+#: repository root (``src/repro/runtime.py`` -> ``.``)
+REPO_ROOT = Path(__file__).resolve().parents[2]
+
+#: compile cache directory when ``JAX_COMPILATION_CACHE_DIR`` is unset
+DEFAULT_CACHE_DIR = REPO_ROOT / ".jax_cache"
+
+#: device bytes one product of a plan may pin: the XLA stream's three int32
+#: index arrays (12 B) plus the fused engine's three replay views (forward
+#: and two grad views, 12 B each)
+DEVICE_BYTES_PER_PRODUCT = 48
+
+#: share of the chip's memory one plan's stream may take by default; the
+#: rest holds operands, results and the transient products of a replay
+DEVICE_STREAM_SHARE = 0.5
+
+#: Pallas kernels that do not compile for a TPU yet (the compiler aborts the
+#: process on them, so they are refused before it is called)
+TPU_REFUSED_KERNELS = frozenset({"hash"})
+
+
+def platform() -> str:
+    """The default JAX backend: ``"cpu"``, ``"tpu"``, ..."""
+    return jax.default_backend()
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas calls run in the interpreter: exactly on the CPU.
+
+    Raises on a platform that is neither CPU nor TPU: the kernels are
+    written for Mosaic and must not silently run anywhere else.
+    """
+    p = platform()
+    if p == "cpu":
+        return True
+    if p == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run on 'tpu' (compiled) or 'cpu' (interpreted), "
+        f"not on {p!r}")
+
+
+def check_kernel(name: str) -> None:
+    """Refuse a Pallas kernel the TPU compiler cannot build, before tracing.
+
+    On the CPU every kernel runs interpreted.  On a TPU a kernel listed in
+    :data:`TPU_REFUSED_KERNELS` raises ``NotImplementedError`` naming it —
+    it never falls back to the interpreter or to a reference.
+    """
+    if name in TPU_REFUSED_KERNELS and not interpret_mode():
+        raise NotImplementedError(
+            f"the {name!r} Pallas kernel does not compile for "
+            f"{platform()!r} yet; plan a method without it (e.g. 'spa' or "
+            "'spars-*' on backend='pallas') or use backend='jax'")
+
+
+@functools.cache
+def device_stream_limit() -> int | None:
+    """Default per-plan stream guard (products) for device plans, or None.
+
+    On a TPU it is sized from the chip's memory: ``bytes_limit`` from the
+    first device's memory statistics, times :data:`DEVICE_STREAM_SHARE`,
+    over :data:`DEVICE_BYTES_PER_PRODUCT`.  Elsewhere (CPU) ``None``: the
+    host guard ``fast.STREAM_MAX_PRODUCTS`` applies.
+    """
+    if platform() != "tpu":
+        return None
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    if not limit:
+        raise RuntimeError(
+            "the TPU reports no memory bytes_limit; cannot size the device "
+            "plan-memory guard")
+    return int(limit * DEVICE_STREAM_SHARE) // DEVICE_BYTES_PER_PRODUCT
+
+
+def compile_cache_dir() -> Path:
+    """``$JAX_COMPILATION_CACHE_DIR`` if set, else ``<repo>/.jax_cache``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    return Path(env) if env else DEFAULT_CACHE_DIR
+
+
+def enable_compile_cache() -> Path:
+    """Keep JAX's persistent compilation cache in :func:`compile_cache_dir`.
+
+    When the environment variable is set JAX already reads it; this sets
+    no other directory then.  Otherwise the fixed in-repo path is used, so
+    every run of the same checkout finds the same cache.
+    """
+    path = compile_cache_dir()
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        jax.config.update("jax_compilation_cache_dir", str(path))
+    return path

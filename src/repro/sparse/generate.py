@@ -28,7 +28,7 @@ def random_uniform_csc(
         rows[j] = rng.choice(n_rows, size=z, replace=False)
         rows[j].sort()
     vals = rng.uniform(0.5, 1.5, size=(n, z)).astype(dtype)  # bounded away from 0
-    col_ptr = np.arange(0, (n + 1) * z, z, dtype=np.int32)
+    col_ptr = np.arange(n + 1, dtype=np.int32) * z
     return CSC(vals.reshape(-1), rows.reshape(-1), col_ptr, (n_rows, n))
 
 

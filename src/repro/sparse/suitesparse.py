@@ -19,6 +19,7 @@ are stored alongside for Table-1 validation.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 
 import numpy as np
 
@@ -322,7 +323,9 @@ def synthesize_suitesparse(
     """
     if isinstance(spec, str):
         spec = by_name(spec)
-    rng = np.random.default_rng(seed ^ hash(spec.name) % (2**31))
+    # crc32, not hash(): str hashes are salted per process, and the same
+    # seed must give the same matrix in every process
+    rng = np.random.default_rng(seed ^ zlib.crc32(spec.name.encode()))
     deg = _degree_sequence(spec, rng)
     n = spec.n
 

@@ -210,13 +210,30 @@ def test_zero_retrace_after_warmup():
     for _ in range(4):
         v = rng.normal(size=a.nnz).astype(F32)
         fn(v, v)
-    assert fn._cache_size() == 1
+    assert fn.func._cache_size() == 1   # the jitted replay
     # the batched fn is its own single trace per batch shape
     bfn = jax_stream.stream_fn_batched(plan)
     for _ in range(3):
         v = rng.normal(size=(6, a.nnz)).astype(F32)
         bfn(v, v)
-    assert bfn._cache_size() == 1
+    assert bfn.func._cache_size() == 1
+
+
+@pytest.mark.parametrize("engine", [None, "fused"])
+def test_replay_takes_the_stream_as_arguments(engine):
+    """The plan's index arrays are arguments of the jitted replay, not
+    constants compiled into it (which would copy the whole stream into the
+    executable)."""
+    from repro.core.pallas_stream import fused_fn
+
+    a = random_powerlaw_csc(200, 4.0, seed=4)
+    plan = plan_spgemm(a, a, "expand", backend="jax")
+    fn = (jax_stream.stream_fn if engine is None else fused_fn)(plan)
+    (idx,) = fn.args
+    idx_bytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(idx))
+    assert idx_bytes >= 3 * 4 * plan.stream.n_products
+    mem = fn.func.lower(idx, a.values, a.values).compile().memory_analysis()
+    assert mem.argument_size_in_bytes >= idx_bytes
 
 
 # --- guard fallback and capability errors -----------------------------------

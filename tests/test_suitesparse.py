@@ -1,5 +1,10 @@
 """SuiteSparse stand-in generator: published-statistics fidelity (E4 input)."""
 
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -22,6 +27,21 @@ def test_generated_stats_match_published(name):
     assert st.nnz_max == spec.nnz_max
     assert abs(st.nnz_var - spec.nnz_var) <= max(0.15 * spec.nnz_var, 0.3)
     assert abs(st.mult_avg - spec.mult_avg) <= max(0.15 * spec.mult_avg, 1.0)
+
+
+def test_same_seed_same_matrix_in_every_process():
+    """The generator's stream does not depend on the process's salted
+    ``str`` hash: two interpreters with different hash seeds agree."""
+    script = ("import hashlib; from repro.sparse import synthesize_suitesparse;"
+              " m, _ = synthesize_suitesparse('olm1000', seed=0);"
+              " print(hashlib.sha256(m.row_indices.tobytes()).hexdigest())")
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    outs = {subprocess.run([sys.executable, "-c", script], check=True,
+                           capture_output=True, text=True, timeout=300,
+                           env=dict(os.environ, PYTHONHASHSEED=str(salt),
+                                    JAX_PLATFORMS="cpu", PYTHONPATH=src)
+                           ).stdout for salt in (1, 2)}
+    assert len(outs) == 1, outs
 
 
 def test_arrow_structure_forced():
