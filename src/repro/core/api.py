@@ -31,7 +31,7 @@ import time
 import weakref
 from collections import OrderedDict
 
-from repro.core import backends
+from repro.core import backends, spans
 import repro.core.fast as _fast
 from repro.core.cost import AUTO_CANDIDATES
 from repro.core.planner import (
@@ -290,7 +290,7 @@ def plan_cache_peek(key):
         return _PLAN_CACHE.get(key)
 
 
-def _build_once(key, build, timeout: float | None = None):
+def _build_once(key, build, timeout: float | None = None, span=spans.NULL):
     """Fetch ``key`` from the LRU, or run ``build()`` exactly once.
 
     Single-flight across threads: the first requester of a missing key
@@ -308,6 +308,7 @@ def _build_once(key, build, timeout: float | None = None):
     :func:`plan_cache_info`) instead of blocking unboundedly on a doomed
     or wedged owner.  The owner itself runs its build to completion —
     hung *background* builds are the PlanBuilder watchdog's job.
+    ``span`` (:mod:`~repro.core.spans`) gets ``hit`` 1 or 0.
     """
     if timeout is None:
         timeout = DEFAULT_BUILD_TIMEOUT
@@ -319,6 +320,7 @@ def _build_once(key, build, timeout: float | None = None):
                 _PLAN_CACHE.move_to_end(key)
                 _CACHE_STATS["hits"] += 1
                 _NEVER_HIT.discard(key)
+                span.set(hit=1)
                 return plan
             done = _BUILDING.get(key)
             owner = done is None
@@ -326,6 +328,7 @@ def _build_once(key, build, timeout: float | None = None):
                 done = _BUILDING[key] = threading.Event()
                 _CACHE_STATS["misses"] += 1
         if owner:
+            span.set(hit=0)
             try:
                 plan = build()
                 _cache_put(key, plan)
@@ -355,11 +358,6 @@ def _single_plan_key(a: CSC, b: CSC, method: str, backend: str,
     # Pallas plans carry no stream (stream_limit=None), so the knob must
     # not invalidate them.
     contract = backends.get_backend(backend)
-    if contract.canonical_method:
-        # method spellings collapse on such backends (jax: one stream
-        # contraction) — key on the canonical form so they share one entry
-        method = contract.canonical_method
-        params = resolve_params(method)
     if not contract.carries_stream:
         limit = None
     elif stream_limit is not None:
@@ -368,6 +366,17 @@ def _single_plan_key(a: CSC, b: CSC, method: str, backend: str,
         limit = _fast.default_stream_limit(contract.device_resident)
     return (pattern_fingerprint(a), pattern_fingerprint(b), method, backend,
             tuple(sorted(params.items())), limit)
+
+
+def _canonical(method: str, backend: str, params: dict) -> tuple:
+    """``(method, params)`` as ``backend`` plans them.  Method spellings
+    collapse on a canonical-method backend (jax: one stream contraction)
+    to the canonical method and its own defaults, so they share one LRU
+    entry and a default method's knobs never reach its planner."""
+    canonical = backends.get_backend(backend).canonical_method
+    if canonical:
+        return canonical, resolve_params(canonical)
+    return method, params
 
 
 def plan_cache_key(a: CSC, b: CSC, method: str | None = None, *,
@@ -393,16 +402,19 @@ def plan_cache_key(a: CSC, b: CSC, method: str | None = None, *,
     _check_canonical_only(backend, t, b_min, b_max)
     if backend == "mesh":
         return _mesh_plan_key(a, b, shards, None, stream_limit)
-    return _single_plan_key(a, b, method, backend,
-                            resolve_params(method, t=t, b_min=b_min,
-                                           b_max=b_max),
+    method, params = _canonical(method, backend,
+                                resolve_params(method, t=t, b_min=b_min,
+                                               b_max=b_max))
+    return _single_plan_key(a, b, method, backend, params,
                             stream_limit=stream_limit)
 
 
 def _cached_plan(a: CSC, b: CSC, method: str, backend: str,
                  params: dict,
                  stream_limit: int | None = None,
-                 build_timeout: float | None = None) -> SpgemmPlan:
+                 build_timeout: float | None = None,
+                 span=spans.NULL) -> SpgemmPlan:
+    method, params = _canonical(method, backend, params)
     key = _single_plan_key(a, b, method, backend, params, stream_limit)
     return _build_once(
         key,
@@ -410,7 +422,7 @@ def _cached_plan(a: CSC, b: CSC, method: str, backend: str,
                             t=params.get("t"), b_min=params.get("b_min"),
                             b_max=params.get("b_max"),
                             stream_limit=stream_limit),
-        timeout=build_timeout)
+        timeout=build_timeout, span=span)
 
 
 def cached_plan(a: CSC, b: CSC, method: str | None = None, *,
@@ -431,7 +443,8 @@ def cached_plan(a: CSC, b: CSC, method: str | None = None, *,
     key), without mutating the global ``fast.STREAM_MAX_PRODUCTS`` knob.
     ``build_timeout`` bounds how long this call may wait on *another*
     thread's in-flight build of the same key (:class:`PlanBuildTimeout`
-    past it; default :data:`DEFAULT_BUILD_TIMEOUT`).
+    past it; default :data:`DEFAULT_BUILD_TIMEOUT`).  The call is the
+    span ``spgemm.plan`` (DESIGN.md §16), with ``hit`` 1 or 0.
     """
     method, backend = _resolve_method_backend(method, backend)
     _check_shards(backend, shards)
@@ -440,13 +453,15 @@ def cached_plan(a: CSC, b: CSC, method: str | None = None, *,
             "cached_plan builds single-method plans; use plan_spgemm_tiled "
             "for method='auto'")
     _check_canonical_only(backend, t, b_min, b_max)
-    if backend == "mesh":
-        return _cached_mesh_plan(a, b, shards, None, stream_limit)
-    return _cached_plan(a, b, method, backend,
-                        resolve_params(method, t=t, b_min=b_min,
-                                       b_max=b_max),
-                        stream_limit=stream_limit,
-                        build_timeout=build_timeout)
+    with spans.span("spgemm.plan") as span:
+        if backend == "mesh":
+            return _cached_mesh_plan(a, b, shards, None, stream_limit,
+                                     span=span)
+        return _cached_plan(a, b, method, backend,
+                            resolve_params(method, t=t, b_min=b_min,
+                                           b_max=b_max),
+                            stream_limit=stream_limit,
+                            build_timeout=build_timeout, span=span)
 
 
 def _cached_tiled_plan(a: CSC, b: CSC, backend: str, tile,
@@ -495,7 +510,7 @@ def _mesh_plan_key(a: CSC, b: CSC, shards, tile,
 
 
 def _cached_mesh_plan(a: CSC, b: CSC, shards=None, tile=None,
-                      stream_limit: int | None = None):
+                      stream_limit: int | None = None, span=spans.NULL):
     key = _mesh_plan_key(a, b, shards, tile, stream_limit)
     n_shards = dict(key[4])["shards"]
 
@@ -505,7 +520,7 @@ def _cached_mesh_plan(a: CSC, b: CSC, shards=None, tile=None,
         return plan_spgemm_mesh(a, b, shards=n_shards, tile=tile,
                                 shard_limit=stream_limit)
 
-    return _build_once(key, build)
+    return _build_once(key, build, span=span)
 
 
 def _auto_mesh_plan(a: CSC, b: CSC, shards, tile, candidates, cache):

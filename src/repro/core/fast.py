@@ -36,6 +36,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.expand import expand_positions, product_count
 from repro.sparse.format import CSC, _np, segment_reduce
 
@@ -127,8 +128,17 @@ def build_product_stream(a, b, max_products: int | None = None
     The returned stream's arrays are frozen (non-writeable): results built
     by the engine share ``c_rows``/``c_col_ptr`` with the plan-resident
     stream, so an in-place mutation of a result must raise instead of
-    silently corrupting every later same-plan execution.
+    silently corrupting every later same-plan execution.  The span
+    ``spgemm.symbolic``, with the stream's ``products``.
     """
+    with spans.span("spgemm.symbolic") as span:
+        s = _build_product_stream(a, b, max_products)
+        if s is not None:
+            span.set(products=s.n_products)
+        return s
+
+
+def _build_product_stream(a, b, max_products) -> Optional[ProductStream]:
     a_cp = _np(a.col_ptr)
     a_rows = _np(a.row_indices)[: int(a_cp[-1])]
     b_cp = _np(b.col_ptr)

@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import fast, faults
+from repro.core import fast, faults, spans
 from repro.sparse.format import CSC, BatchedCSC
 
 # int32 device indices: the plan-memory guard caps streams far below 2**31
@@ -128,35 +128,42 @@ def device_stream(plan) -> Optional[DeviceStream]:
     on the plan alongside it — ``plan.device_stream_nbytes`` /
     ``plan_cache_info()['device_stream_bytes']`` report the device half
     separately.  ``None`` when the plan-memory guard tripped (no host
-    stream to lift) or the plan's backend carries no stream.
+    stream to lift) or the plan's backend carries no stream.  The first
+    build is the span ``spgemm.device_lift``, with the uploaded ``bytes``.
     """
     s = plan.stream
     if s is None:
         return None
     memo = plan._stream_memo
     if "device" not in memo:
-        faults.check("device_lift", key=getattr(plan, "backend", None))
-        check_int32_stream(plan, s)
-        seg_ids = stream_seg_ids(s)
-        with jax.ensure_compile_time_eval():
-            # the lazy build may run *inside* a caller's jit trace (the
-            # first traced execution of a fresh plan); the index arrays
-            # must still come out concrete — they are plan state shared by
-            # every later trace, not constants of this one
-            dev_arrays = (jnp.asarray(s.a_pos, jnp.int32),
-                          jnp.asarray(s.b_pos, jnp.int32),
-                          jnp.asarray(seg_ids))
-        memo["device"] = DeviceStream(
-            a_pos=dev_arrays[0],
-            b_pos=dev_arrays[1],
-            seg_ids=dev_arrays[2],
-            c_rows=s.c_rows,
-            c_col_ptr=s.c_col_ptr,
-            shape=s.shape,
-            n_products=s.n_products,
-            num_segments=s.nnz,
-        )
+        with spans.span("spgemm.device_lift") as span:
+            memo["device"] = _lift(plan, s)
+            span.set(bytes=memo["device"].nbytes)
     return memo["device"]
+
+
+def _lift(plan, s) -> DeviceStream:
+    faults.check("device_lift", key=getattr(plan, "backend", None))
+    check_int32_stream(plan, s)
+    seg_ids = stream_seg_ids(s)
+    with jax.ensure_compile_time_eval():
+        # the lazy build may run *inside* a caller's jit trace (the first
+        # traced execution of a fresh plan); the index arrays must still
+        # come out concrete — they are plan state shared by every later
+        # trace, not constants of this one
+        dev_arrays = (jnp.asarray(s.a_pos, jnp.int32),
+                      jnp.asarray(s.b_pos, jnp.int32),
+                      jnp.asarray(seg_ids))
+    return DeviceStream(
+        a_pos=dev_arrays[0],
+        b_pos=dev_arrays[1],
+        seg_ids=dev_arrays[2],
+        c_rows=s.c_rows,
+        c_col_ptr=s.c_col_ptr,
+        shape=s.shape,
+        n_products=s.n_products,
+        num_segments=s.nnz,
+    )
 
 
 def _guard_error(plan) -> ValueError:
@@ -338,6 +345,17 @@ def host_fallback(plan, av, bv, stats: dict | None = None, *,
     return out
 
 
+def _call(plan, memo_key: str, make_fn, av, bv):
+    """``make_fn(plan)(av, bv)`` as the span ``spgemm.first_call`` when
+    the plan's jitted function is new (trace, lowering, compile or
+    executable load, first dispatch), else ``spgemm.dispatch`` (value
+    transfer and enqueue)."""
+    first = memo_key not in plan._stream_memo
+    fn = make_fn(plan)
+    with spans.span("spgemm.first_call" if first else "spgemm.dispatch"):
+        return fn(av, bv)
+
+
 def execute_jax(plan, a_values, b_values, *, stats: dict | None = None,
                 validate: str | None = None) -> CSC:
     """Numeric phase of a jax-backend plan (executor dispatch target).
@@ -352,7 +370,7 @@ def execute_jax(plan, a_values, b_values, *, stats: dict | None = None,
     bv = _operand_values(b_values)
     if plan.stream is None:
         return host_fallback(plan, av, bv, stats)
-    vals = stream_fn(plan)(av, bv)
+    vals = _call(plan, "jax_fn", stream_fn, av, bv)
     s = plan.stream
     if stats is not None:
         stats.update(engine="stream", backend="jax", device=True,
@@ -380,7 +398,7 @@ def execute_jax_batched(plan, a_values, b_values, *,
     batch = _check_batch(av, bv)
     if plan.stream is None:
         return host_fallback(plan, av, bv, stats, batch=batch)
-    vals = stream_fn_batched(plan)(av, bv)
+    vals = _call(plan, "jax_fn_batched", stream_fn_batched, av, bv)
     s = plan.stream
     if stats is not None:
         stats.update(engine="stream", backend="jax", device=True,

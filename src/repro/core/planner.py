@@ -26,7 +26,7 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import backends, faults
+from repro.core import backends, faults, spans
 from repro.core.analysis import Preprocess, preprocess
 from repro.core.cost import AUTO_CANDIDATES, CostConstants, choose_method
 import repro.core.fast as _fast
@@ -108,17 +108,20 @@ def pattern_fingerprint(m: CSC) -> str:
     """Hash of the sparsity pattern only (shape + col_ptr + row_indices).
 
     Two CSC matrices with equal fingerprints can share one SpgemmPlan; their
-    values never enter the hash.
+    values never enter the hash.  The span ``spgemm.fingerprint``.
     """
     cp = _np(m.col_ptr)
-    ri = _np(m.row_indices)[: int(cp[-1])]
-    h = hashlib.blake2b(digest_size=16)
-    # raw bytes + dtype tags (no widening copies): fingerprints distinguish
-    # index dtypes, which is fine — Pattern.of normalizes to int32 anyway
-    h.update(f"{m.shape}:{cp.dtype}:{ri.dtype}".encode())
-    h.update(cp.tobytes())
-    h.update(ri.tobytes())
-    return h.hexdigest()
+    nnz = int(cp[-1])
+    with spans.span("spgemm.fingerprint", nnz=nnz):
+        ri = _np(m.row_indices)[:nnz]
+        h = hashlib.blake2b(digest_size=16)
+        # raw bytes + dtype tags (no widening copies): fingerprints
+        # distinguish index dtypes, which is fine — Pattern.of normalizes
+        # to int32 anyway
+        h.update(f"{m.shape}:{cp.dtype}:{ri.dtype}".encode())
+        h.update(cp.tobytes())
+        h.update(ri.tobytes())
+        return h.hexdigest()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -409,11 +412,14 @@ class SpgemmPlan:
         faithful per-method oracle executors), ``"stream"`` (the vectorized
         product-stream engine, DESIGN.md §9), or ``None`` for the method's
         default (``"stream"`` for ``expand``, ``"naive"`` otherwise).
+        The call is the span ``spgemm.execute`` (DESIGN.md §16); on a
+        device backend it returns before the device finishes.
         """
         from repro.core.executor import execute
 
-        return execute(self, a_values, b_values, stats=stats,
-                       validate=validate, engine=engine)
+        with spans.span("spgemm.execute"):
+            return execute(self, a_values, b_values, stats=stats,
+                           validate=validate, engine=engine)
 
     def execute_batched(self, a_values, b_values, *,
                         stats: dict | None = None,

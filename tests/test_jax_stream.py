@@ -17,6 +17,7 @@ from test_differential import CASES, _adversarial, oracle_product
 
 from repro.core import (
     backend_names,
+    cached_plan,
     get_backend,
     plan_cache_clear,
     plan_cache_info,
@@ -371,6 +372,24 @@ def test_jax_method_spellings_share_one_canonical_plan():
     # ...but a named method whose *defaults* carry knobs still collapses
     assert spgemm(a, a, "h-hash-256/256", backend="jax",
                   cache=False).nnz == p1.execute(a, a).nnz
+    plan_cache_clear()
+
+
+def test_jax_default_method_plans_on_a_miss():
+    """With no method, the default ``h-hash-256/256`` collapses to the
+    canonical ``expand`` before its knobs reach the planner: a miss
+    through every cached entry point plans instead of raising."""
+    a = random_powerlaw_csc(30, 2.5, seed=19)
+    want = plan_spgemm(a, a, "expand", backend="host").execute(a, a)
+    for call in (lambda: cached_plan(a, a, backend="jax").execute(a, a),
+                 lambda: spgemm(a, a, backend="jax"),
+                 lambda: spgemm_batched(BatchedCSC.stack([a, a]),
+                                        BatchedCSC.stack([a, a]),
+                                        backend="jax")[1]):
+        plan_cache_clear()
+        got = call()
+        assert np.allclose(np.asarray(got.values), want.values, rtol=1e-5)
+        assert plan_cache_info()["misses"] == 1
     plan_cache_clear()
 
 

@@ -1,0 +1,136 @@
+"""The span recorder (``core.spans``, DESIGN.md §16): nothing recorded
+without a profiler session; under one, a plan miss and two replays record
+the layer spans with their parents and request ids, in memory and in the
+profiler's own trace; the bound counts drops; threads keep their own
+parents."""
+
+import glob
+import os
+import threading
+
+import jax
+import pytest
+
+from repro.core import api, cached_plan, spans
+from repro.sparse.generate import random_powerlaw_csc
+
+
+class Session:
+    """A profiler session into ``dir``; ``stop`` ends it once."""
+
+    def __init__(self, dir):
+        self.dir = dir
+        self.on = True
+        jax.profiler.start_trace(str(dir))
+
+    def stop(self):
+        if self.on:
+            self.on = False
+            jax.profiler.stop_trace()
+
+
+@pytest.fixture
+def session(tmp_path):
+    """A profiler session around the test, the recorder emptied first."""
+    spans.clear()
+    s = Session(tmp_path)
+    try:
+        yield s
+    finally:
+        s.stop()
+
+
+def _replay_twice(n=48, seed=3):
+    api.plan_cache_clear()
+    a = random_powerlaw_csc(n, 2.5, seed=seed)
+    plan = cached_plan(a, a, backend="jax")
+    for _ in range(2):
+        jax.block_until_ready(plan.execute(a, a).values)
+    return a
+
+
+def test_nothing_is_recorded_without_a_profiler_session():
+    spans.clear()
+    assert spans.span("spgemm.plan", hit=1) is spans.NULL
+    _replay_twice()
+    assert spans.recorded() == ([], 0)
+
+
+def _names(records, parent):
+    return [r.name for r in records if r.parent == parent]
+
+
+def test_a_miss_then_replays_record_the_layer_spans(session):
+    a = _replay_twice()
+    cached_plan(a, a, backend="jax")                 # a hit
+    records, dropped = spans.recorded()
+    assert dropped == 0
+    roots = sorted((r for r in records if r.parent == -1),
+                   key=lambda r: r.start_ns)
+    assert [(r.name, r.attrs) for r in roots] == [
+        ("spgemm.plan", {"hit": 0}), ("spgemm.execute", {}),
+        ("spgemm.execute", {}), ("spgemm.plan", {"hit": 1})]
+    for root in roots:
+        assert root.request == root.index
+        kids = [r for r in records if r.request == root.index
+                and r is not root]
+        assert all(r.parent == root.index for r in kids)
+        assert all(root.start_ns <= r.start_ns <= r.end_ns <= root.end_ns
+                   for r in kids)
+    miss, first, replay, hit = (_names(records, r.index) for r in roots)
+    assert miss == ["spgemm.fingerprint"] * 4
+    assert first == ["spgemm.symbolic", "spgemm.device_lift",
+                     "spgemm.first_call"]
+    assert replay == ["spgemm.dispatch"]
+    assert hit == ["spgemm.fingerprint"] * 2
+    by_name = {r.name: r for r in records}
+    assert by_name["spgemm.fingerprint"].attrs == {"nnz": a.nnz}
+    plan = cached_plan(a, a, backend="jax")
+    assert by_name["spgemm.symbolic"].attrs == {
+        "products": plan.stream.n_products}
+    assert by_name["spgemm.device_lift"].attrs == {
+        "bytes": plan.device_stream_nbytes}
+    session.stop()
+    (path,) = glob.glob(os.path.join(session.dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    traced = {e.name for plane in
+              jax.profiler.ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events
+              if e.name.startswith("spgemm.")}
+    assert traced == {r.name for r in records}
+
+
+def test_the_bound_keeps_the_newest_records_and_counts_drops(
+        session, monkeypatch):
+    monkeypatch.setattr(spans, "_RECORDER", spans.Recorder(3))
+    for i in range(5):
+        with spans.span("t.span", i=i):
+            pass
+    records, dropped = spans.recorded()
+    assert [r.attrs["i"] for r in records] == [2, 3, 4] and dropped == 2
+    spans.clear()
+    assert spans.recorded() == ([], 0)
+
+
+def test_spans_of_two_threads_do_not_cross_parent(session):
+    both = threading.Barrier(2, timeout=30)
+
+    def work(i):
+        with spans.span("t.root", thread=i):
+            both.wait()
+            with spans.span("t.child", thread=i):
+                both.wait()
+
+    threads = [threading.Thread(target=work, args=(i,)) for i in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+    records, _ = spans.recorded()
+    root = {r.attrs["thread"]: r for r in records if r.name == "t.root"}
+    child = {r.attrs["thread"]: r for r in records if r.name == "t.child"}
+    assert sorted(root) == sorted(child) == [0, 1]
+    for i in (0, 1):
+        assert root[i].parent == -1 and root[i].request == root[i].index
+        assert child[i].parent == child[i].request == root[i].index
