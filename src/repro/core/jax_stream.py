@@ -15,6 +15,16 @@ replays from XLA's compiled-call cache — an execution is a single device
 dispatch, with no per-group Python loop (the Pallas path launches one
 kernel per plan group from Python) and no host round-trip.
 
+**One value table.**  On concrete operands :func:`execute_jax` may pack
+A's values and then B's into one table outside the executable and dispatch
+:func:`table_fn`, whose gathers both index that table (B through
+``b_pos + nnz_A``).  XLA's cross-program prefetch copies one entry
+parameter into VMEM, so with one table neither gather reads its values
+from HBM, while with two a large or long-gathered B stays in HBM.  Where
+two tables would both reach VMEM anyway, where one table would not fit
+(``runtime.prefetch_limits``, :func:`table_form`), and for batched stacks
+and traced operands, the two-table form stays.
+
 **Differentiability.**  The contraction is bilinear, so its VJP is two more
 stream replays through the *same* index arrays — no new symbolic work::
 
@@ -50,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import runtime
 from repro.core import fast, faults, spans
 from repro.sparse.format import CSC, BatchedCSC
 
@@ -269,6 +280,33 @@ def _bilinear_contract(num_segments: int):
     return bilinear_custom_vjp(forward, grad_a, grad_b)
 
 
+def _one_table(contract, offset: int):
+    """``contract`` over one value table holding A's values and then B's:
+    ``f(idx, table)``, B's positions shifted by ``offset`` (A's packed
+    length) inside the program, where the add fuses into the index fusion
+    of B's gather.  The same products in the same order, so the result is
+    the two-table contraction's; the table's cotangent is the sum of the
+    two scatter-adds of the custom vjp."""
+
+    def run(idx, table):
+        a_pos, b_pos, seg_ids = idx
+        return contract((a_pos, b_pos + offset, seg_ids), table, table)
+
+    return run
+
+
+def _contract(plan):
+    """The plan's custom-vjp contraction, memoized; guarded plans raise
+    the capability error."""
+    memo = plan._stream_memo
+    if "jax_contract" not in memo:
+        dev = device_stream(plan)
+        if dev is None:
+            raise _guard_error(plan)
+        memo["jax_contract"] = _bilinear_contract(dev.num_segments)
+    return memo["jax_contract"]
+
+
 def stream_fn(plan):
     """The plan's jitted numeric function ``f(a_values, b_values) -> c_values``.
 
@@ -278,12 +316,72 @@ def stream_fn(plan):
     """
     memo = plan._stream_memo
     if "jax_fn" not in memo:
-        dev = device_stream(plan)
-        if dev is None:
-            raise _guard_error(plan)
-        memo["jax_contract"] = _bilinear_contract(dev.num_segments)
-        memo["jax_fn"] = bind_indices(memo["jax_contract"], dev.indices)
+        contract = _contract(plan)
+        memo["jax_fn"] = bind_indices(contract, memo["device"].indices)
     return memo["jax_fn"]
+
+
+def table_fn(plan):
+    """The execute path's jitted ``f(table) -> c_values`` over one value
+    table (:func:`pack_table`): both gathers read the one entry parameter
+    that XLA's cross-program prefetch puts in VMEM.  Memoized on the plan;
+    guarded plans raise the capability error."""
+    memo = plan._stream_memo
+    if "jax_fn_table" not in memo:
+        run = _one_table(_contract(plan), int(plan.a.col_ptr[-1]))
+        memo["jax_fn_table"] = bind_indices(run, memo["device"].indices)
+    return memo["jax_fn_table"]
+
+
+def table_form(nnz_a: int, nnz_b: int, n_products: int,
+               itemsize: int) -> str:
+    """The execute path's value-table form for concrete operands.
+
+    ``"one"`` where the two-table form would leave an operand's gather
+    reading HBM and one table of both fits the cross-program prefetch
+    (:func:`runtime.prefetch_limits`); else ``"two"``: where both tables
+    reach VMEM anyway, one table only adds a host copy and a slower
+    dispatch (measured in PERF.md §6), and where one table would not fit,
+    two still keep one operand in VMEM.  Values of another width than the
+    limits were measured at keep two tables.  Always ``"one"`` without
+    VMEM (CPU), within int32 positions.
+    """
+    n_values = nnz_a + nnz_b
+    if n_values > _I32_MAX:
+        return "two"
+    lim = runtime.prefetch_limits()
+    if lim is None:
+        return "one"
+    if (itemsize != lim.itemsize
+            or n_values * itemsize > lim.cross_program_bytes):
+        return "two"
+    both_in_vmem = (min(nnz_a, nnz_b) * itemsize <= lim.other_bytes
+                    and n_products <= lim.other_products)
+    return "two" if both_in_vmem else "one"
+
+
+@functools.cache
+def _table_dtype(a_dtype, b_dtype) -> np.dtype:
+    """The dtype of the table packed from these operand dtypes, JAX's
+    promotion (memoized: it costs ~10 us, a small replay's dispatch
+    ~0.4 ms)."""
+    return np.dtype(jnp.result_type(a_dtype, b_dtype))
+
+
+def pack_table(plan, av, bv):
+    """The one value table: A's first nnz values, then B's first nnz.
+
+    Packed outside the executable (an in-program concatenate is not an
+    entry parameter, so XLA would not prefetch it): on the host for host
+    operands, so a call makes one transfer; on the device when either
+    operand is a ``jax.Array``.  Oversized raw value arrays contribute only
+    the plan's nnz.
+    """
+    na, nb = int(plan.a.col_ptr[-1]), int(plan.b.col_ptr[-1])
+    if isinstance(av, jax.Array) or isinstance(bv, jax.Array):
+        return jnp.concatenate([jnp.asarray(av)[:na], jnp.asarray(bv)[:nb]])
+    a, b = np.asarray(av)[:na], np.asarray(bv)[:nb]
+    return np.concatenate([a, b], dtype=_table_dtype(a.dtype, b.dtype))
 
 
 def stream_fn_batched(plan):
@@ -295,9 +393,8 @@ def stream_fn_batched(plan):
     """
     memo = plan._stream_memo
     if "jax_fn_batched" not in memo:
-        stream_fn(plan)   # ensures jax_contract (or raises the guard error)
         memo["jax_fn_batched"] = bind_indices(
-            memo["jax_contract"], memo["device"].indices, batched=True)
+            _contract(plan), memo["device"].indices, batched=True)
     return memo["jax_fn_batched"]
 
 
@@ -345,15 +442,36 @@ def host_fallback(plan, av, bv, stats: dict | None = None, *,
     return out
 
 
-def _call(plan, memo_key: str, make_fn, av, bv):
-    """``make_fn(plan)(av, bv)`` as the span ``spgemm.first_call`` when
-    the plan's jitted function is new (trace, lowering, compile or
-    executable load, first dispatch), else ``spgemm.dispatch`` (value
-    transfer and enqueue)."""
+def _nbytes(x) -> int:
+    """Bytes of a value operand: numpy, device array, tracer or list."""
+    dtype = x.dtype if hasattr(x, "dtype") else np.asarray(x).dtype
+    return int(np.size(x)) * np.dtype(dtype).itemsize
+
+
+def _call(plan, memo_key: str, make_fn, table: str, av, bv):
+    """``make_fn(plan)`` on the values as the span ``spgemm.first_call``
+    when the plan's jitted function is new (trace, lowering, compile or
+    executable load, first dispatch), else ``spgemm.dispatch`` (packing,
+    value transfer and enqueue).  ``table`` is the function's form:
+    ``"one"`` packs ``av`` and ``bv`` into one table first.  The span
+    carries ``table`` and the ``table_bytes`` the executable reads."""
     first = memo_key not in plan._stream_memo
     fn = make_fn(plan)
-    with spans.span("spgemm.first_call" if first else "spgemm.dispatch"):
-        return fn(av, bv)
+    with spans.span("spgemm.first_call" if first else "spgemm.dispatch") \
+            as span:
+        values = (pack_table(plan, av, bv),) if table == "one" else (av, bv)
+        if span is not spans.NULL:
+            span.set(table=table,
+                     table_bytes=sum(_nbytes(x) for x in values))
+        return fn(*values)
+
+
+#: the execute path's jitted function of each form: memo key, maker
+_FORMS = {"one": ("jax_fn_table", table_fn), "two": ("jax_fn", stream_fn)}
+
+
+def _concrete(x):
+    return x if isinstance(x, jax.Array) else np.asarray(x)
 
 
 def execute_jax(plan, a_values, b_values, *, stats: dict | None = None,
@@ -361,8 +479,10 @@ def execute_jax(plan, a_values, b_values, *, stats: dict | None = None,
     """Numeric phase of a jax-backend plan (executor dispatch target).
 
     Returns a CSC whose values are a device array on the plan's canonical
-    stream structure.  Guarded plans (``plan.stream is None``) run
-    :func:`host_fallback`.
+    stream structure.  Concrete operands run :func:`table_fn` on one packed
+    value table when :func:`table_form` allows, else :func:`stream_fn`;
+    traced ones run :func:`stream_fn`.  Guarded plans (``plan.stream is
+    None``) run :func:`host_fallback`.
     """
     plan.a.check_compatible(a_values, validate)
     plan.b.check_compatible(b_values, validate)
@@ -370,7 +490,15 @@ def execute_jax(plan, a_values, b_values, *, stats: dict | None = None,
     bv = _operand_values(b_values)
     if plan.stream is None:
         return host_fallback(plan, av, bv, stats)
-    vals = _call(plan, "jax_fn", stream_fn, av, bv)
+    if _is_traced(av, bv):
+        form = "two"      # inside a caller's program: no entry parameter
+    else:
+        av, bv = _concrete(av), _concrete(bv)
+        form = table_form(int(plan.a.col_ptr[-1]), int(plan.b.col_ptr[-1]),
+                          plan.stream.n_products,
+                          _table_dtype(av.dtype, bv.dtype).itemsize)
+    memo_key, make_fn = _FORMS[form]
+    vals = _call(plan, memo_key, make_fn, form, av, bv)
     s = plan.stream
     if stats is not None:
         stats.update(engine="stream", backend="jax", device=True,
@@ -398,7 +526,7 @@ def execute_jax_batched(plan, a_values, b_values, *,
     batch = _check_batch(av, bv)
     if plan.stream is None:
         return host_fallback(plan, av, bv, stats, batch=batch)
-    vals = _call(plan, "jax_fn_batched", stream_fn_batched, av, bv)
+    vals = _call(plan, "jax_fn_batched", stream_fn_batched, "two", av, bv)
     s = plan.stream
     if stats is not None:
         stats.update(engine="stream", backend="jax", device=True,

@@ -136,21 +136,28 @@ def warm_plan(plan) -> None:
 
     Touches the host product stream (the §9 lazy build), and on
     stream-capable device backends also lifts the device arrays and runs
-    one throwaway numeric execution so XLA compiles the jitted stream
-    function (§10) — the state a serving tick would otherwise pay for on
-    first use.  Guarded plans (``plan.stream is None``) have nothing to
-    warm.  Safe to call on any plan; unknown plan types are ignored.
+    one throwaway numeric execution on float32 zeros so XLA compiles the
+    executable a serving tick would otherwise pay for on first use (§10):
+    on the jax backend the one ``plan.execute`` dispatches (one value
+    table or two, ``jax_stream.table_form``), on the mesh backend its
+    ``stream_apply``.  Guarded plans (``plan.stream is None``) have
+    nothing to warm.  Safe to call on any plan; unknown plan types are
+    ignored.
     """
-    faults.check("warm_compile", key=getattr(plan, "backend", None))
+    backend = getattr(plan, "backend", None)
+    faults.check("warm_compile", key=backend)
     stream = getattr(plan, "stream", None)
-    if stream is None:
+    if stream is None or backend not in ("jax", "mesh"):
         return
-    if getattr(plan, "backend", None) in ("jax", "mesh"):
-        a_nnz = int(plan.a.col_ptr[-1])
-        b_nnz = int(plan.b.col_ptr[-1])
-        out = plan.stream_apply(np.zeros(a_nnz, np.float32),
-                                np.zeros(b_nnz, np.float32))
-        out.block_until_ready()
+    a_zeros = np.zeros(int(plan.a.col_ptr[-1]), np.float32)
+    b_zeros = np.zeros(int(plan.b.col_ptr[-1]), np.float32)
+    if backend == "jax":
+        from repro.core import jax_stream
+
+        out = jax_stream.execute_jax(plan, a_zeros, b_zeros).values
+    else:
+        out = plan.stream_apply(a_zeros, b_zeros)
+    out.block_until_ready()
 
 
 class PlanBuilder:

@@ -6,13 +6,16 @@ other platform.  Nothing takes an ``interpret=`` argument, so a TPU process
 can never run a kernel interpreted by accident.
 
 The module also sizes the default device plan-memory guard from the chip's
-own memory (:func:`device_stream_limit`) and points JAX's persistent
-compilation cache at a fixed directory (:func:`enable_compile_cache`).
+own memory (:func:`device_stream_limit`), says which value arrays XLA
+copies into the chip's VMEM (:func:`prefetch_limits`) and points JAX's
+persistent compilation cache at a fixed directory
+(:func:`enable_compile_cache`).
 Importing it has no side effects; entry points call what they need.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 from pathlib import Path
@@ -33,6 +36,35 @@ DEVICE_BYTES_PER_PRODUCT = 48
 #: share of the chip's memory one plan's stream may take by default; the
 #: rest holds operands, results and the transient products of a replay
 DEVICE_STREAM_SHARE = 0.5
+
+
+@dataclasses.dataclass(frozen=True)
+class PrefetchLimits:
+    """What XLA moves into a chip's VMEM before a gather reads it.
+
+    Facts of the chip and its compiler, not options, measured by compiling
+    for a described chip (``tests/test_tpu_compile.py`` holds each at its
+    edge).  Of an executable's entry parameters, one is copied across
+    programs (cross-program prefetch, the largest that fits); any other
+    gets an ordinary prefetch inside the program only if it is small and
+    the gathers reading it are not too long.  The edges were found for
+    float32 values only (``itemsize``); a wider or narrower value tiles
+    differently, so the limits say nothing of it.
+    """
+
+    cross_program_bytes: int   # the one cross-program-prefetched parameter
+    other_bytes: int           # any other prefetched parameter
+    other_products: int        # longest gather whose operand is prefetched
+    itemsize: int = 4          # bytes of the values the edges were found at
+
+
+#: by device kind; a v5e (128 MiB of VMEM): 112 MiB across programs (an f32
+#: table of 29,360,128 values is prefetched, one of 29,360,129 is not),
+#: 1,044,480 bytes (255 tiles of 1,024 f32) otherwise, and that only for
+#: gathers of at most 117,440,512 products, at any operand size
+PREFETCH_LIMITS = {
+    "TPU v5 lite": PrefetchLimits(112 * 2 ** 20, 1_044_480, 112 * 2 ** 20),
+}
 
 #: Pallas kernels that do not compile for a TPU yet (the compiler aborts the
 #: process on them, so they are refused before it is called)
@@ -92,6 +124,20 @@ def device_stream_limit() -> int | None:
             "the TPU reports no memory bytes_limit; cannot size the device "
             "plan-memory guard")
     return int(limit * DEVICE_STREAM_SHARE) // DEVICE_BYTES_PER_PRODUCT
+
+
+@functools.cache
+def prefetch_limits() -> PrefetchLimits | None:
+    """The chip's :class:`PrefetchLimits`, or None where there is no VMEM.
+
+    On a TPU, those of the first device's kind (:data:`PREFETCH_LIMITS`);
+    a kind not measured yet gets all zeros, so nothing counts as
+    prefetched.  Elsewhere (CPU) ``None``.
+    """
+    if platform() != "tpu":
+        return None
+    return PREFETCH_LIMITS.get(jax.devices()[0].device_kind,
+                               PrefetchLimits(0, 0, 0))
 
 
 def compile_cache_dir() -> Path:

@@ -212,6 +212,11 @@ def test_zero_retrace_after_warmup():
         v = rng.normal(size=a.nnz).astype(F32)
         fn(v, v)
     assert fn.func._cache_size() == 1   # the jitted replay
+    # the execute path's one-table fn likewise traces once
+    for _ in range(4):
+        v = rng.normal(size=a.nnz).astype(F32)
+        plan.execute(v, v)
+    assert jax_stream.table_fn(plan).func._cache_size() == 1
     # the batched fn is its own single trace per batch shape
     bfn = jax_stream.stream_fn_batched(plan)
     for _ in range(3):
@@ -235,6 +240,146 @@ def test_replay_takes_the_stream_as_arguments(engine):
     assert idx_bytes >= 3 * 4 * plan.stream.n_products
     mem = fn.func.lower(idx, a.values, a.values).compile().memory_analysis()
     assert mem.argument_size_in_bytes >= idx_bytes
+
+
+# --- one value table on the execute path ------------------------------------
+
+
+def _operands(kind):
+    """(plan, a operand, b operand, A's values, B's values) of each way
+    the execute path is called."""
+    rng = np.random.default_rng(21)
+    if kind == "a_times_b":
+        a, b = _adversarial("rect_chain")
+        assert a.nnz != b.nnz
+    else:
+        a = b = random_powerlaw_csc(40, 3.0, seed=22)
+    plan = plan_spgemm(a, b, "expand", backend="jax")
+    av = rng.normal(size=a.nnz).astype(F32)
+    bv = av if a is b else rng.normal(size=b.nnz).astype(F32)
+    if kind == "a_times_b":
+        return plan, av, bv, av, bv
+    if kind == "same_buffer":
+        return plan, av, av, av, av
+    if kind == "csc":
+        ao = CSC(av, a.row_indices, a.col_ptr, a.shape)
+        return plan, ao, ao, av, av
+    if kind == "jax_arrays":
+        return plan, jnp.asarray(av), jnp.asarray(av), av, av
+    if kind == "mixed":
+        return plan, jnp.asarray(av), av, av, av
+    assert kind == "oversized"
+    long_a = np.concatenate([av, rng.normal(size=7).astype(F32)])
+    long_b = np.concatenate([av, rng.normal(size=3).astype(F32)])
+    return plan, long_a, long_b, av, av
+
+
+@pytest.mark.parametrize("kind", ["a_times_b", "same_buffer", "csc",
+                                  "jax_arrays", "mixed", "oversized"])
+def test_one_table_execute_bit_identical_to_two_tables(kind):
+    """On concrete operands the execute path gathers both operands from
+    one packed table and builds no other executable; C is the two-table
+    contraction's, bit for bit."""
+    plan, x, y, av, bv = _operands(kind)
+    got = plan.execute(x, y)
+    memo = plan._stream_memo
+    assert "jax_fn_table" in memo
+    assert "jax_fn" not in memo and "jax_fn_batched" not in memo
+    want = jax_stream.stream_fn(plan)(av, bv)
+    np.testing.assert_array_equal(np.asarray(got.values), np.asarray(want))
+    table = jax_stream.pack_table(plan, _operand(x), _operand(y))
+    np.testing.assert_array_equal(np.asarray(table),
+                                  np.concatenate([av, bv]))
+
+
+def _operand(x):
+    return x.values if isinstance(x, CSC) else x
+
+
+@pytest.mark.parametrize("oversized", [False, True])
+def test_one_table_grad_splits_into_operand_cotangents(oversized):
+    """Differentiated through the packing, the one-table contraction's
+    cotangent splits back into the two-table vjp's operand cotangents
+    (oversized operands keep oversized ones, zero past the nnz)."""
+    a, b = _adversarial("rect_chain")
+    plan = plan_spgemm(a, b, "expand", backend="jax")
+    rng = np.random.default_rng(23)
+    extra = 5 if oversized else 0
+    av = jnp.asarray(rng.normal(size=a.nnz + extra).astype(F32))
+    bv = jnp.asarray(rng.normal(size=b.nnz + extra).astype(F32))
+    one = jax_stream._one_table(jax_stream._contract(plan), a.nnz)
+    idx = plan._stream_memo["device"].indices
+
+    def packed(x, y):
+        table = jnp.concatenate([x[: a.nnz], y[: b.nnz]])
+        return jnp.sum(one(idx, table) ** 2)
+
+    def two(x, y):
+        return jnp.sum(plan.stream_apply(x, y) ** 2)
+
+    for got, want in zip(jax.grad(packed, argnums=(0, 1))(av, bv),
+                         jax.grad(two, argnums=(0, 1))(av, bv)):
+        assert got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_grad_through_execute_keeps_two_tables():
+    """Traced operands (a caller's ``jax.grad``) take the two-table traced
+    entry: the same gradients as ``stream_apply``."""
+    a, b = _adversarial("dup_heavy")
+    plan = plan_spgemm(a, b, "expand", backend="jax")
+    av = jnp.asarray(np.asarray(a.values)[: a.nnz].astype(F32))
+    bv = jnp.asarray(np.asarray(b.values)[: b.nnz].astype(F32))
+    got = jax.grad(lambda x, y: jnp.sum(plan.execute(x, y).values),
+                   argnums=(0, 1))(av, bv)
+    assert "jax_fn_table" not in plan._stream_memo
+    want = jax.grad(lambda x, y: jnp.sum(plan.stream_apply(x, y)),
+                    argnums=(0, 1))(av, bv)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_table_form_follows_the_prefetch_limits(monkeypatch):
+    """One table where two would leave a gather in HBM and one fits the
+    cross-program prefetch; two tables otherwise; one without VMEM."""
+    from repro import runtime
+
+    monkeypatch.setattr(runtime, "prefetch_limits", lambda: None)
+    assert jax_stream.table_form(10 ** 3, 10 ** 3, 10, 4) == "one"
+    assert jax_stream.table_form(10 ** 8, 10 ** 8, 10, 4) == "one"
+    assert jax_stream.table_form(jax_stream._I32_MAX, 1, 10, 4) == "two"
+    monkeypatch.setattr(runtime, "prefetch_limits",
+                        lambda: runtime.PrefetchLimits(4000, 400, 1000))
+    form = jax_stream.table_form
+    assert form(100, 100, 1000, 4) == "two"      # both prefetched anyway
+    assert form(101, 500, 1000, 4) == "one"      # B gathered from HBM
+    assert form(100, 100, 1001, 4) == "one"      # too long a gather
+    assert form(100, 100, 1000, 8) == "two"      # limits found at 4 B
+    assert form(101, 500, 1000, 2) == "two"
+    assert form(101, 899, 1000, 4) == "one"      # 4000 B: fits
+    assert form(101, 900, 1000, 4) == "two"      # one table would not fit
+    monkeypatch.setattr(runtime, "prefetch_limits",
+                        lambda: runtime.PrefetchLimits(0, 0, 0))
+    assert form(1, 1, 1, 4) == "two"
+
+
+@pytest.mark.parametrize("limits", [(0, 0, 0), (10 ** 9, 10 ** 9, 10 ** 9)],
+                         ids=["nothing_fits", "both_prefetched"])
+def test_execute_keeps_two_tables_where_one_does_not_help(monkeypatch,
+                                                          limits):
+    """Where the rule says two, the execute path builds and runs the
+    two-table executable alone, with the same C."""
+    from repro import runtime
+
+    plan, x, y, av, bv = _operands("a_times_b")
+    want = np.asarray(plan.execute(x, y).values)
+    plan = plan_spgemm(*_adversarial("rect_chain"), "expand", backend="jax")
+    monkeypatch.setattr(runtime, "prefetch_limits",
+                        lambda: runtime.PrefetchLimits(*limits))
+    got = plan.execute(x, y)
+    memo = plan._stream_memo
+    assert "jax_fn" in memo and "jax_fn_table" not in memo
+    np.testing.assert_array_equal(np.asarray(got.values), want)
 
 
 # --- guard fallback and capability errors -----------------------------------

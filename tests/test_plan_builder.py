@@ -18,6 +18,7 @@ from repro.core import (
     plan_cache_key, plan_cache_peek, spgemm, warm_plan,
 )
 from repro.sparse import random_density_csc
+from repro.sparse.format import csc_to_dense
 
 
 @pytest.fixture(autouse=True)
@@ -229,6 +230,32 @@ def test_warm_plan_materializes_stream():
     warm_plan(plan)
     assert plan.stream_nbytes > 0
     assert plan.device_stream_nbytes > 0
+
+
+@pytest.mark.parametrize("limits, key", [
+    (None, "jax_fn_table"), ((0, 0, 0), "jax_fn")], ids=["one", "two"])
+def test_warm_plan_builds_the_executable_execute_dispatches(monkeypatch,
+                                                            limits, key):
+    """``warm_plan`` compiles the value-table form ``plan.execute`` will
+    dispatch, and only that one: the first execute after it builds
+    nothing new."""
+    from repro import runtime
+
+    monkeypatch.setattr(
+        runtime, "prefetch_limits",
+        lambda: limits and runtime.PrefetchLimits(*limits))
+    a, b = _mats(1)[0]
+    plan = cached_plan(a, b, "expand", backend="jax")
+    warm_plan(plan)
+
+    def fns():
+        return sorted(k for k in plan._stream_memo if k.startswith("jax_fn"))
+
+    assert fns() == [key]
+    got = plan.execute(a, b)
+    assert fns() == [key]
+    np.testing.assert_allclose(csc_to_dense(got),
+                               csc_to_dense(a) @ csc_to_dense(b), rtol=1e-5)
 
 
 def test_allmiss_churn_bit_identical_to_cold_cache():

@@ -82,6 +82,27 @@ def test_device_guard_sized_from_chip_memory(monkeypatch):
         runtime.device_stream_limit.cache_clear()
 
 
+@pytest.mark.parametrize("platform, kind, want", [
+    ("cpu", None, None),
+    ("tpu", "TPU v5 lite",
+     runtime.PrefetchLimits(112 * 2**20, 1_044_480, 112 * 2**20)),
+    ("tpu", "TPU v9 unmeasured", runtime.PrefetchLimits(0, 0, 0)),
+])
+def test_prefetch_limits_are_the_chips(monkeypatch, platform, kind, want):
+    """No VMEM on the CPU; on a TPU its kind's measured limits, and
+    nothing prefetched on a kind not measured."""
+    class Dev:
+        device_kind = kind
+
+    runtime.prefetch_limits.cache_clear()
+    monkeypatch.setattr(runtime, "platform", lambda: platform)
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev()])
+    try:
+        assert runtime.prefetch_limits() == want
+    finally:
+        runtime.prefetch_limits.cache_clear()
+
+
 def test_device_plans_take_the_device_guard(monkeypatch):
     """jax/pallas plans (and their cache keys) default to the device guard;
     host plans keep the host knob."""

@@ -9,8 +9,10 @@ import os
 import threading
 
 import jax
+import numpy as np
 import pytest
 
+from repro import runtime
 from repro.core import api, cached_plan, spans
 from repro.sparse.generate import random_powerlaw_csc
 
@@ -54,6 +56,45 @@ def test_nothing_is_recorded_without_a_profiler_session():
     assert spans.span("spgemm.plan", hit=1) is spans.NULL
     _replay_twice()
     assert spans.recorded() == ([], 0)
+
+
+@pytest.mark.parametrize("limits, table", [
+    (None, "one"), (runtime.PrefetchLimits(0, 0, 0), "two")])
+def test_calls_carry_the_value_table_form(session, monkeypatch, limits,
+                                          table):
+    """``spgemm.first_call`` and ``spgemm.dispatch`` say which value-table
+    form ran and how many bytes of values the executable read: one table
+    packs each operand's first nnz, two tables read both arrays whole."""
+    monkeypatch.setattr(runtime, "prefetch_limits", lambda: limits)
+    api.plan_cache_clear()
+    a = random_powerlaw_csc(48, 2.5, seed=3)
+    plan = cached_plan(a, a, backend="jax")
+    values = np.arange(a.nnz + 9, dtype=np.float32)     # oversized
+    for _ in range(2):
+        jax.block_until_ready(plan.execute(values, values).values)
+    calls = [r for r in spans.recorded()[0]
+             if r.name in ("spgemm.first_call", "spgemm.dispatch")]
+    want = 4 * (2 * a.nnz if table == "one" else 2 * values.size)
+    assert [(r.name, r.attrs) for r in calls] == [
+        ("spgemm.first_call", {"table": table, "table_bytes": want}),
+        ("spgemm.dispatch", {"table": table, "table_bytes": want})]
+
+
+def test_execute_after_warm_plan_is_a_dispatch(session):
+    """A plan warmed by ``warm_plan`` (the builder's warm step) has the
+    executable ``plan.execute`` dispatches: its first execute records
+    ``spgemm.dispatch``, not ``spgemm.first_call``."""
+    from repro.core import warm_plan
+
+    api.plan_cache_clear()
+    a = random_powerlaw_csc(48, 2.5, seed=3)
+    plan = cached_plan(a, a, backend="jax")
+    warm_plan(plan)
+    spans.clear()
+    jax.block_until_ready(plan.execute(a, a).values)
+    assert [r.name for r in spans.recorded()[0]
+            if r.name in ("spgemm.first_call", "spgemm.dispatch")] == [
+        "spgemm.dispatch"]
 
 
 def _names(records, parent):

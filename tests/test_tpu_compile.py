@@ -5,9 +5,13 @@ stream, each compiled for a described (not attached) ``v5e:2x2`` chip.
 Nothing runs: this catches what the interpreter cannot (unaligned blocks,
 unsupported primitives, VMEM overruns) without chip time.  The HASH kernel
 is absent on purpose — the v5e compiler aborts the whole process on it.
+The execute path's one value table is checked in the compiled program:
+both gathers read the one cross-program-prefetched VMEM copy, up to the
+chip's prefetch limit and not past it.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,13 +19,16 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import runtime
-from repro.core import pallas_stream
+from repro.core import jax_stream, pallas_stream
 from repro.core.jax_stream import _bilinear_contract
 
 # Goodwin_013 stand-in (chip_smoke phase (a)): n, max column nnz, products
 N, Z, P_SMALL = 1965, 62, 2_098_840
 # power-law A² (phase (b)): products, operand nnz, output nnz
 P_BIG, NNZ_BIG, NNZ_C_BIG = 59_948_811, 2_998_850, 59_000_000
+# the benchmark's A² cells: products, operand nnz, output nnz
+KRON14 = (156_023_438, 425_666, 40_419_786)
+TABLE1 = (149_059, 32_653, 100_000)
 
 
 @pytest.fixture(scope="module")
@@ -160,3 +167,106 @@ def test_hash_kernel_is_refused_before_compiling(monkeypatch):
         hash_spgemm(x, x.astype(jnp.float32), x[:, 0], x,
                     x.astype(jnp.float32), x[:, 0], x[:1, 0], m=128, h=8,
                     block_cols=128)
+
+
+def _gathers(hlo: str, p: int) -> list:
+    """Per gather fusion of ``p`` values in the entry computation: its
+    value operand, and whether that operand is a cross-program-prefetched
+    copy in VMEM (memory space ``S(1)``) of an entry parameter."""
+    entry = hlo[hlo.index("\nENTRY"):]
+    prefetched = set(re.findall(
+        r"%(\S+) = \(\S+S\(1\)\}.* copy-start\(%\S+\), "
+        r"cross_program_prefetch_index", entry))
+    vmem_copies = {done for done, start in re.findall(
+        r"%(\S+) = f32\[\d+\]\{\S*S\(1\)\} copy-done\(%(\S+)\)", entry)
+        if start in prefetched}
+    found = re.findall(
+        rf"= f32\[{p}\]\{{\S+\}} fusion\(%([^,]+), "
+        r".*op_name=\"[^\"]*/gather\"",
+        entry)
+    return [(operand, operand in vmem_copies) for operand in found]
+
+
+def _one_table_program(s, p, na, nb, n_out):
+    def run(idx, table):
+        return jax_stream._one_table(_bilinear_contract(n_out), na)(idx,
+                                                                    table)
+
+    return _compile(run, tuple(s((p,), jnp.int32) for _ in range(3)),
+                    s((na + nb,), jnp.float32)).as_text()
+
+
+@pytest.mark.parametrize("p,nnz,n_out", [KRON14, TABLE1],
+                         ids=["kron14", "table1"])
+def test_execute_path_gathers_read_one_prefetched_table(one_chip,
+                                                        compiled_mode, p,
+                                                        nnz, n_out):
+    """Both gathers of the one-table executable read the same
+    cross-program-prefetched VMEM copy of the table."""
+    hlo = _one_table_program(_spec(one_chip), p, nnz, nnz, n_out)
+    (a, a_vmem), (b, b_vmem) = _gathers(hlo, p)
+    assert a == b and a_vmem and b_vmem
+
+
+def _two_table_program(s, p, na, nb, n_out):
+    def run(idx, av, bv):
+        return _bilinear_contract(n_out)(idx, av, bv)
+
+    return _compile(run, tuple(s((p,), jnp.int32) for _ in range(3)),
+                    s((na,), jnp.float32), s((nb,), jnp.float32)).as_text()
+
+
+@pytest.fixture
+def v5e_limits(topo, monkeypatch):
+    """The described chip's prefetch limits, as the size rule reads them."""
+    limits = runtime.PREFETCH_LIMITS[topo.devices[0].device_kind]
+    monkeypatch.setattr(runtime, "prefetch_limits", lambda: limits)
+    return limits
+
+
+def test_two_tables_above_the_prefetch_limit(one_chip, compiled_mode,
+                                             v5e_limits):
+    """The cross-program limit is the chip's: a table of its size is
+    prefetched and one value more is not, so both gathers would read HBM.
+    Above it the size rule picks the two-table form, which still
+    prefetches one operand's values (the larger's)."""
+    s = _spec(one_chip)
+    n = v5e_limits.cross_program_bytes // 4
+    p, _, n_out = TABLE1
+    na, nb = n // 2, n - n // 2
+    hlo = _one_table_program(s, p, na, nb, n_out)
+    assert all(vmem for _, vmem in _gathers(hlo, p))
+    assert jax_stream.table_form(na, nb + 1, p, 4) == "two"
+    hlo = _one_table_program(s, p, na, nb + 1, n_out)
+    assert not any(vmem for _, vmem in _gathers(hlo, p))
+    hlo = _two_table_program(s, p, na, nb + 1, n_out)
+    assert [vmem for _, vmem in _gathers(hlo, p)].count(True) == 1
+
+
+def _in_vmem(hlo: str, p: int) -> list:
+    """Per gather of ``p`` values: does it read any VMEM copy (a
+    cross-program or an ordinary prefetch)?"""
+    entry = hlo[hlo.index("\nENTRY"):]
+    copies = set(re.findall(
+        r"%(\S+) = f32\[\d+\]\{\S*S\(1\)\} copy-done", entry))
+    return [operand in copies for operand, _ in _gathers(hlo, p)]
+
+
+@pytest.mark.parametrize("p,nnz,both", [
+    (TABLE1[0], TABLE1[1], True),
+    (TABLE1[0], 261_120, True),              # other_bytes of f32
+    (TABLE1[0], 261_121, False),
+    (117_440_512, TABLE1[1], True),          # other_products
+    (117_440_513, TABLE1[1], False),
+    (KRON14[0], KRON14[1], False),
+], ids=["table1", "other_bytes", "other_bytes+1", "other_products",
+        "other_products+1", "kron14"])
+def test_one_table_where_two_leave_a_gather_in_hbm(one_chip, compiled_mode,
+                                                   v5e_limits, p, nnz, both):
+    """Two tables reach VMEM together only while the smaller operand fits
+    the ordinary prefetch and the gathers are short enough: the size rule
+    keeps two tables exactly there, and packs one table elsewhere."""
+    hlo = _two_table_program(_spec(one_chip), p, nnz, nnz, 100_000)
+    assert all(_in_vmem(hlo, p)) is both
+    assert jax_stream.table_form(nnz, nnz, p, 4) == ("two" if both
+                                                     else "one")
