@@ -21,9 +21,14 @@ matching at the call sites.  The registered pairs:
   last-ulp fp-reassociation vs the oracles.  Default for ``expand``.
 * ``("pallas", "naive")`` — gathers each group's padded value operand with
   the plan's precomputed ``b_vgather``/``b_vmask``, launches one kernel per
-  plan group via ``kernels.ops.run_{spa,spars,hash}``, and compacts each
-  group's tile straight into column-sliced CSC (no dense ``[m, n]`` sink;
-  peak transient memory is one ``[m, tile_cols]`` tile).
+  plan group via ``kernels.ops.run_{spa,spars,hash}``, and compacts the
+  tiles: where the plan holds a ``DeviceLayout`` (dense tiles that fit the
+  device), operands padded on the device, one gather of the tiles on the
+  device and one copy of C's values back; else each group's tile read back
+  and compacted straight into column-sliced CSC on the host (no dense
+  ``[m, n]`` sink; peak transient memory is one ``[m, tile_cols]`` tile).
+  Its phases are the spans ``spgemm.pallas.{operands,group,readback,
+  compact}`` (DESIGN.md §16).
 * ``("jax", "stream")`` — the device-resident stream (``core.jax_stream``,
   DESIGN.md §10): a jitted, differentiable pure-JAX replay of the same
   contraction; one device dispatch per execution.
@@ -44,10 +49,13 @@ where available).  Results are bit-identical to a Python loop of
 
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import runtime
-from repro.core import backends, fast, jax_stream, naive, pallas_stream
+from repro.core import (backends, fast, jax_stream, naive, pallas_stream,
+                        spans)
 from repro.core.backends import check_engine, default_engine, get_backend
 from repro.core.expand import spgemm_expand
 from repro.core.planner import SpgemmPlan
@@ -495,40 +503,105 @@ def _execute_pallas(plan: SpgemmPlan, a_values, b_values, *,
     lay = plan.pallas
     _check_kernels(lay)
     m, n = plan.shape
-    av = padded_values(_values(a_values), lay.a_gather,
-                       lay.a_mask).astype(np.float32, copy=False)
-    b_raw = _values(b_values)
-    a_arrs = kops.device_operand(lay.a_rows, av, lay.a_nnz)
-
-    builder = CSCBuilder((m, n), np.float32)
-    for g in lay.groups:
-        # plan-time-composed masked gather: straight from raw values to the
-        # group operand, no full padded-B intermediate or per-call mask
-        g_vals = padded_values(b_raw, g.b_vgather,
-                               g.b_vmask).astype(np.float32, copy=False)
-        if g.kind == "spa":
-            tile = kops.run_spa(g, a_arrs, g_vals, m=m,
-                                block_cols=lay.block_cols)
-            builder.add_dense_tile(g.cols, tile)
-        elif g.kind == "spars":
-            tile = kops.run_spars(g, a_arrs, g_vals, m=m,
-                                  block_cols=lay.block_cols)
-            builder.add_dense_tile(g.cols, tile)
-        elif g.kind == "hash":
-            keys, vals = kops.run_hash(g, a_arrs, g_vals, m=m,
-                                       block_cols=lay.block_cols)
-            builder.add_hash_tables(g.cols, keys, vals)
-        else:
-            raise AssertionError(g.kind)
-    c = builder.build()
+    za = int(lay.a_rows.shape[1])
+    run = {"spa": kops.run_spa, "spars": kops.run_spars,
+           "hash": kops.run_hash}
+    if lay.device is not None:
+        c = _execute_on_device(plan, a_values, b_values, run, za)
+        tile_shapes = [("dense", (m, g.n_real)) for g in lay.groups]
+    else:
+        c, tile_shapes = _execute_on_host(plan, a_values, b_values, run, za)
     if stats is not None:
         stats.update(engine="naive", backend="pallas", device=True,
                      fallback=None)
-        stats["tile_shapes"] = list(builder.tile_shapes)
-        stats["peak_tile_elems"] = builder.peak_tile_elems
+        stats["tile_shapes"] = tile_shapes
+        stats["peak_tile_elems"] = max(
+            (r * w for _, (r, w) in tile_shapes), default=0)
         stats["n_launches"] = len(lay.groups)
         stats["result_shape"] = (m, n)
+        stats["compacted_on"] = "host" if lay.device is None else "device"
     return c
+
+
+def _execute_on_host(plan, a_values, b_values, run, za) -> tuple:
+    """Pad each group's operand on the host, wait for each tile, read it
+    back and compact it into C's columns (``CSCBuilder``)."""
+    from repro.kernels import ops as kops
+
+    lay = plan.pallas
+    m, n = plan.shape
+    with spans.span("spgemm.pallas.operands"):
+        av = padded_values(_values(a_values), lay.a_gather,
+                           lay.a_mask).astype(np.float32, copy=False)
+        b_raw = _values(b_values)
+        a_arrs = kops.device_operand(lay.a_rows, av, lay.a_nnz)
+
+    builder = CSCBuilder((m, n), np.float32)
+    for g in lay.groups:
+        with spans.span("spgemm.pallas.operands"):
+            # plan-time-composed masked gather: straight from raw values to
+            # the group operand, no full padded-B intermediate or per-call
+            # mask
+            g_vals = jnp.asarray(padded_values(
+                b_raw, g.b_vgather, g.b_vmask).astype(np.float32,
+                                                      copy=False))
+        with spans.span("spgemm.pallas.group", kind=g.kind, cols=g.n_real,
+                        zb=int(g.b_rows.shape[1]), za=za,
+                        products=g.products):
+            out = jax.block_until_ready(
+                run[g.kind](g, a_arrs, g_vals, m=m,
+                            block_cols=lay.block_cols))
+        with spans.span("spgemm.pallas.readback"):
+            tiles = [np.asarray(t)[:, : g.n_real]
+                     for t in (out if g.kind == "hash" else (out,))]
+        with spans.span("spgemm.pallas.compact"):
+            if g.kind == "hash":
+                builder.add_hash_tables(g.cols, *tiles)
+            else:
+                builder.add_dense_tile(g.cols, *tiles)
+    with spans.span("spgemm.pallas.compact"):
+        c = builder.build()
+    return c, list(builder.tile_shapes)
+
+
+def _execute_on_device(plan, a_values, b_values, run, za) -> CSC:
+    """The plan's :class:`~repro.core.planner.DeviceLayout`: the raw values
+    up once and padded on the device, every group launched without
+    waiting, one gather of C's values from the tiles, one copy back, and
+    C's exact zeros dropped as the host compaction of each tile drops
+    them."""
+    from repro.kernels import ops as kops
+
+    lay = plan.pallas
+    dl = lay.device
+    with spans.span("spgemm.pallas.operands"):
+        raw = [jnp.asarray(np.asarray(_values(v)[: int(p.col_ptr[-1])],
+                                      np.float32))
+               for v, p in ((a_values, plan.a), (b_values, plan.b))]
+        a_vals, b_ops = kops.pad_operands(
+            *raw, dl.a_pos, dl.a_src, dl.b_pos, dl.b_src,
+            a_shape=tuple(lay.a_rows.shape), widths=dl.widths,
+            zb=int(lay.groups[0].b_rows.shape[1]))
+        a_arrs = (lay.a_rows, a_vals, lay.a_nnz)
+    tiles = []
+    for g, g_vals in zip(lay.groups, b_ops):
+        with spans.span("spgemm.pallas.group", kind=g.kind, cols=g.n_real,
+                        zb=int(g.b_rows.shape[1]), za=za,
+                        products=g.products):
+            tiles.append(run[g.kind](g, a_arrs, g_vals, m=plan.shape[0],
+                                     block_cols=lay.block_cols))
+    with spans.span("spgemm.pallas.compact"):
+        c_dev = kops.compact_tiles(tiles, dl.src)
+    jax.block_until_ready(c_dev)
+    with spans.span("spgemm.pallas.readback"):
+        vals = np.asarray(c_dev)
+    with spans.span("spgemm.pallas.compact"):
+        keep = np.abs(vals) > 0
+        if keep.all():
+            return CSC(vals, dl.c_rows, dl.c_col_ptr, plan.shape)
+        kept = np.concatenate(([0], np.cumsum(keep))).astype(np.int32)
+        return CSC(vals[keep], dl.c_rows[keep], kept[dl.c_col_ptr],
+                   plan.shape)
 
 
 def _execute_pallas_batched(plan: SpgemmPlan, a_values, b_values, *,

@@ -39,7 +39,7 @@ from repro.sparse.partition import (
     nnz_balanced_col_bounds,
     width_col_bounds,
 )
-from repro.sparse.stats import steps_per_column, tile_stats
+from repro.sparse.stats import ops_per_column, steps_per_column, tile_stats
 
 # method -> base kwargs; the paper's Section 5.3 configurations
 ALGORITHMS = {
@@ -242,7 +242,9 @@ class KernelGroup:
     plan time from the padded layout's gather and the lane-validity mask
     (executions no longer allocate a full padded B nor a per-group
     ``np.where`` mask; the lane selection itself is baked in, so the plan
-    retains no separate sel/valid arrays).
+    retains no separate sel/valid arrays).  ``trips`` are a SPA launch's
+    loop bounds (``spa.trip_counts``, computed here once) and ``products``
+    its scalar products (the ``spgemm.pallas.group`` span's attribute).
     """
 
     kind: str                 # "spa" | "spars" | "hash"
@@ -253,6 +255,8 @@ class KernelGroup:
     b_vmask: np.ndarray       # [n_pad, zb] bool, False for pad slots/lanes
     steps: Optional[jnp.ndarray] = None  # [n_pad/block_cols] trip counts
     h: Optional[int] = None              # hash-table size (kind == "hash")
+    products: int = 0                    # scalar products of the launch
+    trips: Optional[tuple] = None        # SPA: ``spa.trip_counts`` (device)
 
     @property
     def n_real(self) -> int:
@@ -275,6 +279,88 @@ class PallasLayout:
     a_gather: np.ndarray
     a_mask: np.ndarray
     groups: Tuple[KernelGroup, ...]
+    device: Optional["DeviceLayout"] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class DeviceLayout:
+    """A call of a dense-tile plan (SPA, SPARS groups) kept on the device.
+
+    Where a call's tiles and B operands fit ``runtime.device_tile_bytes``,
+    its operands are padded on the device and its tiles compacted there:
+    the raw values go up once, ``a_pos``/``b_pos`` place them (from
+    ``a_src``/``b_src``) into A's padded table and into the groups' B
+    operands laid end to end (``widths`` lanes each, ``zb`` slots a lane),
+    every group launches without waiting, and the tiles, flattened and
+    laid end to end in group order, are gathered at ``src`` (one int32 per
+    C slot, in canonical CSC order), so only C's values come back.
+    ``c_rows``/``c_col_ptr`` are C's symbolic pattern, frozen: results
+    share them with the plan.  A tile holds nothing outside that pattern,
+    so dropping the gathered exact zeros gives what compacting each dense
+    tile on the host gives, and the padding is ``padded_values``' to the
+    bit.
+    """
+
+    a_pos: jnp.ndarray        # [nnz_a] int32 (device) into A's [n_a, za]
+    a_src: jnp.ndarray        # [nnz_a] int32 (device): its A value
+    b_pos: jnp.ndarray        # [k] int32 (device) into the B operands
+    b_src: jnp.ndarray        # [k] int32 (device): its B value
+    widths: Tuple[int, ...]   # each group's padded lanes
+    src: jnp.ndarray          # [nnz_c] int32 (device) into the tiles
+    c_rows: np.ndarray        # [nnz_c] int32
+    c_col_ptr: np.ndarray     # [n+1] int32
+
+
+def _device_layout(a, b, groups, a_gather, a_mask, m: int, n: int
+                   ) -> Optional[DeviceLayout]:
+    """The plan's :class:`DeviceLayout`, or ``None`` where a group is not
+    a dense tile or a call would not fit the device's limit (the executor
+    then pads and compacts group by group on the host)."""
+    from repro import runtime
+    from repro.core.expand import expand_positions
+
+    if not groups or any(g.kind not in ("spa", "spars") for g in groups):
+        return None
+    widths = tuple(int(g.b_nnz.shape[0]) for g in groups)
+    zb = int(groups[0].b_rows.shape[1])
+    lanes = sum(widths)
+    limit = runtime.device_tile_bytes()
+    if max(m, zb) * lanes >= 2 ** 31 or (
+            limit is not None and 4 * (m + zb) * lanes > limit):
+        return None
+    a_pos = np.flatnonzero(a_mask)
+    b_pos, b_src = [], []
+    group_base = np.zeros(n, np.int64)     # flat offset of column j's lane
+    lane_stride = np.zeros(n, np.int64)    # its tile's row stride
+    owned = np.zeros(n, bool)
+    base = lane0 = 0
+    for g, w in zip(groups, widths):
+        pos = np.flatnonzero(g.b_vmask)
+        b_pos.append(lane0 * zb + pos)
+        b_src.append(g.b_vgather.reshape(-1)[pos])
+        group_base[g.cols] = base + np.arange(g.n_real)
+        lane_stride[g.cols] = w
+        owned[g.cols] = True
+        base += m * w
+        lane0 += w
+    p_a, p_b, cols = expand_positions(a.col_ptr, b.col_ptr, b.row_indices)
+    pattern = np.zeros((n, m), bool)       # [column, row]: C's pattern
+    pattern[cols, _np(a.row_indices)[p_a]] = True
+    del p_a, p_b, cols
+    c_cols, c_rows = np.nonzero(pattern)   # column-major, rows ascending
+    if not owned[c_cols].all():
+        raise AssertionError("a column of C lies in no kernel group")
+    c_col_ptr = np.zeros(n + 1, np.int32)
+    np.cumsum(np.bincount(c_cols, minlength=n), out=c_col_ptr[1:])
+    src = group_base[c_cols] + c_rows * lane_stride[c_cols]
+    c_rows = c_rows.astype(np.int32)
+    for arr in (c_rows, c_col_ptr):
+        arr.flags.writeable = False
+    dev = lambda x: jnp.asarray(np.asarray(x).astype(np.int32))
+    return DeviceLayout(dev(a_pos), dev(a_gather.reshape(-1)[a_pos]),
+                        dev(np.concatenate(b_pos)),
+                        dev(np.concatenate(b_src)), widths, dev(src),
+                        c_rows, c_col_ptr)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -812,7 +898,22 @@ def plan_spgemm_tiled(
 # ---------------------------------------------------------------------------
 
 
+def _check_vmem(kinds, a, a_rows, b_rows, block_cols):
+    """Refuse, before anything is uploaded, a schedule whose SPA or SPARS
+    launches need more VMEM than the chip's kernel budget (DESIGN.md §2)."""
+    from repro import runtime
+    from repro.kernels import vmem
+
+    budget = runtime.kernel_vmem_bytes()
+    for kind in sorted(kinds & {"spa", "spars"}):
+        vmem.check(kind, vmem.vmem_bytes(
+            kind, m=a.n_rows, za=a_rows.shape[1], n_a=a.n_cols,
+            zb=b_rows.shape[1], block_cols=block_cols), budget)
+
+
 def _plan_pallas(a, b, method, params, block_cols, tile_cols):
+    from repro.kernels.spa import trip_counts
+
     if tile_cols is None:
         tile_cols = block_cols
     if tile_cols % block_cols:
@@ -847,9 +948,13 @@ def _plan_pallas(a, b, method, params, block_cols, tile_cols):
             steps = np.asarray(steps, np.int32)
             assert len(steps) == n_pad // block_cols, (len(steps), n_pad)
             steps = jnp.asarray(steps)
+        trips = (tuple(map(jnp.asarray, trip_counts(
+            a_nnz, g_rows, g_nnz, block_cols, xp=np)))
+            if kind == "spa" else None)
         groups.append(KernelGroup(kind, cols,
                                   jnp.asarray(g_rows), jnp.asarray(g_nnz),
-                                  vgather, vmask, steps, h))
+                                  vgather, vmask, steps, h,
+                                  int(ops[cols].sum()), trips))
 
     # the kernels process each lane independently, so splitting a family into
     # tile_cols-wide launches changes peak memory, never values
@@ -864,11 +969,16 @@ def _plan_pallas(a, b, method, params, block_cols, tile_cols):
         pre = preprocess(a, b, t=tt, b_min=block_cols, b_max=block_cols)
         head = pre.perm[: pre.split]
 
+    ops = ops_per_column(a, b) if pre is None else pre.ops
+    fam = "hash" if "hash" in method else "spars"
+    kinds = ({"spa"} if len(head) else set()) | (
+        {fam} if method != "spa" and pre.blocks.n_blocks else set())
+    _check_vmem(kinds, a, a_rows, b_rows, block_cols)
+
     for c0 in range(0, len(head), tile_cols):
         add_group("spa", head[c0: c0 + tile_cols])
 
     if method != "spa" and pre.blocks.n_blocks:
-        fam = "hash" if "hash" in method else "spars"
         starts, sizes = pre.blocks.starts, pre.blocks.sizes
         n_blocks = pre.blocks.n_blocks
         # per-block trip count: NOT the block head's Op_j — a lane consumes
@@ -904,5 +1014,7 @@ def _plan_pallas(a, b, method, params, block_cols, tile_cols):
         a_gather=a_gather,
         a_mask=a_mask,
         groups=tuple(groups),
+        device=_device_layout(a, b, groups, a_gather, a_mask,
+                              a.n_rows, n),
     )
     return pre, layout

@@ -1,13 +1,17 @@
 """Host-facing jit'd wrappers around the Pallas kernels.
 
 ``run_spa``/``run_spars``/``run_hash`` each launch one kernel for a single
-plan :class:`~repro.core.planner.KernelGroup` — the per-family column
+plan :class:`~repro.core.planner.KernelGroup` and return its device
+result, padded to whole lane blocks (the executor reads it back) — the
+per-family column
 grouping, padding, trip counts and hash sizes all come pre-computed from the
 plan instead of being re-derived per call.  One launch per distinct hash
 table size H realizes the paper's dynamic table shrinking as compile-time
-VMEM tile selection (DESIGN.md §2); results are compacted per group straight
-into CSC by the executor, so no ``[m, n]`` dense intermediate ever exists
-(DESIGN.md §6).
+VMEM tile selection (DESIGN.md §2).  Where the plan allows, the executor
+pads a call's operands (:func:`pad_operands`) and gathers its dense tiles
+into C's values (:func:`compact_tiles`) on the device, else it pads and
+compacts each group on the host, so no ``[m, n]`` dense intermediate ever
+exists on the host (DESIGN.md §6).
 
 ``run_*_batched`` are the batched twins (DESIGN.md §7): the same plan group
 executed once for B same-pattern value sets — value operands carry a
@@ -20,8 +24,11 @@ plan-then-execute wrapper kept for direct use (tests, notebooks).
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.sparse.format import CSC
@@ -35,38 +42,62 @@ def device_operand(rows: np.ndarray, vals: np.ndarray, nnz: np.ndarray):
     return (jnp.asarray(rows), jnp.asarray(vals), jnp.asarray(nnz))
 
 
-def run_spa(group, a_arrs, b_vals, *, m: int, block_cols: int) -> np.ndarray:
-    """Dense [m, n_real] tile for one SPA plan group."""
+@functools.partial(jax.jit, static_argnames=("a_shape", "widths", "zb"))
+def pad_operands(a_vals, b_vals, a_pos, a_src, b_pos, b_src, *,
+                 a_shape, widths, zb):
+    """A's padded value table and each group's B operand, from the raw
+    values: zeros with the values placed where the plan says
+    (``planner.DeviceLayout``), as ``padded_values`` gives them."""
+    def place(vals, src, pos, size):
+        return jnp.zeros(size, vals.dtype).at[pos].set(
+            vals[src], mode="promise_in_bounds", unique_indices=True)
+
+    a = place(a_vals, a_src, a_pos, a_shape[0] * a_shape[1])
+    b = place(b_vals, b_src, b_pos, sum(widths) * zb)
+    ends = np.cumsum(widths) * zb
+    return a.reshape(a_shape), tuple(
+        b[e - w * zb: e].reshape(w, zb) for w, e in zip(widths, ends))
+
+
+@jax.jit
+def compact_tiles(tiles, src):
+    """C's values from one call's dense tiles: the tiles flattened and laid
+    end to end, gathered at ``src`` (``planner.DeviceLayout``)."""
+    flat = jnp.concatenate([t.reshape(-1) for t in tiles])
+    return flat.at[src].get(mode="promise_in_bounds")
+
+
+def run_spa(group, a_arrs, b_vals, *, m: int, block_cols: int):
+    """Dense [m, n_pad] tile for one SPA plan group, on the device."""
     a_rows, a_vals, a_nnz = a_arrs
-    out = spa_spgemm(
+    return spa_spgemm(
         a_rows, a_vals, a_nnz,
         jnp.asarray(group.b_rows), jnp.asarray(b_vals),
-        jnp.asarray(group.b_nnz),
+        jnp.asarray(group.b_nnz), group.trips,
         m=m, block_cols=block_cols)
-    return np.asarray(out)[:, : group.n_real]
 
 
-def run_spars(group, a_arrs, b_vals, *, m: int, block_cols: int) -> np.ndarray:
-    """Dense [m, n_real] tile for one SPARS plan group (plan-provided steps)."""
+def run_spars(group, a_arrs, b_vals, *, m: int, block_cols: int):
+    """Dense [m, n_pad] tile for one SPARS plan group (plan-provided
+    steps), on the device."""
     a_rows, a_vals, a_nnz = a_arrs
     out, _flags = spars_spgemm(
         a_rows, a_vals, a_nnz,
         jnp.asarray(group.b_rows), jnp.asarray(b_vals),
         jnp.asarray(group.b_nnz), jnp.asarray(group.steps),
         m=m, block_cols=block_cols)
-    return np.asarray(out)[:, : group.n_real]
+    return out
 
 
 def run_hash(group, a_arrs, b_vals, *, m: int, block_cols: int):
-    """Hash tables (keys, vals) [H, n_real] for one HASH plan group."""
+    """Hash tables (keys, vals) [H, n_pad] for one HASH plan group, on the
+    device."""
     a_rows, a_vals, a_nnz = a_arrs
-    keys, vals = hash_spgemm(
+    return hash_spgemm(
         a_rows, a_vals, a_nnz,
         jnp.asarray(group.b_rows), jnp.asarray(b_vals),
         jnp.asarray(group.b_nnz), jnp.asarray(group.steps),
         m=m, h=int(group.h), block_cols=block_cols)
-    return (np.asarray(keys)[:, : group.n_real],
-            np.asarray(vals)[:, : group.n_real])
 
 
 def run_spa_batched(group, a_arrs, b_vals, *, m: int,
@@ -76,7 +107,7 @@ def run_spa_batched(group, a_arrs, b_vals, *, m: int,
     out = spa_spgemm_batched(
         a_rows, a_vals, a_nnz,
         jnp.asarray(group.b_rows), jnp.asarray(b_vals),
-        jnp.asarray(group.b_nnz),
+        jnp.asarray(group.b_nnz), group.trips,
         m=m, block_cols=block_cols)
     return np.asarray(out)[:, :, : group.n_real]
 

@@ -23,6 +23,13 @@ every operand: B's padded columns arrive transposed (``[zb, n_b]``, entry
 XLA; padded entries carry row id -1 and value 0, so no count operand is
 needed.  The gathers run at full f32 precision (row ids and values stay
 exact).
+
+**Trip counts.**  Each loop stops where the rest could add only zeros
+(:func:`trip_counts`, scalar-prefetched, computed once by the plan): the
+B loop at the block's longest column, the row loop of entry ``e`` at the
+longest A column an entry ``e`` gathers.  A's tables stay whole in VMEM,
+one buffer each, under the chip's kernel budget
+(:mod:`repro.kernels.vmem`).
 """
 
 from __future__ import annotations
@@ -35,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro import runtime
+from repro.kernels import vmem
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -60,13 +68,13 @@ def lane_major(rows, vals, nnz, *, lanes: int = 1):
     return r, v
 
 
-def _spa_kernel(b_rows_ref, b_vals_ref, a_rows_ref, a_vals_ref,
+def _spa_kernel(b_trips_ref, z_trips_ref,      # scalar prefetch
+                b_rows_ref, b_vals_ref, a_rows_ref, a_vals_ref,
                 out_ref, ar_ref, av_ref):
     zb, L = b_rows_ref.shape
-    za, n_a = a_rows_ref.shape
+    n_a = a_rows_ref.shape[1]
     m = out_ref.shape[0]
-    a_rows = a_rows_ref[...]
-    a_vals = a_vals_ref[...]
+    i = pl.program_id(0)
     col = jax.lax.broadcasted_iota(jnp.int32, (n_a, L), 0)
     iota_m = jax.lax.broadcasted_iota(jnp.int32, (m, L), 0)
 
@@ -74,12 +82,14 @@ def _spa_kernel(b_rows_ref, b_vals_ref, a_rows_ref, a_vals_ref,
         k = b_rows_ref[pl.ds(e, 1), :].astype(jnp.int32)   # [1, L] A cols
         bv = b_vals_ref[pl.ds(e, 1), :]                      # [1, L]
         # indexed vector load of the A columns: A^T @ one-hot [n_a, L] (MXU)
-        oh = (col == k).astype(a_vals.dtype)
-        ar_ref[...] = jnp.dot(a_rows, oh.astype(jnp.float32),
+        # from the tables' one VMEM copy (a value of a whole table would be
+        # a second)
+        oh = (col == k).astype(a_vals_ref.dtype)
+        ar_ref[...] = jnp.dot(a_rows_ref[...], oh.astype(jnp.float32),
                               precision=_HIGHEST,
                               preferred_element_type=jnp.float32)
-        av_ref[...] = jnp.dot(a_vals, oh, precision=_HIGHEST,
-                              preferred_element_type=a_vals.dtype) * bv
+        av_ref[...] = jnp.dot(a_vals_ref[...], oh, precision=_HIGHEST,
+                              preferred_element_type=a_vals_ref.dtype) * bv
 
         def z_step(z, acc):
             r = jnp.round(ar_ref[pl.ds(z, 1), :]).astype(jnp.int32)
@@ -87,18 +97,42 @@ def _spa_kernel(b_rows_ref, b_vals_ref, a_rows_ref, a_vals_ref,
             # indexed vector store: one-hot row mask FMA on the VMEM tile
             return acc + jnp.where(iota_m == r, contrib, 0)
 
-        return jax.lax.fori_loop(0, za, z_step, acc)
+        # rows past every gathered column's length add nothing: stop there
+        return jax.lax.fori_loop(0, z_trips_ref[i * zb + e], z_step, acc)
 
+    # B entries past every lane's column add nothing: stop there
     out_ref[...] = jax.lax.fori_loop(
-        0, zb, b_step, jnp.zeros((m, L), out_ref.dtype))
+        0, b_trips_ref[i], b_step, jnp.zeros((m, L), out_ref.dtype))
+
+
+def trip_counts(a_nnz, b_rows, b_nnz, block_cols: int, xp=jnp):
+    """``(b_trips [n_blocks], z_trips [n_blocks * zb])`` of a launch, ``zb``
+    B's padded width (a multiple of 8): per block, its longest B column,
+    and per entry ``e`` of its B columns the longest A column an entry
+    ``e`` of the block gathers (0 where none).  ``xp`` is ``jnp`` (inside
+    the kernel's jit) or ``np`` (a plan, once, on the host).
+
+    The kernel's loops stop there; the steps past them would add only
+    zeros, so C is the same to the bit.
+    """
+    n_b, z = b_rows.shape
+    valid = xp.arange(z)[None, :] < b_nnz[:, None]
+    an = xp.where(valid, xp.asarray(a_nnz)[xp.clip(b_rows, 0,
+                                                   len(a_nnz) - 1)], 0)
+    an = xp.pad(an, ((0, 0), (0, _round_up(max(z, 1), 8) - z)))
+    z_trips = an.reshape(n_b // block_cols, block_cols, -1).max(axis=1)
+    b_trips = xp.asarray(b_nnz).reshape(-1, block_cols).max(axis=1)
+    return b_trips.astype(xp.int32), z_trips.reshape(-1).astype(xp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "block_cols"))
-def spa_spgemm(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz,
+def spa_spgemm(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, trips=None,
                *, m: int, block_cols: int = 128):
     """Dense C [m, n_b] = A @ B, SPA dataflow, one grid step per column block.
 
     n_b must be a multiple of block_cols (callers pad; see ops.py).
+    ``trips`` is :func:`trip_counts` of the operands' patterns, which a
+    plan computes once; ``None`` computes them here.
     """
     n_b = b_rows.shape[0]
     assert n_b % block_cols == 0, (n_b, block_cols)
@@ -107,28 +141,31 @@ def spa_spgemm(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz,
     za, n_a = ar.shape
     zb = br.shape[0]
     m8 = _round_up(m, 8)
-    whole = lambda i: (0, 0)
-    out = pl.pallas_call(
-        _spa_kernel,
+    lanes = lambda rows: pl.BlockSpec((rows, block_cols),
+                                      lambda i, *_: (0, i))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(n_b // block_cols,),
-        in_specs=[
-            pl.BlockSpec((zb, block_cols), lambda i: (0, i)),   # b_rows^T
-            pl.BlockSpec((zb, block_cols), lambda i: (0, i)),   # b_vals^T
-            pl.BlockSpec((za, n_a), whole),                     # a_rows^T
-            pl.BlockSpec((za, n_a), whole),                     # a_vals^T
-        ],
-        out_specs=pl.BlockSpec((m8, block_cols), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((m8, n_b), a_vals.dtype),
+        in_specs=[lanes(zb), lanes(zb),                      # b rows, vals
+                  vmem.whole((za, n_a)), vmem.whole((za, n_a))],   # A^T
+        out_specs=lanes(m8),
         scratch_shapes=[pltpu.VMEM((za, block_cols), jnp.float32),
                         pltpu.VMEM((za, block_cols), a_vals.dtype)],
+    )
+    out = pl.pallas_call(
+        _spa_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m8, n_b), a_vals.dtype),
+        compiler_params=vmem.compiler_params(),
         interpret=runtime.interpret_mode(),
-    )(br, bv, ar, av)
+    )(*(trip_counts(a_nnz, b_rows, b_nnz, block_cols) if trips is None
+        else trips), br, bv, ar, av)
     return out[:m]
 
 
 @functools.partial(jax.jit, static_argnames=("m", "block_cols"))
 def spa_spgemm_batched(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz,
-                       *, m: int, block_cols: int = 128):
+                       trips=None, *, m: int, block_cols: int = 128):
     """Batched SPA: dense C [B, m, n_b] for B same-pattern value sets.
 
     Only the value operands carry the batch axis (``a_vals [B, n_a, za]``,
@@ -138,5 +175,5 @@ def spa_spgemm_batched(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz,
     each batch slice is bit-identical to the unbatched kernel.
     """
     f = functools.partial(spa_spgemm, m=m, block_cols=block_cols)
-    return jax.vmap(f, in_axes=(None, 0, None, None, 0, None))(
-        a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz)
+    return jax.vmap(f, in_axes=(None, 0, None, None, 0, None, None))(
+        a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, trips)

@@ -29,6 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro import runtime
+from repro.kernels import vmem
 from repro.kernels.spa import _HIGHEST, _round_up, lane_major
 
 
@@ -45,8 +46,6 @@ def _spars_kernel(steps_ref,            # scalar prefetch: [n_blocks] int32
     zt, n_a = a_tab_ref.shape
     m = out_ref.shape[0]
     steps = steps_ref[pl.program_id(0)]
-    a_tab = a_tab_ref[...]            # [zt, n_a] f32: row ids, then lengths
-    a_vals = a_vals_ref[...]
     b_rows = b_rows_ref[...]
     b_vals = b_vals_ref[...]
     b_nnz = b_nnz_ref[...]            # [1, L]
@@ -62,12 +61,14 @@ def _spars_kernel(steps_ref,            # scalar prefetch: [n_blocks] int32
         sel_b = iota_zb == vidx_b
         bk = jnp.round(_pick(sel_b, b_rows)).astype(jnp.int32)
         bv = _pick(sel_b, b_vals)
-        # -- indexed vector load of vA (A column gather, MXU)
+        # -- indexed vector load of vA (A column gather, MXU), from the
+        # tables' one VMEM copy ([zt, n_a]: row ids, then lengths)
         oh = (iota_na == bk).astype(jnp.float32)          # [n_a, L]
-        tab = jnp.dot(a_tab, oh, precision=_HIGHEST,
+        tab = jnp.dot(a_tab_ref[...], oh, precision=_HIGHEST,
                       preferred_element_type=jnp.float32)  # [zt, L]
-        vals = jnp.dot(a_vals, oh.astype(a_vals.dtype), precision=_HIGHEST,
-                       preferred_element_type=a_vals.dtype)
+        vals = jnp.dot(a_vals_ref[...], oh.astype(a_vals_ref.dtype),
+                       precision=_HIGHEST,
+                       preferred_element_type=a_vals_ref.dtype)
         an = jnp.round(tab[za:za + 1, :]).astype(jnp.int32)   # col lengths
         sel_a = iota_zt == vcnt_a
         r = jnp.round(_pick(sel_a, tab)).astype(jnp.int32)    # [1, L]
@@ -117,7 +118,7 @@ def spars_spgemm(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, steps,
     m8 = _round_up(m, 8)
     kernel = functools.partial(_spars_kernel, za=za)
     lanes = lambda rows: pl.BlockSpec((rows, block_cols), lambda i, s: (0, i))
-    whole = pl.BlockSpec(a_tab.shape, lambda i, s: (0, 0))
+    whole = vmem.whole(a_tab.shape)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_blocks,),
@@ -131,6 +132,7 @@ def spars_spgemm(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, steps,
             jax.ShapeDtypeStruct((m8, n_b), a_vals.dtype),
             jax.ShapeDtypeStruct((m8, n_b), a_vals.dtype),
         ],
+        compiler_params=vmem.compiler_params(),
         interpret=runtime.interpret_mode(),
     )(steps, br, bv, b_nnz.astype(jnp.int32).reshape(1, n_b), a_tab, av)
     return out[:m], flags[:m]

@@ -7,7 +7,9 @@ can never run a kernel interpreted by accident.
 
 The module also sizes the default device plan-memory guard from the chip's
 own memory (:func:`device_stream_limit`), says which value arrays XLA
-copies into the chip's VMEM (:func:`prefetch_limits`) and points JAX's
+copies into the chip's VMEM (:func:`prefetch_limits`) and how much VMEM
+one per-group Pallas kernel may take (:func:`kernel_vmem_bytes`), and
+points JAX's
 persistent compilation cache at a fixed directory
 (:func:`enable_compile_cache`).
 Importing it has no side effects; entry points call what they need.
@@ -66,6 +68,17 @@ PREFETCH_LIMITS = {
     "TPU v5 lite": PrefetchLimits(112 * 2 ** 20, 1_044_480, 112 * 2 ** 20),
 }
 
+#: VMEM one launch of a per-group Pallas kernel (``kernels/spa.py``,
+#: ``kernels/spars.py``) may take, by device kind: its ``vmem_limit_bytes``.
+#: A v5e has 128 MiB; a kernel may take the 112 MiB that XLA's prefetch
+#: across programs may (:data:`PREFETCH_LIMITS`), the rest is XLA's own
+KERNEL_VMEM_BYTES = {
+    "TPU v5 lite": 112 * 2 ** 20,
+}
+
+#: the compiler's default scoped VMEM limit, for a kind not measured yet
+DEFAULT_KERNEL_VMEM_BYTES = 16 * 2 ** 20
+
 #: Pallas kernels that do not compile for a TPU yet (the compiler aborts the
 #: process on them, so they are refused before it is called)
 TPU_REFUSED_KERNELS = frozenset({"hash"})
@@ -117,13 +130,33 @@ def device_stream_limit() -> int | None:
     """
     if platform() != "tpu":
         return None
+    return _device_share_bytes() // DEVICE_BYTES_PER_PRODUCT
+
+
+@functools.cache
+def device_tile_bytes() -> int | None:
+    """Device bytes the dense tiles and B operands of one per-group Pallas
+    call may hold at once, or None where no limit applies.
+
+    A plan whose call fits pads and compacts on the device
+    (``core.planner.DeviceLayout``), so every tile and B operand of a call
+    is alive until its gather.  On a TPU the limit is the share of the chip's memory a
+    plan's stream may take (:data:`DEVICE_STREAM_SHARE` of
+    ``bytes_limit``); elsewhere (CPU) ``None``.
+    """
+    if platform() != "tpu":
+        return None
+    return _device_share_bytes()
+
+
+def _device_share_bytes() -> int:
     stats = jax.devices()[0].memory_stats() or {}
     limit = stats.get("bytes_limit")
     if not limit:
         raise RuntimeError(
             "the TPU reports no memory bytes_limit; cannot size the device "
             "plan-memory guard")
-    return int(limit * DEVICE_STREAM_SHARE) // DEVICE_BYTES_PER_PRODUCT
+    return int(limit * DEVICE_STREAM_SHARE)
 
 
 @functools.cache
@@ -138,6 +171,20 @@ def prefetch_limits() -> PrefetchLimits | None:
         return None
     return PREFETCH_LIMITS.get(jax.devices()[0].device_kind,
                                PrefetchLimits(0, 0, 0))
+
+
+@functools.cache
+def kernel_vmem_bytes() -> int | None:
+    """VMEM budget of one per-group Pallas kernel launch, or None.
+
+    On a TPU, that of the first device's kind (:data:`KERNEL_VMEM_BYTES`),
+    else the compiler's default (:data:`DEFAULT_KERNEL_VMEM_BYTES`).
+    Elsewhere (CPU, kernels interpreted) ``None``: no budget applies.
+    """
+    if platform() != "tpu":
+        return None
+    return KERNEL_VMEM_BYTES.get(jax.devices()[0].device_kind,
+                                 DEFAULT_KERNEL_VMEM_BYTES)
 
 
 def compile_cache_dir() -> Path:

@@ -82,6 +82,21 @@ def test_device_guard_sized_from_chip_memory(monkeypatch):
         runtime.device_stream_limit.cache_clear()
 
 
+def test_device_tile_limit_sized_from_chip_memory(monkeypatch):
+    class Dev:
+        def memory_stats(self):
+            return {"bytes_limit": 16 * 2**30}
+
+    runtime.device_tile_bytes.cache_clear()
+    monkeypatch.setattr(runtime, "platform", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev()])
+    try:
+        assert runtime.device_tile_bytes() == int(
+            16 * 2**30 * runtime.DEVICE_STREAM_SHARE)
+    finally:
+        runtime.device_tile_bytes.cache_clear()
+
+
 @pytest.mark.parametrize("platform, kind, want", [
     ("cpu", None, None),
     ("tpu", "TPU v5 lite",

@@ -141,6 +141,56 @@ def test_a_miss_then_replays_record_the_layer_spans(session):
     assert traced == {r.name for r in records}
 
 
+@pytest.mark.parametrize("compacted_on", ["device", "host"])
+def test_pallas_execute_records_its_four_spans(session, compacted_on,
+                                               monkeypatch):
+    """A replay on the per-group kernels: the values' upload and padding
+    (on the device: once a call; on the host: A's, then each launch's B),
+    one span per launch with its attributes, the readback and the
+    compaction (on the device: one gather, C's values read back once; on
+    the host: each tile read back and compacted), all inside
+    ``spgemm.execute``; the launches' products add up to the multiply's."""
+    from repro.sparse.stats import ops_per_column
+
+    if compacted_on == "host":                      # tiles over the limit
+        monkeypatch.setattr(runtime, "device_tile_bytes", lambda: 1)
+    api.plan_cache_clear()
+    a = random_powerlaw_csc(96, 3.0, seed=4)
+    plan = cached_plan(a, a, "h-spa-40/40", backend="pallas")
+    assert (plan.pallas.device is not None) == (compacted_on == "device")
+    spans.clear()
+    stats = {}
+    plan.execute(a.values, a.values, stats=stats)
+    assert stats["compacted_on"] == compacted_on
+    records, dropped = spans.recorded()
+    assert dropped == 0
+    (root,) = [r for r in records if r.parent == -1]
+    assert root.name == "spgemm.execute"
+    assert all(r.parent == root.index for r in records if r is not root)
+    lay = plan.pallas
+    n = len(lay.groups)
+    names = _names(records, root.index)
+    if compacted_on == "device":
+        assert names == ["spgemm.pallas.operands"] + [
+            "spgemm.pallas.group"] * n + [
+            "spgemm.pallas.compact", "spgemm.pallas.readback",
+            "spgemm.pallas.compact"]
+    else:
+        assert names == ["spgemm.pallas.operands"] + [
+            "spgemm.pallas.operands", "spgemm.pallas.group",
+            "spgemm.pallas.readback", "spgemm.pallas.compact"] * n + [
+            "spgemm.pallas.compact"]
+    groups = [r for r in records if r.name == "spgemm.pallas.group"]
+    assert [r.attrs for r in groups] == [
+        {"kind": g.kind, "cols": g.n_real, "zb": int(g.b_rows.shape[1]),
+         "za": int(lay.a_rows.shape[1]), "products": g.products}
+        for g in lay.groups]
+    assert {g.kind for g in lay.groups} == {"spa", "spars"}
+    assert sum(r.attrs["products"] for r in groups) == int(
+        ops_per_column(a, a).sum())
+    api.plan_cache_clear()
+
+
 def test_the_bound_keeps_the_newest_records_and_counts_drops(
         session, monkeypatch):
     monkeypatch.setattr(spans, "_RECORDER", spans.Recorder(3))

@@ -1,6 +1,8 @@
 """The device path compiles for a TPU v5e, at the widths ``chip_smoke.py``
-drives: the fused stream kernel, the SPA / SPARS / BSR kernels and the XLA
-stream, each compiled for a described (not attached) ``v5e:2x2`` chip.
+drives: the fused stream kernel, the SPA / SPARS / BSR kernels (and SPA /
+SPARS at Table 1's widest A tables, under the chip's kernel VMEM budget)
+and the XLA stream, each compiled for a described (not attached)
+``v5e:2x2`` chip.
 
 Nothing runs: this catches what the interpreter cannot (unaligned blocks,
 unsupported primitives, VMEM overruns) without chip time.  The HASH kernel
@@ -129,6 +131,22 @@ def test_spa_kernel_compiles(one_chip, compiled_mode):
     assert "tpu_custom_call" in c.as_text()
 
 
+def test_spa_kernel_compiles_with_the_plans_trip_counts(one_chip,
+                                                         compiled_mode):
+    """As ``_execute_pallas`` launches it: the loop bounds a plan computed
+    once (``KernelGroup.trips``) arrive as arguments."""
+    from repro.kernels.spa import spa_spgemm
+
+    s = _spec(one_chip)
+    z8 = -(-Z // 8) * 8
+    c = _compile(lambda *a: spa_spgemm(*a[:6], a[6:], m=N, block_cols=128),
+                 s((N, Z), jnp.int32), s((N, Z), jnp.float32),
+                 s((N,), jnp.int32), s((128, Z), jnp.int32),
+                 s((128, Z), jnp.float32), s((128,), jnp.int32),
+                 s((1,), jnp.int32), s((z8,), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
 def test_spars_kernel_compiles(one_chip, compiled_mode):
     from repro.kernels.spars import spars_spgemm
 
@@ -140,6 +158,62 @@ def test_spars_kernel_compiles(one_chip, compiled_mode):
                  s((nb, Z), jnp.float32), s((nb,), jnp.int32),
                  s((nb // 128,), jnp.int32))
     assert "tpu_custom_call" in c.as_text()
+
+
+#: Table 1's widest A tables (n, largest column): A's two lane-major
+#: tables take 20.5 MB and 73.7 MB, over the compiler's default 16 MiB
+WIDE = {"adder_dcop_01": (1813, 1332), "iprob": (3001, 3000)}
+
+
+@pytest.mark.parametrize("kind", ["spa", "spars"])
+@pytest.mark.parametrize("name", sorted(WIDE))
+def test_group_kernels_compile_at_the_widest_table1_tables(
+        one_chip, compiled_mode, monkeypatch, kind, name):
+    """One 128-column launch with A's tables whole in VMEM, one buffer
+    each, under a v5e's kernel budget (``runtime.KERNEL_VMEM_BYTES``)."""
+    from repro.kernels.spa import spa_spgemm
+    from repro.kernels.spars import spars_spgemm
+
+    monkeypatch.setattr(runtime, "kernel_vmem_bytes",
+                        lambda: runtime.KERNEL_VMEM_BYTES["TPU v5 lite"])
+    s = _spec(one_chip)
+    n, z = WIDE[name]
+    args = [s((n, z), jnp.int32), s((n, z), jnp.float32), s((n,), jnp.int32),
+            s((128, z), jnp.int32), s((128, z), jnp.float32),
+            s((128,), jnp.int32)]
+    if kind == "spa":
+        fn = lambda *a: spa_spgemm(*a, m=n, block_cols=128)
+    else:
+        fn = lambda *a: spars_spgemm(*a, m=n, block_cols=128)
+        args.append(s((1,), jnp.int32))
+    c = _compile(fn, *args)
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_device_compaction_compiles_at_iprobs_tiles(one_chip,
+                                                    compiled_mode):
+    """One call's gather of C's values from its tiles, at ``iprob``'s
+    size (24 tiles of [3001, 128], C full: 9,003,003 values), fits the
+    chip (``ops.compact_tiles``)."""
+    from repro.kernels.ops import compact_tiles
+
+    s = _spec(one_chip)
+    tiles = [s((3001, 128), jnp.float32)] * 24
+    c = compact_tiles.lower(tiles, s((9_003_003,), jnp.int32)).compile()
+    assert "gather" in c.as_text()
+
+
+def test_device_padding_compiles_at_iprobs_operands(one_chip, compiled_mode):
+    """One call's operands padded on the device at ``iprob``'s size (9,000
+    values each; A's table [3001, 3000], 24 B operands of [128, 3000]) fit
+    the chip (``ops.pad_operands``)."""
+    from repro.kernels.ops import pad_operands
+
+    s = _spec(one_chip)
+    v, i = s((9_000,), jnp.float32), s((9_000,), jnp.int32)
+    c = pad_operands.lower(v, v, i, i, i, i, a_shape=(3001, 3000),
+                           widths=(128,) * 24, zb=3000).compile()
+    assert "scatter" in c.as_text()
 
 
 def test_bsr_kernel_compiles(one_chip, compiled_mode):
