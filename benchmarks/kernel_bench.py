@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import bsr_from_dense, bsr_spmm, spa_spgemm
+from repro.kernels.vmem import VALUE_PLANES, row_planes
 from repro.sparse import csc_to_padded_columns, random_uniform_csc
 
 
@@ -41,7 +42,7 @@ def run(csv=True):
                 jnp.asarray(c, jnp.int32)) * 2
         us = _time(spa_spgemm, *args, m=n, block_cols=L)
         vmem = (n * L * 4            # accumulator tile
-                + 2 * n * z * 4      # A table (rows+vals)
+                + (row_planes(n) + VALUE_PLANES) * n * z   # A's byte planes
                 + 2 * L * z * 4)     # B block
         rows.append((f"spa_kernel_n{n}_z{z}_L{L}", us, f"vmem_bytes={vmem}"))
 

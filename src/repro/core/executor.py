@@ -496,7 +496,7 @@ def _check_kernels(lay) -> None:
 def _execute_pallas(plan: SpgemmPlan, a_values, b_values, *,
                     stats: dict | None = None,
                     validate: str | None = None) -> CSC:
-    from repro.kernels import ops as kops
+    from repro.kernels import ops as kops, vmem
 
     plan.a.check_compatible(a_values, validate)
     plan.b.check_compatible(b_values, validate)
@@ -504,13 +504,23 @@ def _execute_pallas(plan: SpgemmPlan, a_values, b_values, *,
     _check_kernels(lay)
     m, n = plan.shape
     za = int(lay.a_rows.shape[1])
+    planes = vmem.row_planes(m) + vmem.VALUE_PLANES
     run = {"spa": kops.run_spa, "spars": kops.run_spars,
            "hash": kops.run_hash}
+
+    def attrs(g) -> dict:
+        """The ``spgemm.pallas.group`` span's attributes of group ``g``:
+        SPA and SPARS also name the byte planes of A's table."""
+        return dict(kind=g.kind, cols=g.n_real, zb=int(g.b_rows.shape[1]),
+                    za=za, products=g.products,
+                    **({} if g.kind == "hash" else {"planes": planes}))
+
     if lay.device is not None:
-        c = _execute_on_device(plan, a_values, b_values, run, za)
+        c = _execute_on_device(plan, a_values, b_values, run, attrs)
         tile_shapes = [("dense", (m, g.n_real)) for g in lay.groups]
     else:
-        c, tile_shapes = _execute_on_host(plan, a_values, b_values, run, za)
+        c, tile_shapes = _execute_on_host(plan, a_values, b_values, run,
+                                          attrs)
     if stats is not None:
         stats.update(engine="naive", backend="pallas", device=True,
                      fallback=None)
@@ -523,7 +533,7 @@ def _execute_pallas(plan: SpgemmPlan, a_values, b_values, *,
     return c
 
 
-def _execute_on_host(plan, a_values, b_values, run, za) -> tuple:
+def _execute_on_host(plan, a_values, b_values, run, attrs) -> tuple:
     """Pad each group's operand on the host, wait for each tile, read it
     back and compact it into C's columns (``CSCBuilder``)."""
     from repro.kernels import ops as kops
@@ -545,9 +555,7 @@ def _execute_on_host(plan, a_values, b_values, run, za) -> tuple:
             g_vals = jnp.asarray(padded_values(
                 b_raw, g.b_vgather, g.b_vmask).astype(np.float32,
                                                       copy=False))
-        with spans.span("spgemm.pallas.group", kind=g.kind, cols=g.n_real,
-                        zb=int(g.b_rows.shape[1]), za=za,
-                        products=g.products):
+        with spans.span("spgemm.pallas.group", **attrs(g)):
             out = jax.block_until_ready(
                 run[g.kind](g, a_arrs, g_vals, m=m,
                             block_cols=lay.block_cols))
@@ -564,7 +572,7 @@ def _execute_on_host(plan, a_values, b_values, run, za) -> tuple:
     return c, list(builder.tile_shapes)
 
 
-def _execute_on_device(plan, a_values, b_values, run, za) -> CSC:
+def _execute_on_device(plan, a_values, b_values, run, attrs) -> CSC:
     """The plan's :class:`~repro.core.planner.DeviceLayout`: the raw values
     up once and padded on the device, every group launched without
     waiting, one gather of C's values from the tiles, one copy back, and
@@ -585,9 +593,7 @@ def _execute_on_device(plan, a_values, b_values, run, za) -> CSC:
         a_arrs = (lay.a_rows, a_vals, lay.a_nnz)
     tiles = []
     for g, g_vals in zip(lay.groups, b_ops):
-        with spans.span("spgemm.pallas.group", kind=g.kind, cols=g.n_real,
-                        zb=int(g.b_rows.shape[1]), za=za,
-                        products=g.products):
+        with spans.span("spgemm.pallas.group", **attrs(g)):
             tiles.append(run[g.kind](g, a_arrs, g_vals, m=plan.shape[0],
                                      block_cols=lay.block_cols))
     with spans.span("spgemm.pallas.compact"):

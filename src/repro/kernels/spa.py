@@ -17,19 +17,26 @@ line-11 "store as sparse".
 
 **Layout.**  Inside the kernel the C columns are the 128-wide lane axis of
 every operand: B's padded columns arrive transposed (``[zb, n_b]``, entry
-``e`` of the block's columns is sublane row ``e``) and A's tables transposed
-(``[za, n_a]``), so each step reads one row and the one-hot gather
-``A^T @ onehot`` lands lane-major.  :func:`lane_major` builds these views in
-XLA; padded entries carry row id -1 and value 0, so no count operand is
-needed.  The gathers run at full f32 precision (row ids and values stay
-exact).
+``e`` of the block's columns is sublane row ``e``), and A's columns as one
+transposed int8 table of byte planes (:func:`a_table`), so each step reads
+one row and the one-hot gather ``A^T @ onehot`` lands lane-major.
+:func:`lane_major` builds the transposed views in XLA; padded entries
+carry row id -1 and value 0, so no count operand is needed.
+
+**Exact gather.**  A one-hot matmul with integer accumulation returns each
+byte of the gathered word exactly, so A's table holds the bytes of each
+entry's row code (row id + 1, padding 0; :func:`~repro.kernels.vmem
+.row_planes` of them, from C's row count) and of its f32 bit pattern (4),
+one plane after the other, and one ``int8 @ int8 -> int32`` dot on the MXU
+gathers every plane of a B entry's A columns.  Shifts, ``|`` and a bitcast
+put the row ids and values back together bit for bit: ±0, denormals, inf
+and NaN included.
 
 **Trip counts.**  Each loop stops where the rest could add only zeros
 (:func:`trip_counts`, scalar-prefetched, computed once by the plan): the
 B loop at the block's longest column, the row loop of entry ``e`` at the
-longest A column an entry ``e`` gathers.  A's tables stay whole in VMEM,
-one buffer each, under the chip's kernel budget
-(:mod:`repro.kernels.vmem`).
+longest A column an entry ``e`` gathers.  A's table stays whole in VMEM,
+one buffer, under the chip's kernel budget (:mod:`repro.kernels.vmem`).
 """
 
 from __future__ import annotations
@@ -43,59 +50,106 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import runtime
 from repro.kernels import vmem
-
-_HIGHEST = jax.lax.Precision.HIGHEST
+from repro.kernels.vmem import VALUE_PLANES, row_planes
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def lane_major(rows, vals, nnz, *, lanes: int = 1):
+def lane_major(rows, vals, nnz, *, lanes: int = 1,
+               z_rows: int | None = None):
     """Transposed, masked, tile-padded view of a padded-column operand.
 
-    ``rows``/``vals`` ``[n, z]`` -> ``[z8, n']`` with ``z8`` a multiple of 8
-    and ``n'`` a multiple of ``lanes``; entries at or past a column's
-    ``nnz`` (and all padding) get row id -1 and value 0.  Row ids come back
-    as f32 (exact below 2**24) so the one-hot gather is one MXU pass.
+    ``rows``/``vals`` ``[n, z]`` -> int32 row ids and values ``[z_rows,
+    n']``, ``z_rows`` by default ``z`` padded to a multiple of 8 and
+    ``n'`` a multiple of ``lanes``; entries at or past a column's ``nnz``
+    (and all padding) get row id -1 and value 0.
     """
     n, z = rows.shape
+    if z_rows is None:
+        z_rows = _round_up(max(z, 1), 8)
     valid = jax.lax.broadcasted_iota(jnp.int32, (n, z), 1) < nnz[:, None]
-    pad = ((0, _round_up(max(z, 1), 8) - z), (0, _round_up(n, lanes) - n))
-    r = jnp.pad(jnp.where(valid, rows, -1).T.astype(jnp.float32), pad,
+    pad = ((0, z_rows - z), (0, _round_up(n, lanes) - n))
+    r = jnp.pad(jnp.where(valid, rows, -1).T.astype(jnp.int32), pad,
                 constant_values=-1)
     v = jnp.pad(jnp.where(valid, vals, 0).T, pad)
     return r, v
 
 
+def byte_planes(words, n: int):
+    """int8 ``[n · z, n_a]``: bytes ``0..n-1`` of the int32 ``words [z,
+    n_a]``, low byte first, one plane after the other."""
+    return jnp.concatenate([jax.lax.bitcast_convert_type(
+        ((words >> 8 * p) & 0xFF).astype(jnp.uint8), jnp.int8)
+        for p in range(n)])
+
+
+def from_bytes(planes):
+    """The int32 words whose bytes, low first, are the gathered
+    ``planes``; the int8 dot reads a byte of 128 or more as negative, so
+    each is masked to its 8 bits."""
+    word = planes[0] & 0xFF
+    for p, b in enumerate(planes[1:], 1):
+        word = word | ((b & 0xFF) << 8 * p)
+    return word
+
+
+def a_table(rows, vals, nnz, *, m: int, kind: str):
+    """A's gather table: int8 ``[(row_planes(m) + 4) · zt, n_a']``,
+    ``zt = vmem.table_rows(kind, za)``: the byte planes of the lane-major
+    row codes (row id + 1, padding 0; a SPARS table's row ``za`` holds
+    A's column lengths), then those of the f32 values' bit patterns.
+    """
+    za = rows.shape[1]
+    r, v = lane_major(rows, vals, nnz, lanes=128,
+                      z_rows=vmem.table_rows(kind, za))
+    codes = r + 1
+    if kind == "spars":
+        codes = codes.at[za].set(jnp.pad(nnz.astype(jnp.int32),
+                                         (0, codes.shape[1] - nnz.shape[0])))
+    bits = jax.lax.bitcast_convert_type(v.astype(jnp.float32), jnp.int32)
+    return jnp.concatenate([byte_planes(codes, row_planes(m)),
+                            byte_planes(bits, VALUE_PLANES)])
+
+
+def gathered(planes, row_bytes: int):
+    """``(row ids, f32 values)`` from the gathered bytes of one table row
+    (``planes``: each plane's, the ``row_bytes`` planes of row codes
+    first); a padded entry, or a column no one-hot selects, reads row
+    -1."""
+    r = from_bytes(planes[:row_bytes]) - 1
+    return r, jax.lax.bitcast_convert_type(from_bytes(planes[row_bytes:]),
+                                           jnp.float32)
+
+
 def _spa_kernel(b_trips_ref, z_trips_ref,      # scalar prefetch
-                b_rows_ref, b_vals_ref, a_rows_ref, a_vals_ref,
-                out_ref, ar_ref, av_ref):
+                b_rows_ref, b_vals_ref, a_ref,
+                out_ref, g_ref, *, row_bytes: int):
     zb, L = b_rows_ref.shape
-    n_a = a_rows_ref.shape[1]
+    zp, n_a = a_ref.shape
+    planes = row_bytes + VALUE_PLANES
+    zt = zp // planes
     m = out_ref.shape[0]
     i = pl.program_id(0)
     col = jax.lax.broadcasted_iota(jnp.int32, (n_a, L), 0)
     iota_m = jax.lax.broadcasted_iota(jnp.int32, (m, L), 0)
 
     def b_step(e, acc):
-        k = b_rows_ref[pl.ds(e, 1), :].astype(jnp.int32)   # [1, L] A cols
+        k = b_rows_ref[pl.ds(e, 1), :]                       # [1, L] A cols
         bv = b_vals_ref[pl.ds(e, 1), :]                      # [1, L]
-        # indexed vector load of the A columns: A^T @ one-hot [n_a, L] (MXU)
-        # from the tables' one VMEM copy (a value of a whole table would be
-        # a second)
-        oh = (col == k).astype(a_vals_ref.dtype)
-        ar_ref[...] = jnp.dot(a_rows_ref[...], oh.astype(jnp.float32),
-                              precision=_HIGHEST,
-                              preferred_element_type=jnp.float32)
-        av_ref[...] = jnp.dot(a_vals_ref[...], oh, precision=_HIGHEST,
-                              preferred_element_type=a_vals_ref.dtype) * bv
+        # indexed vector load of the A columns: every byte plane of A^T
+        # through one exact int8 one-hot [n_a, L] matmul (MXU), from the
+        # table's one VMEM copy (a value of the whole table would be a
+        # second)
+        g_ref[...] = jnp.dot(a_ref[...], (col == k).astype(jnp.int8),
+                             preferred_element_type=jnp.int32)
 
         def z_step(z, acc):
-            r = jnp.round(ar_ref[pl.ds(z, 1), :]).astype(jnp.int32)
-            contrib = av_ref[pl.ds(z, 1), :]                 # [1, L]
+            r, av = gathered([g_ref[pl.ds(p * zt + z, 1), :]
+                              for p in range(planes)], row_bytes)
             # indexed vector store: one-hot row mask FMA on the VMEM tile
-            return acc + jnp.where(iota_m == r, contrib, 0)
+            return acc + jnp.where(iota_m == r, av * bv, 0)
 
         # rows past every gathered column's length add nothing: stop there
         return jax.lax.fori_loop(0, z_trips_ref[i * zb + e], z_step, acc)
@@ -136,9 +190,8 @@ def spa_spgemm(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, trips=None,
     """
     n_b = b_rows.shape[0]
     assert n_b % block_cols == 0, (n_b, block_cols)
-    ar, av = lane_major(a_rows, a_vals, a_nnz, lanes=128)
+    table = a_table(a_rows, a_vals, a_nnz, m=m, kind="spa")
     br, bv = lane_major(b_rows, b_vals, b_nnz)
-    za, n_a = ar.shape
     zb = br.shape[0]
     m8 = _round_up(m, 8)
     lanes = lambda rows: pl.BlockSpec((rows, block_cols),
@@ -147,19 +200,19 @@ def spa_spgemm(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, trips=None,
         num_scalar_prefetch=2,
         grid=(n_b // block_cols,),
         in_specs=[lanes(zb), lanes(zb),                      # b rows, vals
-                  vmem.whole((za, n_a)), vmem.whole((za, n_a))],   # A^T
+                  vmem.whole(table.shape)],                  # A^T planes
         out_specs=lanes(m8),
-        scratch_shapes=[pltpu.VMEM((za, block_cols), jnp.float32),
-                        pltpu.VMEM((za, block_cols), a_vals.dtype)],
+        scratch_shapes=[pltpu.VMEM((table.shape[0], block_cols),
+                                   jnp.int32)],
     )
     out = pl.pallas_call(
-        _spa_kernel,
+        functools.partial(_spa_kernel, row_bytes=row_planes(m)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m8, n_b), a_vals.dtype),
+        out_shape=jax.ShapeDtypeStruct((m8, n_b), jnp.float32),
         compiler_params=vmem.compiler_params(),
         interpret=runtime.interpret_mode(),
     )(*(trip_counts(a_nnz, b_rows, b_nnz, block_cols) if trips is None
-        else trips), br, bv, ar, av)
+        else trips), br, bv, table)
     return out[:m]
 
 

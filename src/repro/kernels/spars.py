@@ -13,10 +13,12 @@ The per-block trip count (max Op_j in the block) is data-dependent; it rides
 in as a scalar-prefetch operand per grid step, exactly how a production TPU
 kernel consumes CSC pointer structure (PrefetchScalarGridSpec).
 
-Layout as in ``kernels/spa.py``: the lanes are the block's C columns, B and
-A arrive transposed (:func:`~repro.kernels.spa.lane_major`), and the cursor
-vectors are ``[1, L]`` rows.  A's column lengths ride as one extra row of
-the A row table, so one MXU pass gathers row ids and lengths together.
+Layout and gather as in ``kernels/spa.py``: the lanes are the block's C
+columns, B arrives transposed (:func:`~repro.kernels.spa.lane_major`) and
+A as one int8 table of byte planes (:func:`~repro.kernels.spa.a_table`),
+and the cursor vectors are ``[1, L]`` rows.  A's column lengths ride as
+one extra row of the row-code planes, so one exact int8 matmul gathers
+row ids, values and lengths together.
 """
 
 from __future__ import annotations
@@ -30,7 +32,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import runtime
 from repro.kernels import vmem
-from repro.kernels.spa import _HIGHEST, _round_up, lane_major
+from repro.kernels.spa import (_round_up, a_table, from_bytes, gathered,
+                               lane_major)
+from repro.kernels.vmem import VALUE_PLANES, row_planes
 
 
 def _pick(sel, table):
@@ -39,11 +43,12 @@ def _pick(sel, table):
 
 
 def _spars_kernel(steps_ref,            # scalar prefetch: [n_blocks] int32
-                  b_rows_ref, b_vals_ref, b_nnz_ref,
-                  a_tab_ref, a_vals_ref,
-                  out_ref, flags_ref, *, za: int):
+                  b_rows_ref, b_vals_ref, b_nnz_ref, a_ref,
+                  out_ref, flags_ref, *, za: int, row_bytes: int):
     zb, L = b_rows_ref.shape
-    zt, n_a = a_tab_ref.shape
+    zp, n_a = a_ref.shape
+    planes = row_bytes + VALUE_PLANES
+    zt = zp // planes
     m = out_ref.shape[0]
     steps = steps_ref[pl.program_id(0)]
     b_rows = b_rows_ref[...]
@@ -59,20 +64,18 @@ def _spars_kernel(steps_ref,            # scalar prefetch: [n_blocks] int32
         active = vidx_b < b_nnz                           # [1, L] vMask
         # -- indexed vector load of vB (this lane's B column entry)
         sel_b = iota_zb == vidx_b
-        bk = jnp.round(_pick(sel_b, b_rows)).astype(jnp.int32)
+        bk = _pick(sel_b, b_rows)
         bv = _pick(sel_b, b_vals)
-        # -- indexed vector load of vA (A column gather, MXU), from the
-        # tables' one VMEM copy ([zt, n_a]: row ids, then lengths)
-        oh = (iota_na == bk).astype(jnp.float32)          # [n_a, L]
-        tab = jnp.dot(a_tab_ref[...], oh, precision=_HIGHEST,
-                      preferred_element_type=jnp.float32)  # [zt, L]
-        vals = jnp.dot(a_vals_ref[...], oh.astype(a_vals_ref.dtype),
-                       precision=_HIGHEST,
-                       preferred_element_type=a_vals_ref.dtype)
-        an = jnp.round(tab[za:za + 1, :]).astype(jnp.int32)   # col lengths
+        # -- indexed vector load of vA (A column gather, MXU): every byte
+        # plane through one exact int8 one-hot matmul, from the table's
+        # one VMEM copy
+        g = jnp.dot(a_ref[...], (iota_na == bk).astype(jnp.int8),
+                    preferred_element_type=jnp.int32)     # [zp, L]
+        an = from_bytes([g[p * zt + za:p * zt + za + 1]   # col lengths
+                         for p in range(row_bytes)])
         sel_a = iota_zt == vcnt_a
-        r = jnp.round(_pick(sel_a, tab)).astype(jnp.int32)    # [1, L]
-        av = _pick(sel_a, vals)
+        r, av = gathered([_pick(sel_a, g[p * zt:(p + 1) * zt])
+                          for p in range(planes)], row_bytes)
         # -- FMA + indexed store into the [m, L] accumulator
         hit = (iota_m == r) & active
         acc = acc + jnp.where(hit, av * bv, 0)
@@ -105,36 +108,29 @@ def spars_spgemm(a_rows, a_vals, a_nnz, b_rows, b_vals, b_nnz, steps,
     n_b = b_rows.shape[0]
     assert n_b % block_cols == 0, (n_b, block_cols)
     n_blocks = n_b // block_cols
-    ar, av = lane_major(a_rows, a_vals, a_nnz, lanes=128)
-    za = a_rows.shape[1]
-    if za == ar.shape[0]:             # no padding row left for the lengths
-        ar = jnp.pad(ar, ((0, 8), (0, 0)), constant_values=-1)
-        av = jnp.pad(av, ((0, 8), (0, 0)))
-    # A's column lengths as row ``za`` of the row table
-    a_tab = ar.at[za].set(jnp.pad(a_nnz.astype(jnp.float32),
-                                  (0, ar.shape[1] - a_nnz.shape[0])))
+    table = a_table(a_rows, a_vals, a_nnz, m=m, kind="spars")
     br, bv = lane_major(b_rows, b_vals, b_nnz)
     zb = br.shape[0]
     m8 = _round_up(m, 8)
-    kernel = functools.partial(_spars_kernel, za=za)
+    kernel = functools.partial(_spars_kernel, za=a_rows.shape[1],
+                               row_bytes=row_planes(m))
     lanes = lambda rows: pl.BlockSpec((rows, block_cols), lambda i, s: (0, i))
-    whole = vmem.whole(a_tab.shape)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_blocks,),
-        in_specs=[lanes(zb), lanes(zb), lanes(1), whole, whole],
+        in_specs=[lanes(zb), lanes(zb), lanes(1), vmem.whole(table.shape)],
         out_specs=[lanes(m8), lanes(m8)],
     )
     out, flags = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((m8, n_b), a_vals.dtype),
-            jax.ShapeDtypeStruct((m8, n_b), a_vals.dtype),
+            jax.ShapeDtypeStruct((m8, n_b), jnp.float32),
+            jax.ShapeDtypeStruct((m8, n_b), jnp.float32),
         ],
         compiler_params=vmem.compiler_params(),
         interpret=runtime.interpret_mode(),
-    )(steps, br, bv, b_nnz.astype(jnp.int32).reshape(1, n_b), a_tab, av)
+    )(steps, br, bv, b_nnz.astype(jnp.int32).reshape(1, n_b), table)
     return out[:m], flags[:m]
 
 

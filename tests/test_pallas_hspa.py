@@ -113,15 +113,44 @@ def test_trip_counts_by_hand():
 
 
 def test_vmem_rule_counts_the_tables_once():
-    """A's two tables take one buffer each: growing A's width by one
-    8-row tile adds its two tables' bytes once, and the gathered-column
-    scratch and values, not a second copy of the tables."""
+    """A's byte-plane table takes one buffer: growing A's width by one
+    32-row int8 tile adds that tile to each of its six planes (two of row
+    codes at m = 1813, four of values) once, and the int32 gather of those
+    rows twice (the dot's result and its scratch), not a second copy of
+    the table."""
     kw = dict(m=1813, n_a=1813, zb=1332, block_cols=128)
-    grow = vmem.vmem_bytes("spa", za=1340, **kw) \
+    grow = vmem.vmem_bytes("spa", za=1364, **kw) \
         - vmem.vmem_bytes("spa", za=1332, **kw)
-    table_tile = vmem.tile_bytes(8, 1813)
-    assert grow == 2 * table_tile + 4 * vmem.tile_bytes(8, 128)
+    planes = vmem.row_planes(1813) + vmem.VALUE_PLANES
+    assert planes == 6
+    table_tile = vmem.tile_bytes(32, 1813, 1)
+    assert table_tile == 32 * 1920
+    assert grow == planes * table_tile + 2 * planes * vmem.tile_bytes(32, 128)
     assert vmem.tile_bytes(3000, 3001) == 3000 * 3072 * 4
+    assert vmem.tile_bytes(3000, 3001, 1) == 3008 * 3072
+
+
+def _dense18():
+    """``(name, n, largest column)`` of each matrix of the cell
+    ``table1_dense18.hspa``: the shapes of its 18 plans (A·A, so B's
+    widest column is A's)."""
+    from pathlib import Path
+
+    data = Path(__file__).parents[1] / "benchmarks" / "chip" / "data"
+    with np.load(data / "table1_dense18.npz") as d:
+        return [(str(name), len(d[f"{name}.indptr"]) - 1,
+                 int(np.diff(d[f"{name}.indptr"]).max()))
+                for name in d["names"]]
+
+
+@pytest.mark.parametrize("kind", ["spa", "spars"])
+@pytest.mark.parametrize("i", range(18))
+def test_every_dense18_plan_fits_a_v5e(kind, i):
+    """Each of the 18 plans' launches passes ``vmem.check`` at a v5e's
+    budget."""
+    name, n, z = _dense18()[i]
+    vmem.check(kind, vmem.vmem_bytes(kind, m=n, za=z, n_a=n, zb=z,
+                                     block_cols=128), V5E)
 
 
 @pytest.mark.parametrize("kind", ["spa", "spars"])

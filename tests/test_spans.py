@@ -183,7 +183,8 @@ def test_pallas_execute_records_its_four_spans(session, compacted_on,
     groups = [r for r in records if r.name == "spgemm.pallas.group"]
     assert [r.attrs for r in groups] == [
         {"kind": g.kind, "cols": g.n_real, "zb": int(g.b_rows.shape[1]),
-         "za": int(lay.a_rows.shape[1]), "products": g.products}
+         "za": int(lay.a_rows.shape[1]), "products": g.products,
+         "planes": 5}      # m = 96: one plane of row codes, four of values
         for g in lay.groups]
     assert {g.kind for g in lay.groups} == {"spa", "spars"}
     assert sum(r.attrs["products"] for r in groups) == int(

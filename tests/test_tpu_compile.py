@@ -1,8 +1,8 @@
 """The device path compiles for a TPU v5e, at the widths ``chip_smoke.py``
 drives: the fused stream kernel, the SPA / SPARS / BSR kernels (and SPA /
-SPARS at Table 1's widest A tables, under the chip's kernel VMEM budget)
-and the XLA stream, each compiled for a described (not attached)
-``v5e:2x2`` chip.
+SPARS at Table 1's widest A tables, gathered by an int8 dot, under the
+chip's kernel VMEM budget) and the XLA stream, each compiled for a
+described (not attached) ``v5e:2x2`` chip.
 
 Nothing runs: this catches what the interpreter cannot (unaligned blocks,
 unsupported primitives, VMEM overruns) without chip time.  The HASH kernel
@@ -160,17 +160,37 @@ def test_spars_kernel_compiles(one_chip, compiled_mode):
     assert "tpu_custom_call" in c.as_text()
 
 
-#: Table 1's widest A tables (n, largest column): A's two lane-major
-#: tables take 20.5 MB and 73.7 MB, over the compiler's default 16 MiB
+#: Table 1's widest A tables (n, largest column): A's byte-plane table
+#: (six int8 planes each) takes 15.5 MB and 55.4 MB, the first with its
+#: gathers already over the compiler's default 16 MiB
 WIDE = {"adder_dcop_01": (1813, 1332), "iprob": (3001, 3000)}
+
+
+def _kernel_dots(jaxpr) -> list:
+    """``(operand dtypes, precision)`` of every ``dot_general`` inside the
+    ``pallas_call`` bodies of ``jaxpr``, however deep in loops."""
+    def walk(j, inside):
+        for eqn in j.eqns:
+            if inside and eqn.primitive.name == "dot_general":
+                yield (tuple(str(v.aval.dtype) for v in eqn.invars),
+                       eqn.params.get("precision"))
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from walk(sub, inside or
+                                        eqn.primitive.name == "pallas_call")
+    return list(walk(jaxpr.jaxpr, False))
 
 
 @pytest.mark.parametrize("kind", ["spa", "spars"])
 @pytest.mark.parametrize("name", sorted(WIDE))
 def test_group_kernels_compile_at_the_widest_table1_tables(
         one_chip, compiled_mode, monkeypatch, kind, name):
-    """One 128-column launch with A's tables whole in VMEM, one buffer
-    each, under a v5e's kernel budget (``runtime.KERNEL_VMEM_BYTES``)."""
+    """One 128-column launch with A's byte-plane table whole in VMEM, one
+    buffer, under a v5e's kernel budget (``runtime.KERNEL_VMEM_BYTES``);
+    the kernel's one gather is an int8 dot with int32 accumulation, not
+    an f32 one at ``HIGHEST``."""
     from repro.kernels.spa import spa_spgemm
     from repro.kernels.spars import spars_spgemm
 
@@ -186,6 +206,8 @@ def test_group_kernels_compile_at_the_widest_table1_tables(
     else:
         fn = lambda *a: spars_spgemm(*a, m=n, block_cols=128)
         args.append(s((1,), jnp.int32))
+    assert _kernel_dots(jax.make_jaxpr(fn)(*args)) == [
+        (("int8", "int8"), None)]
     c = _compile(fn, *args)
     assert "tpu_custom_call" in c.as_text()
 
