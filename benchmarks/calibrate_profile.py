@@ -2,11 +2,11 @@
 
 Runs the synthetic microbenchmark ladder of ``repro.core.profile`` — host
 SPA regimes, the plan-resident product stream, the guard-tripped transient
-rebuild, the jitted device stream, the fused Pallas kernel, and (with >1
-device) a real ``psum_scatter`` payload ladder — fits the
-``CostConstants`` terms by weighted least squares, searches the structural
-knobs (stream guard, fused block, auto tile targets), and writes one JSON
-profile per machine fingerprint under ``REPRO_PROFILE_DIR`` (or ``--out``).
+rebuild, the jitted device stream, and (with >1 device) a real
+``psum_scatter`` payload ladder — fits the ``CostConstants`` terms by
+weighted least squares, searches the structural knobs (stream guard, auto
+tile targets), and writes one JSON profile per machine fingerprint under
+``REPRO_PROFILE_DIR`` (or ``--out``).
 
 After this runs, every ``method="auto"`` consult on this machine ranks
 engines on *measured* constants instead of the shipped defaults.  CI runs

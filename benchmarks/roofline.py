@@ -2,7 +2,7 @@
 
 The paper's "approaches the roofline" claim needs a number, not prose.  For
 each numeric engine (host ``naive``/SPA, host ``stream``, ``jax`` device
-stream, ``fused`` Pallas kernel) this script times the plan-reuse numeric
+stream) this script times the plan-reuse numeric
 phase of the PR 3 mixed-density workload and reports, per engine:
 
 * **GFLOP/s** — ``2 * P`` flops (one multiply + one accumulate per stream
@@ -32,9 +32,7 @@ bandwidth bound", which is exactly the comparison the paper makes.
 
     PYTHONPATH=src python benchmarks/roofline.py [--smoke] [--out PATH]
 
-Writes ``BENCH_roofline.json``; importable pieces
-(:func:`measure_peak_bandwidth`, :func:`stream_bytes_model`,
-:func:`bandwidth_fraction`) are shared with ``benchmarks/executor_fused.py``.
+Writes ``BENCH_roofline.json``.
 """
 
 from __future__ import annotations
@@ -99,7 +97,6 @@ def _engines(a, b):
         ("naive", ps, lambda: ps.execute(a, b, engine="naive"), 8, 8),
         ("stream", ph, lambda: ph.execute(a, b, engine="stream"), 8, 8),
         ("jax", pj, _dev(lambda: pj.execute(a, b, engine="stream")), 4, 4),
-        ("fused", pj, _dev(lambda: pj.execute(a, b, engine="fused")), 4, 4),
     ]
 
 
@@ -131,7 +128,7 @@ def run(m: int = 256, n_sparse: int = 992, dense_a: int = 32,
     for name, plan, fn, vsz, isz in engines:
         c = fn() if name != "naive" else None           # warmup/trace
         got = csc_to_dense(plan.execute(a, b).to_host()) \
-            if name in ("jax", "fused") else csc_to_dense(
+            if name == "jax" else csc_to_dense(
                 plan.execute(a, b, engine=name))
         if ref is None:
             ref = got
@@ -158,8 +155,8 @@ def run(m: int = 256, n_sparse: int = 992, dense_a: int = 32,
         print(f"| {r['engine']:6s} | {r['t_ms']:8.3f} | {r['gflops']:7.3f} "
               f"| {r['bw_achieved_gbs']:8.3f} | {r['bw_frac']:10.4f} |"
               f"{'' if r['correct'] else '  !! MISMATCH'}")
-    print("\n(the Pallas interpreter emulates the kernel on the CPU, and the "
-          "bound is the host's: no row here is a device number)")
+    print("\n(the jax row runs XLA on the CPU, and the bound is the "
+          "host's: no row here is a device number)")
 
     report = {
         "bench": "roofline",

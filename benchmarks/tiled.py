@@ -406,30 +406,6 @@ def calibrate():
         lambda: ptj.execute(tiny, tiny).values.block_until_ready(),
         reps=20)
 
-    # fused Pallas stream kernel (DESIGN.md §11): cached-trace steady state
-    # + dispatch overhead, like the jax pair.  Interpret mode on CPU, so on
-    # the CI container these are the honest numbers that keep "fused" out
-    # of every host auto choice; re-run on a real device before trusting
-    # auto to pick it.  A smaller stream than the jax probe keeps the
-    # interpret-mode emulation (minutes/Mproduct) inside benchmark budget.
-    af = csc_from_dense(np.ones((128, 128)))
-    bfd = np.zeros((128, 64))
-    for j in range(64):
-        bfd[rng.integers(128, size=4), j] = 1.0
-    bf = csc_from_dense(bfd)
-    pf = plan_spgemm(af, bf, "expand", backend="jax")
-    pf.execute(af, bf, engine="fused")   # warmup: views + trace
-    fused_prod = best_of(
-        lambda: pf.execute(af, bf, engine="fused")
-        .values.block_until_ready(),
-        reps=3) / (bf.nnz * 128)
-    ptf = plan_spgemm(tiny, tiny, "expand", backend="jax")
-    ptf.execute(tiny, tiny, engine="fused")
-    fused_base = best_of(
-        lambda: ptf.execute(tiny, tiny, engine="fused")
-        .values.block_until_ready(),
-        reps=20)
-
     print("measured host constants (paste into core/cost.py):")
     print("CostConstants(")
     print(f"    spa_col={spa_col:.1e}, spa_entry={spa_entry:.1e}, "
@@ -437,7 +413,6 @@ def calibrate():
     print(f"    stream_base={stream_base:.1e}, "
           f"stream_prod={stream_prod:.1e},")
     print(f"    jax_base={jax_base:.1e}, jax_prod={jax_prod:.1e},")
-    print(f"    fused_base={fused_base:.1e}, fused_prod={fused_prod:.1e},")
     print(f"    expand_base=1.0e-4, expand_prod={expand_prod:.1e}, "
           f"expand_sort={expand_sort:.1e},")
     print(")")

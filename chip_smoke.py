@@ -8,12 +8,11 @@ Every phase runs through the public entry points (``cached_plan`` /
 ``--seed``, and is compared bit for bit with the host product stream.
 
 (a) A·A on the ``Goodwin_013`` Table-1 stand-in (n=1,965, ~2M products): the
-    XLA stream (``backend="jax"``), the fused Pallas kernel
-    (``engine="fused"``), and the per-group Pallas kernels that compile for
-    the chip (``backend="pallas"``: SPA, SPARS).  One plan miss, then hits.
-    The HASH kernel must be refused by name.
+    XLA stream (``backend="jax"``) and the per-group Pallas kernels that
+    compile for the chip (``backend="pallas"``: SPA, SPARS).  One plan
+    miss, then hits.  The HASH kernel must be refused by name.
 (b) A² of a power-law matrix whose frozen stream puts ~0.7 GB of int32
-    indices in HBM, on the XLA stream and the fused kernel.
+    indices in HBM, on the XLA stream.
 (mesh, ``--chips 4``) A² of a larger power-law matrix whose stream is above
     one chip's plan-memory guard, on ``backend="mesh"``, with each chip
     holding only its own shard of the stream.
@@ -116,16 +115,11 @@ def phase_table1(seed: int) -> None:
     for i in range(3):
         hit = cached_plan(a, a, "expand", backend="jax")         # hits
         require(hit is plan, "plan cache hit returned another plan")
-        for engine in (None, "fused"):
-            stats = {}
-            c, t = timed(lambda: block(hit.execute(a, a, engine=engine,
-                                                   stats=stats)))
-            if i == 0:
-                log(f"  jax engine={engine or 'stream'}: first execute "
-                    f"{t:.3f}s wall (upload + compile)")
-            check(f"jax engine={engine or 'stream'} [{i}]", c, ref, stats)
-            if engine == "fused":
-                require(stats["n_launches"] == 1, stats)
+        stats = {}
+        c, t = timed(lambda: block(hit.execute(a, a, stats=stats)))
+        if i == 0:
+            log(f"  jax: first execute {t:.3f}s wall (upload + compile)")
+        check(f"jax [{i}]", c, ref, stats)
     after = api.plan_cache_info()
     require(after["misses"] - before["misses"] == 1, (before, after))
     require(after["hits"] - before["hits"] == 3, (before, after))
@@ -179,20 +173,15 @@ def phase_powerlaw(seed: int) -> None:
         "(fingerprints + symbolic phase)")
     ref, t_ref = timed(lambda: host_reference(a, a, products))
     log(f"  host reference {t_ref:.3f}s wall")
-    for engine in (None, "fused"):
-        name = f"jax engine={engine or 'stream'}"
-        stats = {}
-        c, t_first = timed(lambda: block(plan.execute(a, a, engine=engine,
-                                                      stats=stats)))
-        check(name, c, ref, stats)
-        reps = [timed(lambda: block(plan.execute(a, a, engine=engine)))[1]
-                for _ in range(3)]
-        log(f"  {name}: first execute {t_first:.3f}s wall, replay "
-            f"{statistics.median(reps):.4f}s wall (median of 3)")
+    stats = {}
+    c, t_first = timed(lambda: block(plan.execute(a, a, stats=stats)))
+    check("jax", c, ref, stats)
+    reps = [timed(lambda: block(plan.execute(a, a)))[1] for _ in range(3)]
+    log(f"  jax: first execute {t_first:.3f}s wall, replay "
+        f"{statistics.median(reps):.4f}s wall (median of 3)")
     info = api.plan_cache_info()
     mem = jax.devices()[0].memory_stats() or {}
-    log(f"  stream bytes in HBM: xla={info['device_stream_bytes']} "
-        f"fused={info['fused_stream_bytes']}  "
+    log(f"  stream bytes in HBM: {info['device_stream_bytes']}  "
         f"peak_bytes_in_use={mem.get('peak_bytes_in_use')}")
 
 

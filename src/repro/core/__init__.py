@@ -50,7 +50,6 @@ from repro.core.jax_stream import (
     device_stream,
     stream_fn,
 )
-from repro.core.pallas_stream import FusedStream, fused_fn, fused_stream
 from repro.core.executor import execute as execute_plan
 from repro.core.executor import execute_batched as execute_plan_batched
 from repro.core.executor import execute_tiled, execute_tiled_batched
@@ -129,9 +128,6 @@ __all__ = [
     "bilinear_custom_vjp",
     "device_stream",
     "stream_fn",
-    "FusedStream",
-    "fused_fn",
-    "fused_stream",
     "resolve_engine",
     "cached_plan",
     "plan_cache_clear",

@@ -152,14 +152,11 @@ def plan_cache_info() -> dict:
     plans' child tile plans (each counted once even when shared) — see
     DESIGN.md §9.  ``device_stream_bytes`` separately totals the
     device-resident index arrays jax-backend plans cache alongside the host
-    ones (DESIGN.md §10), and ``fused_stream_bytes`` the fused-kernel
-    replay views (padded gather indices + segment metadata,
-    ``core.pallas_stream``, DESIGN.md §11) — all three can be resident on
-    one plan at once.  The guard bounds each *plan's* stream; the LRU
-    bounds entries, but a tiled plan holds one guard-sized stream per
-    distinct tile pattern, so watch these numbers (and shrink via
-    ``plan_cache_resize`` or a lower guard) when caching large tiled
-    workloads.
+    ones (DESIGN.md §10) — both can be resident on one plan at once.  The
+    guard bounds each *plan's* stream; the LRU bounds entries, but a tiled
+    plan holds one guard-sized stream per distinct tile pattern, so watch
+    these numbers (and shrink via ``plan_cache_resize`` or a lower guard)
+    when caching large tiled workloads.
 
     ``mesh_stream_bytes`` totals the device-stacked shard-stream index
     arrays held by mesh-backend plans (DESIGN.md §13) on top of their
@@ -175,22 +172,19 @@ def plan_cache_info() -> dict:
     :func:`plan_cache_resize`, and ``builders`` lists each live
     ``PlanBuilder``'s :meth:`~repro.core.plan_builder.PlanBuilder.info`
     (queue depth, retries, timeouts, recycled workers, backpressure
-    policy).  ``host_fallbacks`` counts device-plan executions (jax,
-    pallas fused) that ran on the host stream because the plan's stream
-    was above its guard.
+    policy).  ``host_fallbacks`` counts device-stream executions that ran
+    on the host stream because the plan's stream was above its guard.
     """
     with _CACHE_LOCK:
         lookups = _CACHE_STATS["hits"] + _CACHE_STATS["misses"]
         host_seen: dict = {}
         dev_seen: dict = {}
-        fused_seen: dict = {}
         mesh_seen: dict = {}
         for p in _PLAN_CACHE.values():
             mesh_seen[id(p)] = getattr(p, "mesh_stream_nbytes", 0)
             for sp in [t.plan for t in getattr(p, "tiles", ())] or [p]:
                 host_seen[id(sp)] = getattr(sp, "stream_nbytes", 0)
                 dev_seen[id(sp)] = getattr(sp, "device_stream_nbytes", 0)
-                fused_seen[id(sp)] = getattr(sp, "fused_stream_nbytes", 0)
         out = dict(_CACHE_STATS, size=len(_PLAN_CACHE),
                    max_size=PLAN_CACHE_SIZE,
                    hit_rate=(_CACHE_STATS["hits"] / lookups
@@ -198,7 +192,6 @@ def plan_cache_info() -> dict:
                    in_flight=len(_BUILDING),
                    stream_bytes=sum(host_seen.values()),
                    device_stream_bytes=sum(dev_seen.values()),
-                   fused_stream_bytes=sum(fused_seen.values()),
                    mesh_stream_bytes=sum(mesh_seen.values()))
         refs = list(_BUILDERS)
     # builder.info() takes the builder's own lock — collect outside ours

@@ -6,13 +6,10 @@ Three execution backends host the numeric phase of a cached symbolic plan:
   bit-exact oracles of the paper's algorithms) and the vectorized product
   stream (``engine="stream"``, DESIGN.md §9).
 * ``"pallas"`` — the TPU kernel schedule (one launch per plan
-  :class:`~repro.core.planner.KernelGroup`, DESIGN.md §2/§6), plus the
-  fused single-launch stream kernel (``engine="fused"``,
-  ``core.pallas_stream``, DESIGN.md §11).
+  :class:`~repro.core.planner.KernelGroup`, DESIGN.md §2/§6).
 * ``"jax"``    — the device-resident stream (``core.jax_stream``,
   DESIGN.md §10): the plan's product stream compiled into a jitted,
-  differentiable pure-JAX function; ``engine="fused"`` swaps the XLA
-  lowering for the fused Pallas kernel on the same plan.
+  differentiable pure-JAX function.
 
 Rather than each call site string-matching backend names, everything that
 needs a capability decision — ``core.api`` argument validation,
@@ -176,12 +173,10 @@ HOST = register_backend(ExecutionContract(
 
 PALLAS = register_backend(ExecutionContract(
     name="pallas",
-    # "naive" is a no-op: the per-group kernel schedule.  "fused" is the
-    # single-launch fused stream kernel (core/pallas_stream.py, DESIGN.md
-    # §11) — it rides the plan's product stream, which is why the pallas
-    # backend now carries one (built lazily: per-group executions never
-    # touch it)
-    engines=(None, "naive", "fused"),
+    # "naive" is a no-op: the per-group kernel schedule.  The plan still
+    # carries the pattern's product stream (built lazily: per-group
+    # executions never touch it) for the traced entry ``stream_apply``
+    engines=(None, "naive"),
     default_engine="naive",
     # the host-only executors have no kernel family, and the "jax" auto
     # candidate (the device stream riding a tile grid) has no pallas lane
@@ -196,8 +191,7 @@ PALLAS = register_backend(ExecutionContract(
 
 JAX = register_backend(ExecutionContract(
     name="jax",
-    # the device stream, plus its fused-Pallas lowering (DESIGN.md §11)
-    engines=(None, "stream", "fused"),
+    engines=(None, "stream"),
     default_engine="stream",
     supports_batched=True,
     supports_grad=True,

@@ -50,15 +50,12 @@ from repro.sparse.stats import TileStats
 # -> the device-resident stream of DESIGN.md §10, picked for in-guard
 # tiles wherever the calibrated device per-product cost undercuts the
 # numpy stream — on accelerator-backed installs, not the CI CPU, see
-# CostConstants.jax_prod; "fused" -> the single-launch fused Pallas
-# kernel of DESIGN.md §11, same admission logic with its own calibrated
-# constants).  Pallas: the paper's families — dense-tile SPA vs
-# small-table HASH, with SPARS between.  Jax: the device stream and its
-# fused lowering.
+# CostConstants.jax_prod).  Pallas: the paper's families — dense-tile SPA
+# vs small-table HASH, with SPARS between.  Jax: the device stream.
 AUTO_CANDIDATES = {
-    "host": ("spa", "expand", "jax", "fused"),
+    "host": ("spa", "expand", "jax"),
     "pallas": ("spa", "spars-40/40", "hash-256/256"),
-    "jax": ("jax", "fused"),
+    "jax": ("jax",),
     # mesh children are device-stream replays; the distribute-or-not
     # decision itself is estimate_mesh_cost/should_distribute, not a
     # per-tile method race
@@ -96,16 +93,6 @@ class CostConstants:
     # on hardware where the scatter is parallel (real devices)
     jax_base: float = 1.4e-5
     jax_prod: float = 3.7e-8
-    # fused Pallas stream kernel (core/pallas_stream.py): one launch for
-    # the whole numeric phase.  Constants are the honest CI-container
-    # numbers (``benchmarks/tiled.py --calibrate``), where the kernel runs
-    # in the Pallas interpreter and the [block, block] one-hot
-    # contraction is emulated on CPU — per-product cost sits ~40x above
-    # the numpy stream's, so auto never picks "fused" here.  Re-calibrate
-    # on a real device, where the MXU absorbs the one-hot matmul and this
-    # becomes the cheapest in-guard family.
-    fused_base: float = 7.9e-5
-    fused_prod: float = 3.0e-7
     # mesh backend communication terms (DESIGN.md §13): fixed collective
     # dispatch/launch overhead per sharded execution, plus a per-byte toll
     # on the cross-device partial-C reduction — a tiled psum_scatter moves
@@ -154,7 +141,7 @@ def _note_if_default(backend: str, candidates: tuple) -> None:
 
 
 def _family(method: str) -> str:
-    if method in ("spa", "expand", "esc", "jax", "fused"):
+    if method in ("spa", "expand", "esc", "jax"):
         return method
     if method.startswith("h-"):
         return "hybrid"
@@ -214,13 +201,6 @@ def _host_cost(stats: TileStats, method: str, c: CostConstants) -> float:
         # guard-tripped jax plans fall back to the host transient rebuild
         # (core/jax_stream.py), so they cost what guarded expand costs
         return _guarded_rebuild_cost(flops, c)
-    if fam == "fused":
-        if flops <= _fast.default_stream_limit(device=True):
-            # single fused kernel launch: one dispatch, flat per-product
-            return c.fused_base + c.fused_prod * flops
-        # guard-tripped fused executions fall back to the host transient
-        # rebuild (core/pallas_stream.py), same as the other stream engines
-        return _guarded_rebuild_cost(flops, c)
     if fam == "esc":
         rounds = (math.ceil(math.log2(max(stats.m, 2)) / 5)
                   + math.ceil(math.log2(max(stats.n, 2)) / 5))
@@ -243,10 +223,7 @@ def _host_cost(stats: TileStats, method: str, c: CostConstants) -> float:
 def _pallas_cost(stats: TileStats, method: str, c: CostConstants) -> float:
     fam = _family(method)
     m = max(stats.m, 1)
-    if fam in ("expand", "esc", "jax", "fused"):
-        # "fused" is an engine on pallas plans, not a per-group kernel
-        # family the relative-work model ranks — it never competes in a
-        # pallas-domain tile grid (host/jax grids admit it in seconds)
+    if fam in ("expand", "esc", "jax"):
         raise ValueError(f"method {method!r} has no Pallas kernel family")
     if fam == "spa":
         return c.p_spa_entry * m * stats.nnz_b + c.p_spa_col * m * stats.n

@@ -32,12 +32,6 @@ matching at the call sites.  The registered pairs:
 * ``("jax", "stream")`` — the device-resident stream (``core.jax_stream``,
   DESIGN.md §10): a jitted, differentiable pure-JAX replay of the same
   contraction; one device dispatch per execution.
-* ``("pallas", "fused")`` / ``("jax", "fused")`` — the fused stream kernel
-  (``core.pallas_stream``, DESIGN.md §11): the plan's whole numeric phase
-  as *one* Pallas launch (gather → multiply → segmented accumulate inside
-  the kernel), differentiable through the same shared ``custom_vjp``
-  machinery as the jax stream.  Both backends dispatch to the same pair —
-  the fused kernel is the meeting point of the two device contracts.
 
 ``execute_batched(plan, a_vals [B, nnz], b_vals [B, nnz])`` is the batched
 numeric phase (DESIGN.md §7): B same-pattern multiplies through *one*
@@ -54,8 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import runtime
-from repro.core import (backends, fast, jax_stream, naive, pallas_stream,
-                        spans)
+from repro.core import backends, fast, jax_stream, naive, spans
 from repro.core.backends import check_engine, default_engine, get_backend
 from repro.core.expand import spgemm_expand
 from repro.core.planner import SpgemmPlan
@@ -223,12 +216,6 @@ register_executor("host", "naive", _host_naive, _host_naive_batched)
 register_executor("host", "stream", _host_stream, _host_stream_batched)
 register_executor("jax", "stream", jax_stream.execute_jax,
                   jax_stream.execute_jax_batched)
-# one executor pair serves both device backends: the fused kernel runs the
-# plan's product stream, which every stream-carrying contract exposes
-register_executor("pallas", "fused", pallas_stream.execute_fused,
-                  pallas_stream.execute_fused_batched)
-register_executor("jax", "fused", pallas_stream.execute_fused,
-                  pallas_stream.execute_fused_batched)
 
 
 # ---------------------------------------------------------------------------
@@ -328,11 +315,9 @@ def execute_tiled(plan, a_values, b_values, *,
     every tile's backend (:func:`_check_tile_engines` — a mixed host/jax
     grid accepts ``None``/``"stream"`` but rejects ``"naive"``, whose
     bit-exact promise the device tiles cannot keep); ``engine=None`` runs
-    each tile's cost-model-chosen engine (``TilePlan.engine`` — the
-    "fused" auto candidate sets it) falling back to the method default.
-    ``stats`` records
-    the grid, the per-tile method choices, and — on the Pallas backend —
-    the aggregated launch count and peak transient tile size.
+    each tile's method default.  ``stats`` records the grid, the per-tile
+    method choices, and — on the Pallas backend — the aggregated launch
+    count and peak transient tile size.
     """
     plan.a.check_compatible(a_values, validate)
     plan.b.check_compatible(b_values, validate)
@@ -348,9 +333,7 @@ def execute_tiled(plan, a_values, b_values, *,
         cs = {} if (stats is not None
                     and plan.backend == "pallas") else None
         per_block[tile.n].append(_host_child(
-            tile.plan.execute(ta, tb, stats=cs,
-                              engine=engine if engine is not None
-                              else tile.engine)))
+            tile.plan.execute(ta, tb, stats=cs, engine=engine)))
         if cs is not None:
             child_stats.append(cs)
     _record_tile_stats(plan, stats, child_stats)
@@ -382,9 +365,7 @@ def execute_tiled_batched(plan, a_values, b_values, *,
         ta, tb = _tile_values(plan, tile, av, bv)
         cs = {} if (stats is not None
                     and plan.backend == "pallas") else None
-        outs = tile.plan.execute_batched(
-            ta, tb, stats=cs,
-            engine=engine if engine is not None else tile.engine)
+        outs = tile.plan.execute_batched(ta, tb, stats=cs, engine=engine)
         for bi, c in enumerate(outs):
             per_block[bi][tile.n].append(_host_child(c))
         if cs is not None:

@@ -108,9 +108,9 @@ def check_int32_stream(plan, s) -> None:
     A hard error beats int32-wrapped in-bounds-promised gathers:
     products/output slots (huge guard) or *operand* positions
     (``a_pos``/``b_pos`` index the value arrays — a small stream over a
-    >2**31-nnz operand still needs wide indices) past int32.  Shared by
-    the device stream and the fused Pallas stream (``core.pallas_stream``),
-    whose index arrays bound-check identically.
+    >2**31-nnz operand still needs wide indices) past int32.  Checked
+    when a plan's index arrays are lifted to the device
+    (:func:`device_stream`).
     """
     if max(s.n_products, s.nnz, int(plan.a.col_ptr[-1]),
            int(plan.b.col_ptr[-1])) > _I32_MAX:
@@ -214,11 +214,11 @@ def bilinear_custom_vjp(forward, grad_a, grad_b):
     operands and return the corresponding operand cotangent (shaped like
     the primal operand — oversized raw value arrays get oversized
     cotangents).  ``idx`` gets no cotangent.  Shared by the XLA device
-    stream (:func:`_bilinear_contract`), the fused Pallas stream
-    (``core.pallas_stream``) and the mesh stream, which differ only in how
-    a replay is lowered.  ``jax.vmap`` composes with the returned function,
-    which is how the batched paths ride one trace for a whole ``[B, nnz]``
-    value stack; :func:`bind_indices` jits it for one plan.
+    stream (:func:`_bilinear_contract`) and the mesh stream, which differ
+    only in how a replay is lowered.  ``jax.vmap`` composes with the
+    returned function, which is how the batched paths ride one trace for
+    a whole ``[B, nnz]`` value stack; :func:`bind_indices` jits it for one
+    plan.
     """
 
     @jax.custom_vjp

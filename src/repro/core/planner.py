@@ -398,10 +398,9 @@ class SpgemmPlan:
         share one stream.  Carried by every stream-capable backend
         (``contract.carries_stream``: host, jax, and pallas — the jax
         backend builds its device-resident index arrays from this host
-        stream, and the fused Pallas kernel its replay views, DESIGN.md
-        §10/§11).  ``None`` when the stream would exceed ``stream_limit``
-        (the guard resolved at plan time) — stream executions then rebuild
-        transiently.
+        stream, DESIGN.md §10).  ``None`` when the stream would exceed
+        ``stream_limit`` (the guard resolved at plan time) — stream
+        executions then rebuild transiently.
         """
         if not self.contract.carries_stream:
             return None
@@ -433,20 +432,7 @@ class SpgemmPlan:
         d = self._stream_memo.get("device")
         return d.nbytes if d is not None else 0
 
-    @property
-    def fused_stream_nbytes(self) -> int:
-        """Bytes of fused-kernel replay views held by this plan.
-
-        The fused engine (``core.pallas_stream``, DESIGN.md §11) caches
-        three device-resident index views (forward + two grad replays) on
-        the plan; this reads the memo without triggering the lazy build —
-        ``plan_cache_info()['fused_stream_bytes']`` aggregates it alongside
-        the host and XLA-device stream bytes.
-        """
-        f = self._stream_memo.get("fused")
-        return f.nbytes if f is not None else 0
-
-    def stream_apply(self, a_values, b_values, engine: str = None):
+    def stream_apply(self, a_values, b_values):
         """Jit-compatible, differentiable numeric phase: C values only.
 
         The device-backend entry point for traced code (DESIGN.md §10):
@@ -454,12 +440,10 @@ class SpgemmPlan:
         the planned patterns, and the return is the ``[nnz_c]`` C value
         array of the plan's canonical output structure
         (``plan.stream.c_rows`` / ``c_col_ptr``) — a pure function of the
-        inputs, safe under ``jax.jit``/``jax.grad``/``jax.vmap``.
-        ``engine=None`` lowers through the XLA stream; ``engine="fused"``
-        through the single-launch fused Pallas kernel (DESIGN.md §11) —
-        both ride the same bilinear custom vjp.  Requires a stream-capable
-        backend and a plan-resident stream (guarded plans raise: a traced
-        execution cannot fall back to the host rebuild).
+        inputs, safe under ``jax.jit``/``jax.grad``/``jax.vmap``, lowered
+        through the XLA stream.  Requires a stream-capable backend and a
+        plan-resident stream (guarded plans raise: a traced execution
+        cannot fall back to the host rebuild).
         """
         from repro.core import jax_stream
 
@@ -468,14 +452,6 @@ class SpgemmPlan:
         # here rather than read undefined memory
         self.a.check_compatible(a_values)
         self.b.check_compatible(b_values)
-        if engine == "fused":
-            from repro.core import pallas_stream
-
-            return pallas_stream.fused_fn(self)(a_values, b_values)
-        if engine is not None and engine != "stream":
-            raise ValueError(
-                f"stream_apply supports engine=None/'stream'/'fused', "
-                f"got {engine!r}")
         return jax_stream.stream_fn(self)(a_values, b_values)
 
     @property
@@ -598,7 +574,7 @@ def plan_spgemm(
 
     # resolve the guard now (it is a mutable module knob) so every plan's
     # lazy stream build is deterministic no matter when it happens; pallas
-    # plans carry it too since the fused engine rides the product stream
+    # plans carry it too (``stream_apply`` traces their pattern's stream)
     limit = (_fast.default_stream_limit(contract.device_resident)
              if stream_limit is None else int(stream_limit))
     if backend == "pallas":
@@ -644,17 +620,12 @@ class TilePlan:
     a_vals: Tuple[int, int]
     b_vals: np.ndarray
     plan: SpgemmPlan
-    #: engine override the cost model chose for this tile (None = the child
-    #: plan's method default; "fused" = the single-launch fused kernel)
-    engine: Optional[str] = None
 
     @property
     def method(self) -> str:
-        # report the candidate spelling the cost model chose: "jax"/"fused"
-        # tiles (the device stream riding a host grid) carry an
-        # expand-method child plan on the jax backend
-        if self.engine == "fused":
-            return "fused"
+        # report the candidate spelling the cost model chose: "jax" tiles
+        # (the device stream riding a host grid) carry an expand-method
+        # child plan on the jax backend
         return "jax" if self.plan.backend == "jax" else self.plan.method
 
 
@@ -714,13 +685,6 @@ class TiledSpgemmPlan:
         """Device-resident stream bytes held via child tile plans (distinct
         children counted once, as in :attr:`stream_nbytes`)."""
         seen = {id(t.plan): t.plan.device_stream_nbytes for t in self.tiles}
-        return sum(seen.values())
-
-    @property
-    def fused_stream_nbytes(self) -> int:
-        """Fused-kernel replay-view bytes held via child tile plans
-        (distinct children counted once, as in :attr:`stream_nbytes`)."""
-        seen = {id(t.plan): t.plan.fused_stream_nbytes for t in self.tiles}
         return sum(seen.values())
 
     @property
@@ -829,23 +793,16 @@ def plan_spgemm_tiled(
                 else nnz_balanced_col_bounds(b, auto_n))
 
     def _tile_plan(ta, tb, method):
-        # the "jax" candidate spelling = the device stream (DESIGN.md §10),
-        # "fused" = its single-launch Pallas lowering (DESIGN.md §11): both
-        # ride an expand-method child plan on the jax backend, so a host
-        # grid can mix numpy tiles with device-stream/fused tiles.  The
-        # engine distinction lives on the TilePlan, not the child plan —
-        # same pattern, same shared plan in the LRU.
-        if method in ("jax", "fused"):
-            meth, be = "expand", "jax"
-            engine = "fused" if method == "fused" else None
-        else:
-            meth, be, engine = method, backend, None
+        # the "jax" candidate spelling = the device stream (DESIGN.md §10):
+        # it rides an expand-method child plan on the jax backend, so a
+        # host grid can mix numpy tiles with device-stream tiles
+        meth, be = (("expand", "jax") if method == "jax"
+                    else (method, backend))
         if cache:
             from repro.core.api import _cached_plan
 
-            return _cached_plan(ta, tb, meth, be,
-                                resolve_params(meth)), engine
-        return plan_spgemm(ta, tb, meth, backend=be), engine
+            return _cached_plan(ta, tb, meth, be, resolve_params(meth))
+        return plan_spgemm(ta, tb, meth, backend=be)
 
     # A column blocks depend only on k: slice them once, not once per n block
     a_tiles = [csc_col_slice(a, int(k0), int(k1))
@@ -864,10 +821,9 @@ def plan_spgemm_tiled(
             if stats.flops == 0:
                 continue  # stored B entries only reference empty A columns
             method = choose_method(stats, backend, cands, constants)
-            child, engine = _tile_plan(a_tile, b_tile, method)
             tiles.append(TilePlan(
                 k=ki, n=ni, a_vals=(a_lo, a_hi), b_vals=b_lo + rel,
-                plan=child, engine=engine))
+                plan=_tile_plan(a_tile, b_tile, method)))
 
     # the cost-constant provenance the per-tile choices were ranked under:
     # a plan built on measured constants must never alias one built on
@@ -882,7 +838,7 @@ def plan_spgemm_tiled(
               ("profile", profile_tag),
               # stream-carrying backends only (all three today): the guard
               # steers host/jax per-tile method choices and bounds every
-              # child plan's lazy stream build, fused replays included
+              # child plan's lazy stream build
               ("stream_guard",
                _fast.default_stream_limit(contract.device_resident)
                if contract.carries_stream else None),

@@ -17,13 +17,12 @@ cross-check**.
 * :func:`calibrate_profile` runs a small synthetic microbenchmark ladder
   per (backend, engine) family — host SPA, the plan-resident product
   stream, the guard-tripped transient rebuild, the jitted device stream,
-  the fused Pallas kernel, and (for the mesh backend) a real
+  and (for the mesh backend) a real
   ``psum_scatter`` payload ladder — and fits each family's
   :class:`~repro.core.cost.CostConstants` terms by weighted least squares.
   It can also *auto-tune* the structural knobs the cost model sits on: the
-  plan-memory guard (``fast.STREAM_MAX_PRODUCTS``), the fused product-axis
-  block (``pallas_stream.FUSED_BLOCK``) and the auto tile-grid nnz targets
-  (``sparse.partition``).
+  plan-memory guard (``fast.STREAM_MAX_PRODUCTS``) and the auto tile-grid
+  nnz targets (``sparse.partition``).
 * :func:`save_profile` / :func:`load_profile` persist the fit as one JSON
   file per fingerprint under ``REPRO_PROFILE_DIR`` (default
   ``~/.cache/repro-spgemm/profiles``); :func:`current_profile` loads it
@@ -59,11 +58,9 @@ PROFILE_VERSION = 1
 
 #: structural-knob tuning keys a profile may carry (DESIGN.md §15):
 #: ``stream_max_products`` -> ``fast.STREAM_MAX_PRODUCTS`` (plan-memory
-#: guard), ``fused_block`` -> ``pallas_stream.FUSED_BLOCK`` (fused kernel
-#: product-axis tile), ``tile_n_target``/``tile_k_target`` -> the auto
-#: tile-grid nnz targets ``sparse.partition.auto_tile_grid`` sizes from.
-TUNING_KEYS = ("stream_max_products", "fused_block",
-               "tile_n_target", "tile_k_target")
+#: guard), ``tile_n_target``/``tile_k_target`` -> the auto tile-grid nnz
+#: targets ``sparse.partition.auto_tile_grid`` sizes from.
+TUNING_KEYS = ("stream_max_products", "tile_n_target", "tile_k_target")
 
 _LOCK = threading.RLock()
 _STATE: dict = {"profile": None, "loading": False}
@@ -204,7 +201,7 @@ class MachineProfile:
             fingerprint=dict(doc["fingerprint"]),
             source=str(doc.get("source", "measured")),
             created_at=float(doc.get("created_at", 0.0)),
-            fitted=tuple(doc.get("fitted", ())),
+            fitted=tuple(k for k in doc.get("fitted", ()) if k in known),
             tuning={k: v for k, v in doc.get("tuning", {}).items()
                     if k in TUNING_KEYS},
             path=path,
@@ -367,9 +364,8 @@ def note_default_auto(backend: str, candidates: tuple = ()) -> None:
     constants are the ones known to be machine-sensitive)."""
     from repro.core import backends
 
-    device_families = {"jax", "fused"}
     contract = backends.get_backend(backend)
-    if not (contract.device_resident or device_families & set(candidates)):
+    if not (contract.device_resident or "jax" in candidates):
         return
     with _LOCK:
         _COUNTERS["default_auto_uses"] += 1
@@ -386,14 +382,13 @@ def note_default_auto(backend: str, candidates: tuple = ()) -> None:
 def apply_tuning(prof: MachineProfile | None = None) -> dict:
     """Apply a profile's tuned structural knobs to the live module globals.
 
-    Sets ``fast.STREAM_MAX_PRODUCTS`` and ``pallas_stream.FUSED_BLOCK``
-    from ``prof.tuning`` (the tile targets are consulted live by
+    Sets ``fast.STREAM_MAX_PRODUCTS`` from ``prof.tuning`` (the tile
+    targets are consulted live by
     ``sparse.partition.auto_tile_grid`` and need no global).  Explicit —
     never run implicitly on load, because mutating the guard re-keys every
     cached stream plan.  Returns ``{knob: value}`` for what was applied.
     """
     import repro.core.fast as fast
-    import repro.core.pallas_stream as pallas_stream
 
     prof = current_profile() if prof is None else prof
     applied = {}
@@ -401,9 +396,6 @@ def apply_tuning(prof: MachineProfile | None = None) -> dict:
     if "stream_max_products" in t:
         fast.STREAM_MAX_PRODUCTS = int(t["stream_max_products"])
         applied["stream_max_products"] = fast.STREAM_MAX_PRODUCTS
-    if "fused_block" in t:
-        pallas_stream.FUSED_BLOCK = int(t["fused_block"])
-        applied["fused_block"] = pallas_stream.FUSED_BLOCK
     return applied
 
 
@@ -568,7 +560,8 @@ def _stream_ladder(scale: float, rng):
 
     out = []
     # the near-empty (8, 4, 1) rung pins the base (dispatch) terms of all
-    # three stream families — see the matching note in _measure_fused
+    # three stream families, which a flop ladder alone under-determines:
+    # an unpinned base fits negative and clamps to the floor
     for m, n, per in ((8, 4, 1), (64, 32, 2), (192, 96, 4),
                       (max(int(512 * scale), 128), 128, 6),
                       (max(int(1024 * scale), 256), 256, 8)):
@@ -617,36 +610,6 @@ def _measure_jax(ladder, reps: int):
         rows.append([1.0, flops])
         times.append(_best_of(
             lambda: plan.execute(a, b).values.block_until_ready(), reps))
-    return fields, rows, times
-
-
-def _measure_fused(scale: float, reps: int, rng):
-    """Fused Pallas stream kernel: fused_base + fused_prod*P.
-
-    Small sizes only — on CPU the kernel runs in the Pallas interpreter
-    and costs minutes per Mproduct; the honest interpreter-measured
-    constants keep auto from ever picking "fused"
-    here, which is exactly what they should do.
-    """
-    from repro.core.planner import plan_spgemm
-
-    fields = ("fused_base", "fused_prod")
-    rows, times = [], []
-    # the (8, 4, 1) rung is near-empty on purpose: it pins the base
-    # (dispatch) term, which a flop ladder alone under-determines — an
-    # unpinned base fits negative, clamps to the floor, and a ~free
-    # fused_base makes auto pick "fused" for every tiny tile
-    for m, n, per in ((8, 4, 1), (32, 16, 2), (96, 48, 3),
-                      (max(int(160 * scale), 64), 64, 4)):
-        a, b = _dense_sparse_pair(m, n, per, rng)
-        flops = b.nnz * m
-        plan = plan_spgemm(a, b, "expand", backend="jax",
-                           stream_limit=flops + 1)
-        plan.execute(a, b, engine="fused").values.block_until_ready()
-        rows.append([1.0, flops])
-        times.append(_best_of(
-            lambda: plan.execute(a, b, engine="fused")
-            .values.block_until_ready(), reps))
     return fields, rows, times
 
 
@@ -704,25 +667,6 @@ def _tune_stream_guard() -> int:
     return int(min(max(ram * 0.05 / 20.0, 1_000_000), 64_000_000))
 
 
-def _tune_fused_block(scale: float, reps: int, rng) -> int:
-    """Measured argmin over candidate fused product-axis blocks."""
-    from repro.core.pallas_stream import fused_stream
-    from repro.core.planner import plan_spgemm
-
-    a, b = _dense_sparse_pair(96, 48, 3, rng)
-    best_block, best_t = None, math.inf
-    for block in (64, 128, 256):
-        plan = plan_spgemm(a, b, "expand", backend="jax",
-                           stream_limit=b.nnz * 96 + 1)
-        fused_stream(plan, block=block)   # build the views under this block
-        plan.execute(a, b, engine="fused").values.block_until_ready()
-        t = _best_of(lambda: plan.execute(a, b, engine="fused")
-                     .values.block_until_ready(), reps)
-        if t < best_t:
-            best_block, best_t = block, t
-    return int(best_block)
-
-
 def _tune_tile_targets(constants: CostConstants, scale: float, reps: int,
                        rng) -> tuple[int, int]:
     """Measured argmin over auto tile-grid nnz targets on a small
@@ -769,7 +713,7 @@ def _tune_tile_targets(constants: CostConstants, scale: float, reps: int,
 # the calibration entry point
 # ---------------------------------------------------------------------------
 
-SECTIONS = ("spa", "stream", "expand", "jax", "fused", "comm")
+SECTIONS = ("spa", "stream", "expand", "jax", "comm")
 
 
 def calibrate_profile(*, scale: float = 1.0, reps: int = 3,
@@ -785,7 +729,7 @@ def calibrate_profile(*, scale: float = 1.0, reps: int = 3,
     currently persisted profile if any, else ``DEFAULT_CONSTANTS``), so a
     forced-8-device run can refresh just the ``comm`` ladder into the same
     directory.  ``tune=True`` additionally searches the structural knobs
-    (guard, fused block, tile targets).  ``save=True`` persists via
+    (guard, tile targets).  ``save=True`` persists via
     :func:`save_profile` and installs the result as the current profile.
     """
     bad = [s for s in sections if s not in SECTIONS]
@@ -807,8 +751,6 @@ def calibrate_profile(*, scale: float = 1.0, reps: int = 3,
         measured.append(_measure_expand(ladder, reps))
     if "jax" in sections:
         measured.append(_measure_jax(ladder, reps))
-    if "fused" in sections:
-        measured.append(_measure_fused(scale, reps, rng))
     if "comm" in sections:
         measured.append(_measure_comm(scale, reps))
 
@@ -818,8 +760,6 @@ def calibrate_profile(*, scale: float = 1.0, reps: int = 3,
     tuning = dict(base.tuning)
     if tune:
         tuning["stream_max_products"] = _tune_stream_guard()
-        if "fused" in sections:
-            tuning["fused_block"] = _tune_fused_block(scale, reps, rng)
         if "spa" in sections or "stream" in sections:
             n_t, k_t = _tune_tile_targets(constants, scale, reps, rng)
             tuning["tile_n_target"], tuning["tile_k_target"] = n_t, k_t

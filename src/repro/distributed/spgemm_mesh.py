@@ -383,7 +383,7 @@ def plan_spgemm_mesh(
                     "shrink tile= or raise shard_limit")
             tiles.append(TilePlan(
                 k=ki, n=ni, a_vals=(a_lo, a_hi), b_vals=b_lo + rel,
-                plan=_child(a_tile, b_tile), engine=None))
+                plan=_child(a_tile, b_tile)))
             tile_flops.append(int(st.flops))
 
     # LPT placement on the calibrated device-stream cost (dispatch + flat

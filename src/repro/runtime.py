@@ -31,8 +31,9 @@ REPO_ROOT = Path(__file__).resolve().parents[2]
 DEFAULT_CACHE_DIR = REPO_ROOT / ".jax_cache"
 
 #: device bytes one product of a plan may pin: the XLA stream's three int32
-#: index arrays (12 B) plus the fused engine's three replay views (forward
-#: and two grad views, 12 B each)
+#: index arrays (12 B); the other 36 B are the guard's margin for a replay's
+#: transient gathered values and products.  Kept at 48 so the plan guard,
+#: and with it every plan's device/host choice, does not move
 DEVICE_BYTES_PER_PRODUCT = 48
 
 #: share of the chip's memory one plan's stream may take by default; the

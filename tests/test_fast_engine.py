@@ -153,10 +153,9 @@ def test_stream_bytes_reported_and_guard_keys_stream_carriers():
     a = random_powerlaw_csc(40, 3.0, seed=30)
     spgemm(a, a, method="expand")            # default engine builds a stream
     assert plan_cache_info()["stream_bytes"] > 0
-    # the guard knob keys every stream-carrying plan — since PR 6 that is
-    # all three backends (pallas plans carry a stream for the fused
-    # engine, DESIGN.md §11), so a knob change rebuilds pallas and host
-    # plans alike
+    # the guard knob keys every stream-carrying plan — all three backends
+    # (pallas plans carry a stream for ``stream_apply``), so a knob change
+    # rebuilds pallas and host plans alike
     spgemm(a, a, method="spa", backend="pallas")
     misses = plan_cache_info()["misses"]
     old = fast.STREAM_MAX_PRODUCTS

@@ -166,6 +166,52 @@ def test_grad_matches_dense_matmul_oracle(case):
                                rtol=1e-4, atol=1e-5)
 
 
+def test_single_segment_fed_by_every_product():
+    """A [1, k] @ B [k, 1]: one C entry, one segment, every product of the
+    stream summed into the same output slot."""
+    k = 23
+    a = csc_from_dense(np.arange(1, k + 1, dtype=np.float64).reshape(1, k))
+    b = csc_from_dense(np.ones((k, 1)))
+    plan = plan_spgemm(a, b, "expand", backend="jax")
+    assert plan.stream.n_products == k and plan.stream.nnz == 1
+    c = plan.execute(a, b)
+    assert c.nnz == 1
+    np.testing.assert_array_equal(np.asarray(c.values)[: c.nnz],
+                                  [k * (k + 1) / 2])
+    np.testing.assert_array_equal(
+        np.asarray(plan.stream_apply(np.asarray(a.values, F32),
+                                     np.asarray(b.values, F32))),
+        [k * (k + 1) / 2])
+
+
+def test_grad_of_entries_that_meet_no_product_is_zero():
+    """Stored A entries whose B row is empty and stored B entries whose A
+    column is empty take part in no product: their cotangent is exactly
+    zero, and every other one is the dense matmul gradient."""
+    a, b = _adversarial("empty_cols")
+    plan = plan_spgemm(a, b, "expand", backend="jax")
+    av = jnp.asarray(np.asarray(a.values)[: a.nnz].astype(F32))
+    bv = jnp.asarray(np.asarray(b.values)[: b.nnz].astype(F32))
+    ga, gb = jax.grad(lambda x, y: jnp.sum(plan.stream_apply(x, y)),
+                      argnums=(0, 1))(av, bv)
+
+    _, ac = _stored_coords(a)
+    br, _ = _stored_coords(b)
+    b_row_empty = np.bincount(br, minlength=b.shape[0]) == 0
+    a_col_empty = np.bincount(ac, minlength=a.shape[1]) == 0
+    a_idle, b_idle = b_row_empty[ac], a_col_empty[br]
+    assert a_idle.any() and b_idle.any() and not a_idle.all()
+    np.testing.assert_array_equal(np.asarray(ga)[a_idle], 0.0)
+    np.testing.assert_array_equal(np.asarray(gb)[b_idle], 0.0)
+    # d sum(C) / dA[i, j] = sum_c B[j, c];  d sum(C) / dB[r, c] = sum_i A[i, r]
+    b_rowsum = np.bincount(br, np.asarray(bv, np.float64), b.shape[0])
+    a_colsum = np.bincount(ac, np.asarray(av, np.float64), a.shape[1])
+    np.testing.assert_allclose(np.asarray(ga), b_rowsum[ac],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(gb), a_colsum[br],
+                               rtol=1e-5, atol=1e-5)
+
+
 # --- vmap batched path ------------------------------------------------------
 
 
@@ -225,16 +271,13 @@ def test_zero_retrace_after_warmup():
     assert bfn.func._cache_size() == 1
 
 
-@pytest.mark.parametrize("engine", [None, "fused"])
-def test_replay_takes_the_stream_as_arguments(engine):
+def test_replay_takes_the_stream_as_arguments():
     """The plan's index arrays are arguments of the jitted replay, not
     constants compiled into it (which would copy the whole stream into the
     executable)."""
-    from repro.core.pallas_stream import fused_fn
-
     a = random_powerlaw_csc(200, 4.0, seed=4)
     plan = plan_spgemm(a, a, "expand", backend="jax")
-    fn = (jax_stream.stream_fn if engine is None else fused_fn)(plan)
+    fn = jax_stream.stream_fn(plan)
     (idx,) = fn.args
     idx_bytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(idx))
     assert idx_bytes >= 3 * 4 * plan.stream.n_products
@@ -451,6 +494,13 @@ def test_engine_capability_errors():
     pj = plan_spgemm(a, a, "expand", backend="jax")
     with pytest.raises(ValueError, match="unknown engine"):
         pj.execute(a, a, engine="bogus")
+    # the retired fused engine is an unknown spelling on both device
+    # backends, and the error lists the valid ones
+    pp = plan_spgemm(a, a, "spa", backend="pallas")
+    for plan in (pj, pp):
+        with pytest.raises(ValueError, match="unknown engine 'fused'; one "
+                           "of None, 'naive', 'stream'"):
+            plan.execute(a, a, engine="fused")
     # the jax backend has no naive oracles (bit_exact_oracle=False)
     with pytest.raises(ValueError, match="naive"):
         pj.execute(a, a, engine="naive")
@@ -473,11 +523,12 @@ def test_backend_registry_contracts():
     host, pallas, jx = (get_backend(n) for n in ("host", "pallas", "jax"))
     assert host.bit_exact_oracle and not host.supports_grad
     assert jx.supports_grad and jx.device_resident and jx.carries_stream
-    # the fused engine rides the plan's product stream, so since PR 6 the
-    # pallas contract carries one too (built lazily)
+    # pallas plans carry the pattern's product stream too (built lazily,
+    # for stream_apply); the XLA stream is the one device lowering of it
     assert pallas.carries_stream and pallas.cost_domain == "relative"
     assert "expand" in pallas.excluded_methods
-    assert "fused" in pallas.engines and "fused" in jx.engines
+    assert pallas.engines == (None, "naive")
+    assert jx.engines == (None, "stream")
     with pytest.raises(ValueError, match="unknown backend"):
         get_backend("cuda")
     with pytest.raises(ValueError, match="unknown backend"):
@@ -539,9 +590,8 @@ def test_jax_default_method_plans_on_a_miss():
 
 
 def test_stream_apply_works_on_pallas_plans():
-    """Pallas plans carry a product stream since PR 6 (the fused engine
-    rides it), so ``stream_apply`` — previously a capability error there —
-    now traces the same contraction as a host/jax plan of the pattern."""
+    """Pallas plans carry a product stream, so ``stream_apply`` traces the
+    same contraction as a host/jax plan of the pattern."""
     a = random_powerlaw_csc(20, 2.0, seed=19)
     pallas_plan = plan_spgemm(a, a, "spa", backend="pallas")
     host_plan = plan_spgemm(a, a, "expand", backend="host")

@@ -20,7 +20,6 @@ import numpy as np
 import pytest
 
 import repro.core.fast as fast
-import repro.core.pallas_stream as pallas_stream
 from repro.core import plan_cache_clear, plan_cache_info, profile
 from repro.core.cost import (
     DEFAULT_CONSTANTS,
@@ -42,11 +41,11 @@ def _isolated_profile(tmp_path, monkeypatch):
     monkeypatch.setenv("REPRO_PROFILE_DIR", str(tmp_path / "profiles"))
     monkeypatch.delenv("REPRO_PROFILE_FILE", raising=False)
     monkeypatch.delenv("REPRO_AUTO_CALIBRATE", raising=False)
-    guard, block = fast.STREAM_MAX_PRODUCTS, pallas_stream.FUSED_BLOCK
+    guard = fast.STREAM_MAX_PRODUCTS
     profile.reset()
     yield
     profile.reset()
-    fast.STREAM_MAX_PRODUCTS, pallas_stream.FUSED_BLOCK = guard, block
+    fast.STREAM_MAX_PRODUCTS = guard
     plan_cache_clear()
 
 
@@ -89,7 +88,8 @@ def test_fingerprint_key_sensitive_to_fields():
 def test_save_load_roundtrip(tmp_path):
     c = dataclasses.replace(DEFAULT_CONSTANTS, jax_base=1.25e-4,
                             comm_byte=3.5e-9)
-    prof = _measured(c, tuning={"fused_block": 64}, fitted=("jax_base",))
+    prof = _measured(c, tuning={"stream_max_products": 2_000_000},
+                     fitted=("jax_base",))
     path = profile.save_profile(prof, directory=str(tmp_path))
     assert os.path.exists(path)
     back = profile.load_profile(directory=str(tmp_path))
@@ -99,8 +99,29 @@ def test_save_load_roundtrip(tmp_path):
     assert back.constants.comm_byte == pytest.approx(3.5e-9)
     assert back.constants.spa_col == DEFAULT_CONSTANTS.spa_col
     assert back.fitted == ("jax_base",)
-    assert back.tuning == {"fused_block": 64}
+    assert back.tuning == {"stream_max_products": 2_000_000}
     assert back.tag == prof.tag
+
+
+def test_profile_written_with_retired_keys_still_loads(tmp_path):
+    """A profile file from before the fused stream kernel was retired
+    carries its constants, fit and tuning key: they are dropped on load
+    and everything else is kept."""
+    doc = _measured(fitted=("jax_base",)).to_json()
+    doc["constants"].update(jax_base=1.25e-4, fused_base=7.9e-5,
+                            fused_prod=3.0e-7)
+    doc["fitted"] = ["fused_base", "fused_prod", "jax_base"]
+    doc["tuning"] = {"fused_block": 64, "stream_max_products": 2_000_000}
+    path = tmp_path / "old.json"
+    path.write_text(json.dumps(doc))
+    back = profile.load_profile(path=str(path))
+    assert back is not None and back.source == "measured"
+    assert back.constants.jax_base == pytest.approx(1.25e-4)
+    assert back.constants.spa_col == DEFAULT_CONSTANTS.spa_col
+    assert not any(f.name.startswith("fused")
+                   for f in dataclasses.fields(back.constants))
+    assert back.fitted == ("jax_base",)
+    assert back.tuning == {"stream_max_products": 2_000_000}
 
 
 def test_load_missing_returns_none(tmp_path):
@@ -276,8 +297,7 @@ def test_choose_method_consults_profile():
     # must re-rank the same tile to SPA — via the installed profile, with
     # no constants argument at the call site
     slow_streams = dataclasses.replace(
-        DEFAULT_CONSTANTS, stream_base=1.0, expand_base=1.0, jax_base=1.0,
-        fused_base=1.0)
+        DEFAULT_CONSTANTS, stream_base=1.0, expand_base=1.0, jax_base=1.0)
     profile.set_profile(_measured(slow_streams))
     assert choose_method(st, "host") == "spa"
     profile.set_profile(None)
@@ -345,12 +365,12 @@ def test_measured_profile_does_not_warn():
 
 
 def test_apply_tuning_sets_knobs():
+    # the tile targets are read live by auto_tile_grid: no global to set
     prof = _measured(tuning={"stream_max_products": 123_456,
-                             "fused_block": 64})
+                             "tile_n_target": 4096})
     applied = profile.apply_tuning(prof)
-    assert applied == {"stream_max_products": 123_456, "fused_block": 64}
+    assert applied == {"stream_max_products": 123_456}
     assert fast.STREAM_MAX_PRODUCTS == 123_456
-    assert pallas_stream.FUSED_BLOCK == 64
 
 
 def test_apply_tuning_untouched_without_keys():

@@ -134,14 +134,13 @@ def test_device_plans_take_the_device_guard(monkeypatch):
         assert key[-1] == want, backend
 
 
-@pytest.mark.parametrize("engine", [None, "fused"])
-def test_guarded_device_plan_falls_back_loudly(engine):
+def test_guarded_device_plan_falls_back_loudly():
     a = random_powerlaw_csc(30, 2.5, seed=3)
     api.plan_cache_clear()
     plan = cached_plan(a, a, "expand", backend="jax", stream_limit=1)
     stats = {}
     with pytest.warns(RuntimeWarning, match="host stream engine"):
-        c = plan.execute(a, a, engine=engine, stats=stats)
+        c = plan.execute(a, a, stats=stats)
     assert stats["fallback"] == "host" and stats["device"] is False
     assert api.plan_cache_info()["host_fallbacks"] == 1
     ref = plan_spgemm(a, a, "expand").execute(a, a)

@@ -316,10 +316,9 @@ def test_auto_candidates_are_valid_methods():
     assert sorted(AUTO_CANDIDATES) == sorted(backend_names())
     for backend, cands in AUTO_CANDIDATES.items():
         for m in cands:
-            # "jax"/"fused" are the cross-backend candidate spellings: the
-            # device stream / fused Pallas kernel riding a tile grid
-            # (DESIGN.md §10/§11)
-            assert (m in ALGORITHMS or m in ("jax", "fused")
+            # "jax" is the cross-backend candidate spelling: the device
+            # stream riding a tile grid (DESIGN.md §10)
+            assert (m in ALGORITHMS or m == "jax"
                     or m.startswith(("spars", "hash", "h-")))
 
 

@@ -1,5 +1,5 @@
 """The device path compiles for a TPU v5e, at the widths ``chip_smoke.py``
-drives: the fused stream kernel, the SPA / SPARS / BSR kernels (and SPA /
+drives: the SPA / SPARS / BSR kernels (and SPA /
 SPARS at Table 1's widest A tables, gathered by an int8 dot, under the
 chip's kernel VMEM budget) and the XLA stream, each compiled for a
 described (not attached) ``v5e:2x2`` chip.
@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import runtime
-from repro.core import jax_stream, pallas_stream
+from repro.core import jax_stream
 from repro.core.jax_stream import _bilinear_contract
 
 # Goodwin_013 stand-in (chip_smoke phase (a)): n, max column nnz, products
@@ -73,38 +73,6 @@ def _compile(fn, *args):
 def _spec(one_chip):
     return lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
                                                   sharding=one_chip)
-
-
-def _fused_args(s, p):
-    nb = -(-(-(-p // 128)) // pallas_stream.ROWS) * pallas_stream.ROWS
-    return (s((p,), jnp.int32), s((p,), jnp.int32),
-            s((nb, 128), jnp.int32), s((nb,), jnp.int32),
-            s((nb,), jnp.int32))
-
-
-@pytest.mark.parametrize("p,n_out,nnz", [
-    (P_SMALL, 1_900_000, 56_059),
-    (P_BIG, NNZ_C_BIG, NNZ_BIG),
-], ids=["goodwin", "powerlaw"])
-def test_fused_engine_compiles(one_chip, compiled_mode, p, n_out, nnz):
-    s = _spec(one_chip)
-
-    def run(view, x, y):
-        v = pallas_stream.FusedView(*view, None, n_out, p, 128)
-        return pallas_stream._fused_call(v, x, y)
-
-    c = _compile(run, _fused_args(s, p), s((nnz,), jnp.float32),
-                 s((nnz,), jnp.float32))
-    assert "tpu_custom_call" in c.as_text()
-
-
-def test_fused_kernel_compiles_batched(one_chip, compiled_mode):
-    """The vmapped launch (``execute_fused_batched``): batch as a grid axis."""
-    s = _spec(one_chip)
-    nb = 2048
-    c = _compile(jax.vmap(pallas_stream._block_partials),
-                 s((3, nb, 128), jnp.float32), s((3, nb, 128), jnp.int32))
-    assert "tpu_custom_call" in c.as_text()
 
 
 def test_xla_stream_compiles(one_chip, compiled_mode):
